@@ -14,7 +14,7 @@ parallel/supervisor.Supervisor and measures, with wall-clock brackets:
   detector overhead is visible).
 
 Like every bench here: same-run brackets only — this host's CPU varies
-wildly run to run (BASELINE.md), so the numbers are for shape, not
+wildly run to run, so the numbers are for shape, not
 absolutes. Results print as one JSON line and persist under
 ``BASELINE.json.published.measured_gang_recovery`` plus
 ``MULTICHIP_gang.json`` (the multichip bracket the roadmap asks for).
@@ -50,7 +50,6 @@ def _gang(tmp, tag, per_worker_env=None, max_restarts=3):
         **os.environ,
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
-        "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "xla_cache"),
     }
     env.pop("PIO_FAULT_SPEC", None)
     return Supervisor(
@@ -321,7 +320,6 @@ def _run_train(env: dict, engine_dir: str, num_workers: int,
         "PIO_TRAIN_FEED": "partition",
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1",
-        "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "xla_cache"),
     }
     run_env.pop("PIO_FAULT_SPEC", None)
     t0 = time.perf_counter()

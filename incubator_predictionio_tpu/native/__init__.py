@@ -7,9 +7,9 @@ HBase client + TableInputFormat scan play in the reference (storage/hbase/
 arrays, the exact host-side layout the input pipeline uploads to device.
 
 Build strategy: the .so is compiled lazily on first use (one translation
-unit, ~1s with g++ -O3) into ``_lib/`` next to this file, keyed by an ABI
-version exported by the library; `make -C native` does the same for
-packaging. When no C++ toolchain is available ``parse_events_jsonl``
+unit, ~1s with g++ -O3) into ``_lib/`` next to this file, keyed by the ABI
+version the library exports and by a hash of the source it was built
+from; `make -C native` does the same for packaging. When no C++ toolchain is available ``parse_events_jsonl``
 raises ``NativeUnavailable`` and callers fall back to the pure-Python
 scan — behavior is identical, only slower (tests assert equality).
 """
@@ -17,6 +17,7 @@ scan — behavior is identical, only slower (tests assert equality).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
 import os
 import subprocess
@@ -46,12 +47,28 @@ def _src_path() -> str:
     return os.path.join(repo_root, "native", "src", "event_codec.cc")
 
 
+def _src_digest() -> str:
+    """Short content hash of the codec source ("" when the source is not
+    there to hash)."""
+    try:
+        with open(_src_path(), "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()[:12]
+    except OSError:
+        return ""
+
+
 def _lib_path() -> str:
     # ABI version in the filename: glibc dlopen dedups by pathname, so a
     # same-path rebuild inside a live process would silently resolve to
-    # the stale mapped library (its symbols, not the new ones).
-    return os.path.join(os.path.dirname(os.path.abspath(__file__)), "_lib",
-                        f"libpioevent.v{_EXPECTED_VERSION}.so")
+    # the stale mapped library (its symbols, not the new ones). The
+    # source hash beside it: _lib/ is git-ignored and survives checkouts
+    # and tree copies, so an edited source with an unchanged ABI number
+    # must not find — and run — the binary of the old source.
+    digest = _src_digest()
+    return os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "_lib",
+        f"libpioevent.v{_EXPECTED_VERSION}{'.' + digest if digest else ''}"
+        ".so")
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -193,7 +210,7 @@ def _build() -> str:
     if proc.returncode != 0:
         raise NativeUnavailable(f"g++ build failed: {proc.stderr[-2000:]}")
     os.replace(tmp, out)
-    # drop superseded ABI versions (and the pre-v7 unversioned file)
+    # drop superseded builds (older ABI versions, older sources)
     import glob
 
     for stale in glob.glob(os.path.join(os.path.dirname(out), "libpioevent*.so")):

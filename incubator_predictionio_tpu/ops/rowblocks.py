@@ -2,8 +2,8 @@
 
 The ALS half-step is gather-bound on TPU: the factor-row gather unit
 sustains a fixed ~420M rows/s regardless of row width (≤128 lanes),
-sortedness, or table size (measured in tools/profile_als.py; see
-BASELINE.md "roofline"). Every padded entry is therefore a wasted gather
+sortedness, or table size (measured 2026-07 with tools/profile_als.py;
+see docs/tpu.md). Every padded entry is therefore a wasted gather
 slot, and any segment-reduction after the gather is pure overhead. This
 layout minimizes both:
 

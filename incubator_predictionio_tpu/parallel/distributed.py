@@ -46,9 +46,10 @@ def resolve_distributed_timeouts() -> dict:
       ``initialization_timeout``; default 300 s). Floored at 1 s —
       jax takes whole seconds.
     - ``PIO_DIST_HEARTBEAT_MS`` — coordination-service heartbeat
-      interval, client and service side (default 10 s, floor 1 s).
+      interval (default 10 s, floor 1 s).
     - ``PIO_DIST_MAX_MISSING_HEARTBEATS`` — missed beats before a
       process is declared dead and the job torn down (default 10).
+      jax takes their product (``heartbeat_timeout_seconds``).
 
     Malformed or absent values fall back to the jax defaults (a typo'd
     knob must not take down a training job at init).
@@ -94,44 +95,17 @@ def initialize_distributed(
         # CPU backend") — select the gloo TCP implementation before the
         # backend initializes. TPU/GPU pods use their own interconnect
         # collectives and never read this flag.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):  # older/newer jax: no flag
-            log.debug("jax_cpu_collectives_implementation not supported")
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     t = resolve_distributed_timeouts()
-    try:
-        # The public jax.distributed.initialize does not expose the
-        # coordination-service heartbeat knobs (jax 0.4.x); it is a thin
-        # wrapper over State.initialize plus this same guard, so call
-        # the state object directly and keep the guard.
-        from jax._src import distributed as _dist
-        from jax._src import xla_bridge as _xb
-
-        if _xb.backends_are_initialized():
-            raise RuntimeError(
-                "initialize_distributed() must be called before any JAX "
-                "computations are executed.")
-        _dist.global_state.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-            initialization_timeout=t["initialization_timeout"],
-            service_heartbeat_interval_seconds=t["heartbeat_interval"],
-            service_max_missing_heartbeats=t["max_missing_heartbeats"],
-            client_heartbeat_interval_seconds=t["heartbeat_interval"],
-            client_max_missing_heartbeats=t["max_missing_heartbeats"],
-        )
-    except (ImportError, TypeError, AttributeError):
-        # Private surface moved (newer jax): the public API still honors
-        # the connection timeout; heartbeat cadence stays at defaults.
-        log.debug("falling back to public jax.distributed.initialize",
-                  exc_info=True)
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-            initialization_timeout=t["initialization_timeout"],
-        )
+    jax.distributed.initialize(
+        coordinator_address=coordinator_address,
+        num_processes=num_processes,
+        process_id=process_id,
+        initialization_timeout=t["initialization_timeout"],
+        # jax takes the product: a peer silent for this long is dead
+        heartbeat_timeout_seconds=(t["heartbeat_interval"]
+                                   * t["max_missing_heartbeats"]),
+    )
     log.info(
         "jax.distributed initialized: process %d/%d, %d global devices",
         process_id, num_processes, len(jax.devices()),

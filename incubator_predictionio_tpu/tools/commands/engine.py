@@ -10,7 +10,7 @@ import os
 import sys
 
 from ...data.storage.registry import Storage
-from ...workflow.context import WorkflowContext
+from ...workflow.context import WorkflowContext, enable_compilation_cache
 from ...workflow.json_extractor import engine_and_params_from_json, load_engine_json
 from ...workflow.workflow_params import WorkflowParams
 from . import verb
@@ -140,6 +140,9 @@ def train_cmd(args: list[str]) -> int:
         install_worker_signal_handlers()
     from ...workflow.core_workflow import run_train
 
+    # before the engine module is imported: a jit that ran at its import
+    # would latch the process's compile cache off (workflow/context.py)
+    enable_compilation_cache()
     engine, params, factory, variant, engine_json = _load_engine(ns)
     app_name = (
         dict(params.data_source_params).get("app_name")
@@ -176,7 +179,13 @@ def train_cmd(args: list[str]) -> int:
             #                         drain outcome, never a failure
         raise
     train_s = _time.perf_counter() - t0
-    print(f"[info] Training completed in {train_s:.2f}s. "
+    from ...parallel.mesh import device_report
+
+    # the configured mesh's devices, so the line proves where it trained
+    dev = device_report(ctx.get_mesh().devices.flat)
+    print(f"[info] Training completed in {train_s:.2f}s on "
+          f"platform={dev['platform']} deviceKind={dev['deviceKind']!r} "
+          f"deviceCount={dev['deviceCount']}. "
           f"Engine instance ID: {instance_id}")
     return 0
 
@@ -389,6 +398,7 @@ def _build_engine_server(ns):
     from ...common import envknobs
     from ...workflow.create_server import EngineServer
 
+    enable_compilation_cache()  # before the engine module import
     engine, params, factory, variant, _ = _load_engine(ns)
     app_name = dict(params.data_source_params).get("app_name") or dict(
         params.data_source_params

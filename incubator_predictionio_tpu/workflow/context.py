@@ -9,24 +9,39 @@ everything a component needs to read events and place arrays.
 from __future__ import annotations
 
 import dataclasses
+import logging
+import os
 from typing import Any, Optional
 
 from ..data.storage.registry import Storage
 from ..workflow.workflow_params import WorkflowParams
 
+log = logging.getLogger("pio.workflow")
+
 _cache_enabled = False
 
+#: Where compiled executables are kept when JAX_COMPILATION_CACHE_DIR is
+#: unset: ONE fixed path inside the checkout, so every `pio` process —
+#: whatever its PIO_FS_BASEDIR — finds what the last one compiled (a
+#: directory that moves between runs never hits). Git-ignored.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-def _enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache under $PIO_FS_BASEDIR/xla_cache.
 
-    Every `pio` verb is its own process; without this each train/deploy
-    re-pays the full XLA compile (tens of seconds on TPU) for programs
-    compiled identically last run. Wired here — every compiling verb
-    builds a WorkflowContext, and jax is already imported by then —
-    because this jax version ignores the JAX_COMPILATION_CACHE_DIR env
-    var, so the config call is required and metadata-only verbs should
-    not import jax just to make it. PIO_COMPILATION_CACHE=0 opts out;
+def enable_compilation_cache() -> None:
+    """Persistent XLA compilation cache, so a fresh `pio train`/`pio
+    deploy` process loads the executables an earlier one compiled
+    instead of re-paying the compile.
+
+    Where JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and this
+    function sets nothing. Otherwise the cache lives at
+    DEFAULT_COMPILATION_CACHE_DIR. Must run before the process's first
+    compile: JAX latches "no cache" for the life of the process at the
+    first compile that finds no directory, so the compiling verbs call
+    this before they import an engine module, and WorkflowContext calls
+    it again for library users who never pass through the CLI.
+    PIO_COMPILATION_CACHE=0 opts out (and keeps jax un-imported);
     sub-second compiles are skipped by JAX's default
     jax_persistent_cache_min_compile_time_secs=1.
     """
@@ -38,16 +53,18 @@ def _enable_compilation_cache() -> None:
 
     if not envknobs.env_flag("PIO_COMPILATION_CACHE", True):
         return
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
     try:
         import jax
 
-        from ..data.storage.registry import base_dir
-
-        cache_dir = os.path.join(base_dir(), "xla_cache")
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception:  # noqa: BLE001 - cache is an optimization only
-        pass
+        os.makedirs(DEFAULT_COMPILATION_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir",
+                          DEFAULT_COMPILATION_CACHE_DIR)
+    except Exception:  # noqa: BLE001 - the cache is an optimization; a
+        # train must not die for it, but a dead cache must be visible
+        log.warning("persistent compilation cache NOT enabled (every "
+                    "process will pay the full compile)", exc_info=True)
 
 
 @dataclasses.dataclass
@@ -68,7 +85,7 @@ class WorkflowContext:
     input_pipeline: Any = None
 
     def __post_init__(self):
-        _enable_compilation_cache()
+        enable_compilation_cache()
 
     def get_storage(self) -> Storage:
         return self.storage or Storage.instance()

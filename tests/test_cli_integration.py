@@ -33,7 +33,7 @@ def cli_env(tmp_path):
     env = dict(os.environ)
     env["PIO_FS_BASEDIR"] = str(tmp_path / "store")
     # CPU platform for subprocesses (they don't load tests/conftest.py).
-    env["PIO_TEST_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
@@ -82,6 +82,15 @@ def test_quickstart_lifecycle(cli_env, tmp_path):
     # pio train
     r = run_pio(["train", "--engine-dir", tpl], cli_env)
     assert "Training completed" in r.stdout
+    # the completion line says where it trained
+    assert "platform=cpu deviceKind='cpu' deviceCount=8" in r.stdout
+
+    # --device=tpu where JAX found no TPU: an error, not a CPU train
+    r = run_pio(["train", "--engine-dir", tpl, "--device", "tpu"], cli_env,
+                check=False)
+    assert r.returncode != 0
+    assert "--device=tpu" in r.stderr and "platform 'cpu'" in r.stderr
+    assert "Training completed" not in r.stdout
 
     # pio export round-trips
     out_file = tmp_path / "export.jsonl"

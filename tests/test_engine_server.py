@@ -27,6 +27,10 @@ def test_engine_server_query_and_reload(memory_storage):
         assert r.status_code == 200
         status = r.json()
         assert status["status"] == "alive"
+        # which device answers, as JAX reports it (tests run on the
+        # 8-device virtual CPU platform)
+        assert (status["platform"], status["deviceKind"],
+                status["deviceCount"]) == ("cpu", "cpu", 8)
         first_instance = status["engineInstanceId"]
 
         # the hot path

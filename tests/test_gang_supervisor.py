@@ -494,7 +494,7 @@ def test_pio_train_num_workers_cli_e2e(tmp_path):
 
     env = dict(os.environ)
     env["PIO_FS_BASEDIR"] = str(tmp_path / "store")
-    env["PIO_TEST_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env["JAX_PLATFORMS"] = "cpu"  # workers pick gloo collectives
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla_cache")

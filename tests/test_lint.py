@@ -73,10 +73,10 @@ def test_suppression_inventory_can_only_shrink():
         # gang identity knobs (rank / world size) parse STRICTLY: a
         # garbled value must crash the worker at startup, not fall back
         # to rank 0 / world 1 and corrupt the gang topology
-        ("incubator_predictionio_tpu/parallel/distributed.py", 88,
+        ("incubator_predictionio_tpu/parallel/distributed.py", 89,
          ("knob-envknobs",),
          "identity knob: strict crash beats tolerant world=1"),
-        ("incubator_predictionio_tpu/parallel/distributed.py", 90,
+        ("incubator_predictionio_tpu/parallel/distributed.py", 91,
          ("knob-envknobs",),
          "identity knob: strict crash beats tolerant rank=0"),
     ], (
@@ -699,7 +699,7 @@ def test_cli_clean_rc0_and_filters(tmp_path, capsys):
 
 def test_console_lint_verb_never_imports_jax():
     """`pio lint` must stay a pure parse pass: the console dispatches
-    it before any jax-touching setup (PIO_TEST_FORCE_CPU included), so
+    it before any jax-touching setup, so
     a full run fits tier-1 in seconds. Subprocess-proved — including
     the ISSUE 11 whole-program flow rules (call graph + tests/ scan)
     and the --profile path, which must stay equally import-light."""

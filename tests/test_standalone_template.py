@@ -1,6 +1,6 @@
 """Standalone (user-source) template project end-to-end.
 
-Round-1 gap (VERDICT.md missing #3): every bundled template pointed at
+Round-1 gap: every bundled template pointed at
 engines built into the framework; nothing proved a template with its OWN
 DASE source — the product's third-party authorship path — trains and
 serves. This drives the real `pio` binary: template get → app new →
@@ -39,7 +39,7 @@ def run_pio(args, env, check=True, cwd=None):
 def cli_env(tmp_path):
     env = dict(os.environ)
     env["PIO_FS_BASEDIR"] = str(tmp_path / "store")
-    env["PIO_TEST_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
