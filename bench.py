@@ -11,7 +11,7 @@ program; compile time is reported separately on stderr.
 
 The HEADLINE number is measured through the REAL product path:
 Engine.train → ALSAlgorithm (template defaults: computeDtype="auto",
-chunkTiles=-1) → ops.als.train_als, instrumented via its `timings` hook.
+chunkTiles=-1) → ops.als.train_als, read from its `als.*` spans.
 A second, ops-level run (hand-built executable, same auto-resolved knobs
 unless PIO_BENCH_CHUNK overrides) is reported on stderr as a cross-check
 that the DASE wrapper adds no overhead; a >7% gap logs a WARNING (and
@@ -175,8 +175,8 @@ def ops_level_events_per_sec(u, i, r, n_users, n_items, nnz, rank, iters):
 
 def dase_events_per_sec(u, i, r, n_users, n_items, nnz, rank, iters):
     """THE product path: Engine.train → ALSAlgorithm with template-default
-    params ("auto" dtype/chunking) → train_als, timed via its timings hook
-    at the same boundaries as the ops-level harness."""
+    params ("auto" dtype/chunking) → train_als, timed by the spans the
+    product path records (als.upload, als.loop, its xla.compile children)."""
     import jax
 
     from incubator_predictionio_tpu.controller.datasource import DataSource
@@ -185,6 +185,7 @@ def dase_events_per_sec(u, i, r, n_users, n_items, nnz, rank, iters):
     from incubator_predictionio_tpu.models.recommendation import (
         ALSAlgorithm, TrainingData,
     )
+    from incubator_predictionio_tpu.ops.als import train_phase_seconds
     from incubator_predictionio_tpu.parallel.mesh import default_mesh
     from incubator_predictionio_tpu.workflow.context import WorkflowContext
 
@@ -212,14 +213,14 @@ def dase_events_per_sec(u, i, r, n_users, n_items, nnz, rank, iters):
         "algorithms": [{"name": "als", "params": algo_params}],
     })
     ctx = WorkflowContext(app_name="bench")
-    ctx.bench_timings = {}
     n_dev = len(default_mesh().devices.flatten().tolist())
 
+    since_ns = time.perf_counter_ns()
     t0 = time.time()
     models = engine.train(ctx, engine_params)
     total = time.time() - t0
-    t = ctx.bench_timings
-    assert "device_train_seconds" in t, "timings hook did not fire"
+    t = train_phase_seconds(since_ns)
+    assert t["device_train_seconds"] > 0, "train_als recorded no als.loop span"
     assert np.isfinite(models[0].factors.user_factors).all()
     events_per_sec = nnz / t["device_train_seconds"] / n_dev
     log(f"[bench:dase] Engine.train total {total:.1f}s — upload "

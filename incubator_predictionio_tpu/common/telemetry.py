@@ -22,15 +22,24 @@ This module is the one measurement substrate every layer records into:
   text format (``# HELP``/``# TYPE``, escaped labels, cumulative
   ``_bucket``/``_sum``/``_count``) served by the event server, the
   engine server, and the dashboard at ``GET /metrics``.
-- **Sampled request tracing** — ``PIO_TRACE`` sets a sample rate;
-  sampled requests get a trace id (honoring an incoming
-  ``X-Pio-Trace-Id``, which — whenever tracing is enabled at all —
-  bypasses the probability roll so a caller can follow one request
-  through every tier; ``PIO_TRACE`` unset/0 stays fully off), the
-  id rides a
-  ``contextvars`` slot across ``asyncio.to_thread`` into the serving
-  stages, and finished spans are written as JSON lines to
-  ``PIO_TRACE_SINK`` (a path, or ``stderr``).
+- **Spans** — :func:`span` (a context manager) and :func:`add_span`
+  (start and end known afterwards) are the one primitive that training
+  (``train.run`` → ``dase.*`` → ``als.*``) and serving (``http POST
+  /queries.json`` → ``query.*`` → ``topk.*``) both use. A span is
+  ``(trace_id, span_id, parent_id, name, t0_ns, t1_ns, tags)`` on
+  ``time.perf_counter_ns``; the parent rides a ``contextvars`` slot, so
+  it crosses ``copy_context()`` into the query executor. Every finished
+  span goes into a bounded process-wide ring (:func:`spans_snapshot`),
+  gated by ``PIO_METRICS`` like the histograms; while open it also
+  holds a ``jax.profiler.TraceAnnotation("pio:<name>")`` once
+  :func:`install_xla_hooks` was handed jax (this module never imports
+  it), so a profiler session shows host spans beside device ops.
+- **Sampled export** — ``PIO_TRACE`` sets a sample rate; a sampled
+  request gets a trace id (honoring an incoming ``X-Pio-Trace-Id``,
+  which — whenever tracing is enabled at all — bypasses the
+  probability roll so a caller can follow one request through every
+  tier; ``PIO_TRACE`` unset/0 stays fully off) and its spans are also
+  written as JSON lines to ``PIO_TRACE_SINK`` (a path, or ``stderr``).
 
 Per-instance JSON views (ingest ``snapshot()``, ``stats.json``) remain
 per-server-instance; the registry is process-cumulative, which is what
@@ -39,8 +48,9 @@ a scraper expects.
 
 from __future__ import annotations
 
-import contextlib
+import collections
 import contextvars
+import itertools
 import json
 import os
 import random
@@ -48,13 +58,14 @@ import sys
 import threading
 import time
 import uuid
-from typing import Callable, Iterable, Optional
+from typing import Callable, Iterable, NamedTuple, Optional
 
 from . import envknobs
 
 __all__ = [
     "CounterFamily", "GaugeFamily", "HistogramFamily", "Registry",
-    "Trace", "TraceRecorder", "TRACE_HEADER",
+    "Span", "span", "add_span", "spans_snapshot", "install_xla_hooks",
+    "RING_SIZE", "Trace", "TraceRecorder", "TRACE_HEADER",
     "current_trace", "activate_trace", "deactivate_trace",
     "metrics_enabled", "set_metrics_enabled", "timer_start",
     "registry", "render_all", "sample_trace", "configure_tracer",
@@ -76,11 +87,13 @@ class _State:
     """Mutable module state behind one attribute load (the hot-path
     check is ``if not _STATE.metrics_on: return``)."""
 
-    __slots__ = ("metrics_on",)
+    __slots__ = ("metrics_on", "annotation")
 
 
 _STATE = _State()
 _STATE.metrics_on = _env_flag("PIO_METRICS", True)
+#: jax.profiler.TraceAnnotation once install_xla_hooks was handed jax
+_STATE.annotation = None
 
 
 def metrics_enabled() -> bool:
@@ -127,14 +140,14 @@ class Counter:
         self._shards = tuple(
             (threading.Lock(), [0]) for _ in range(_N_SHARDS))
 
-    def inc(self, n: int = 1) -> None:
+    def inc(self, n: float = 1) -> None:
         if not _STATE.metrics_on:
             return
         lock, box = self._shards[_shard_index()]
         with lock:
             box[0] += n
 
-    def value(self) -> int:
+    def value(self) -> float:
         total = 0
         for lock, box in self._shards:
             with lock:
@@ -456,53 +469,247 @@ def render_all() -> str:
 
 
 # ---------------------------------------------------------------------------
-# sampled request tracing
+# spans: one primitive for training and serving
 # ---------------------------------------------------------------------------
 
+#: finished spans kept in memory for a reader in the process
+RING_SIZE = 65536
+
+#: perf_counter_ns -> epoch ns, for the sink's ``startUs``
+_EPOCH_OFFSET_NS = time.time_ns() - time.perf_counter_ns()
+
+
+class Span(NamedTuple):
+    """One finished span. ``t0_ns``/``t1_ns`` are ``perf_counter_ns``
+    readings; ``trace_id`` is shared by the spans of one request or one
+    train; ``parent_id`` is None on a root."""
+
+    trace_id: object
+    span_id: int
+    parent_id: Optional[int]
+    name: str
+    t0_ns: int
+    t1_ns: int
+    tags: Optional[dict]
+
+
+_SPAN_VAR: "contextvars.ContextVar[Optional[_OpenSpan]]" = \
+    contextvars.ContextVar("pio_span", default=None)
 _TRACE_VAR: "contextvars.ContextVar[Optional[Trace]]" = \
     contextvars.ContextVar("pio_trace", default=None)
+_RING: "collections.deque[Span]" = collections.deque(maxlen=RING_SIZE)
+_IDS = itertools.count(1)
 
+
+def _lineage(trace_id=None) -> tuple:
+    """(sampled Trace or None, trace id, parent id) of a span opening in
+    this context: beneath the span that is open, else a root."""
+    parent = _SPAN_VAR.get()
+    if parent is not None:
+        return parent._trace, parent.trace_id, parent.span_id
+    trace = _TRACE_VAR.get()
+    if trace is not None:
+        trace_id = trace.trace_id
+    elif trace_id is None:
+        trace_id = next(_IDS)
+    return trace, trace_id, None
+
+
+def _finish(rec: Span, trace: "Optional[Trace]") -> None:
+    _RING.append(rec)
+    if trace is not None:
+        trace.add(rec)
+
+
+class _OpenSpan:
+    """The context manager :func:`span` returns while metrics are on.
+    Entering binds it as the ``contextvars`` parent of what opens
+    beneath it (across ``copy_context()`` into an executor thread too)
+    and, once jax was handed over (:func:`install_xla_hooks`), holds a
+    ``TraceAnnotation("pio:<name>")`` so a profiler session shows the
+    span beside the device's ops."""
+
+    __slots__ = ("trace_id", "span_id", "parent_id", "name", "tags",
+                 "t0_ns", "dur_ns", "_trace", "_token", "_ann")
+
+    def __init__(self, name: str, trace_id, tags: Optional[dict]):
+        self.name = name
+        self.trace_id = trace_id
+        self.tags = tags
+        self.dur_ns = 0
+
+    def tag(self, **tags) -> None:
+        """Tags only known while the span is open (an HTTP status)."""
+        if self.tags is None:
+            self.tags = tags
+        else:
+            self.tags.update(tags)
+
+    def __enter__(self) -> "_OpenSpan":
+        self._trace, self.trace_id, self.parent_id = _lineage(self.trace_id)
+        self.span_id = next(_IDS)
+        self._token = _SPAN_VAR.set(self)
+        ann = _STATE.annotation
+        if ann is not None:
+            self._ann = ann = ann("pio:" + self.name)
+            ann.__enter__()
+        else:
+            self._ann = None
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        t1 = time.perf_counter_ns()
+        self.dur_ns = t1 - self.t0_ns
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
+        _SPAN_VAR.reset(self._token)
+        _finish(Span(self.trace_id, self.span_id, self.parent_id, self.name,
+                     self.t0_ns, t1, self.tags), self._trace)
+        return False
+
+
+class _NoopSpan:
+    """What :func:`span` returns with ``PIO_METRICS=0``: one shared
+    object, nothing recorded, nothing allocated."""
+
+    __slots__ = ()
+    dur_ns = 0
+
+    def tag(self, **tags) -> None:
+        pass
+
+    def __enter__(self) -> "_NoopSpan":
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        return False
+
+
+_NOOP_SPAN = _NoopSpan()
+
+
+def span(name: str, *, trace_id=None, **tags):
+    """Context manager around one piece of work::
+
+        with telemetry.span("als.init"):
+            x0, y0 = _fresh_init(...)
+
+    The finished :class:`Span` goes into the process-wide ring
+    (:func:`spans_snapshot`) and, for a ``PIO_TRACE``-sampled request,
+    to the sink. Its parent is the span open in this context; a root
+    starts a new trace under ``trace_id`` (a train gives its
+    engine-instance id) or the next small integer. Gated by
+    ``PIO_METRICS`` like every other record call."""
+    if not _STATE.metrics_on:
+        return _NOOP_SPAN
+    return _OpenSpan(name, trace_id, tags or None)
+
+
+def add_span(name: str, t0_ns: int, t1_ns: int, **tags) -> None:
+    """A span whose start and end (``perf_counter_ns``) are only known
+    afterwards: a compile reported by its listener, the wait of an
+    admitted query for a worker. A child of the span open in this
+    context; not in the profiler's trace (an annotation cannot be
+    backdated)."""
+    if not _STATE.metrics_on:
+        return
+    trace, trace_id, parent_id = _lineage()
+    _finish(Span(trace_id, next(_IDS), parent_id, name, t0_ns, t1_ns,
+                 tags or None), trace)
+
+
+def spans_snapshot() -> list[Span]:
+    """A copy of the ring, oldest first (at most RING_SIZE spans)."""
+    return list(_RING.copy())
+
+
+def install_xla_hooks(jax) -> None:
+    """Take from an imported ``jax`` what this module must not import
+    (the event server records here and never loads jax): the profiler's
+    ``TraceAnnotation`` for open spans, and ``jax.monitoring`` listeners
+    that turn every backend compile into an ``xla.compile`` span and the
+    ``pio_xla_*`` counters. ``workflow/context.py`` calls it where it
+    enables the compile cache; once per process."""
+    if _STATE.annotation is not None:
+        return
+    _STATE.annotation = jax.profiler.TraceAnnotation
+    reg = registry()
+    compiles = reg.counter(
+        "pio_xla_compiles_total",
+        "XLA backend compiles (a load from the persistent compile cache "
+        "counts: it is what the caller waited for).").labels()
+    seconds = reg.counter(
+        "pio_xla_compile_seconds_total",
+        "Seconds spent in XLA backend compiles or cache loads.").labels()
+    cache = reg.counter(
+        "pio_xla_cache_events_total",
+        "Persistent compile cache events as jax.monitoring names them "
+        "(cache_hits, cache_misses, ...).", ("event",))
+
+    def on_duration(event: str, secs: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            t1 = time.perf_counter_ns()
+            compiles.inc()
+            seconds.inc(secs)
+            add_span("xla.compile", t1 - int(secs * 1e9), t1,
+                     seconds=secs)
+
+    def on_event(event: str, **_kw) -> None:
+        if event.startswith("/jax/compilation_cache/"):
+            cache.labels(event.rsplit("/", 1)[1]).inc()
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+
+
+# ---------------------------------------------------------------------------
+# sampled request tracing: which requests' spans also go to the sink
+# ---------------------------------------------------------------------------
 
 class Trace:
-    """One sampled request: collects spans, flushed once at the end.
+    """One sampled request: collects its finished spans, flushed once
+    at the end.
 
     Spans are buffered in-process and written as JSON lines in one
     flush so a trace's spans land contiguously in the sink even under
-    concurrent requests."""
+    concurrent requests. A span that ends after the flush (a worker
+    that overran its deadline) is written on its own."""
 
     __slots__ = ("trace_id", "_recorder", "_spans", "_lock")
 
     def __init__(self, trace_id: str, recorder: "TraceRecorder"):
         self.trace_id = trace_id
         self._recorder = recorder
-        self._spans: list[dict] = []
+        self._spans: Optional[list[Span]] = []
         self._lock = threading.Lock()
 
-    def add_span(self, name: str, dur_ns: int, **tags) -> None:
-        span = {
-            "traceId": self.trace_id,
-            "span": name,
-            "startUs": (time.time_ns() - dur_ns) // 1000,
-            "durUs": dur_ns // 1000,
-        }
-        if tags:
-            span["tags"] = tags
+    def add(self, rec: Span) -> None:
         with self._lock:
-            self._spans.append(span)
-
-    @contextlib.contextmanager
-    def span(self, name: str, **tags):
-        t0 = time.perf_counter_ns()
-        try:
-            yield self
-        finally:
-            self.add_span(name, time.perf_counter_ns() - t0, **tags)
+            if self._spans is not None:
+                self._spans.append(rec)
+                return
+        self._recorder.emit([rec])
 
     def flush(self) -> None:
         with self._lock:
-            spans, self._spans = self._spans, []
+            spans, self._spans = self._spans, None
         if spans:
             self._recorder.emit(spans)
+
+
+def _sink_line(rec: Span) -> str:
+    line = {
+        "traceId": rec.trace_id,
+        "span": rec.name,
+        "startUs": (rec.t0_ns + _EPOCH_OFFSET_NS) // 1000,
+        "durUs": (rec.t1_ns - rec.t0_ns) // 1000,
+        "spanId": rec.span_id,
+        "parentId": rec.parent_id,
+    }
+    if rec.tags:
+        line["tags"] = rec.tags
+    return json.dumps(line, separators=(",", ":")) + "\n"
 
 
 class TraceRecorder:
@@ -545,9 +752,8 @@ class TraceRecorder:
             return None
         return Trace(uuid.uuid4().hex[:16], self)
 
-    def emit(self, spans: list[dict]) -> None:
-        data = "".join(json.dumps(s, separators=(",", ":")) + "\n"
-                       for s in spans)
+    def emit(self, spans: list[Span]) -> None:
+        data = "".join(_sink_line(s) for s in spans)
         try:
             with self._lock:
                 if self.sink == "stderr":
@@ -605,33 +811,36 @@ def deactivate_trace(token) -> None:
 
 
 def trace_middleware():
-    """aiohttp middleware: sample each request, bind the trace into the
-    handler's context, stamp ``X-Pio-Trace-Id`` on the response, and
-    flush the root span. Servers append this to their middleware list;
-    with tracing off it forwards with one None check."""
+    """aiohttp middleware: open the request's root span (the ring sees
+    every request while metrics are on), sample it for the sink, bind
+    the trace into the handler's context, stamp ``X-Pio-Trace-Id`` on a
+    sampled response and flush. Servers append this to their middleware
+    list."""
     from aiohttp import web
 
     @web.middleware
     async def _trace_mw(request, handler):
         tr = sample_trace(request.headers.get(TRACE_HEADER))
-        if tr is None:
-            return await handler(request)
-        token = activate_trace(tr)
-        t0 = time.perf_counter_ns()
-        status = 500
+        token = activate_trace(tr) if tr is not None else None
         try:
-            resp = await handler(request)
-            status = resp.status
-            resp.headers[TRACE_HEADER] = tr.trace_id
-            return resp
-        except web.HTTPException as e:
-            status = e.status
-            e.headers[TRACE_HEADER] = tr.trace_id
-            raise
+            with span(f"http {request.method} {request.path}") as root:
+                status = 500
+                try:
+                    resp = await handler(request)
+                    status = resp.status
+                    if tr is not None:
+                        resp.headers[TRACE_HEADER] = tr.trace_id
+                    return resp
+                except web.HTTPException as e:
+                    status = e.status
+                    if tr is not None:
+                        e.headers[TRACE_HEADER] = tr.trace_id
+                    raise
+                finally:
+                    root.tag(status=status)
         finally:
-            deactivate_trace(token)
-            tr.add_span(f"http {request.method} {request.path}",
-                        time.perf_counter_ns() - t0, status=status)
-            tr.flush()
+            if tr is not None:
+                deactivate_trace(token)
+                tr.flush()
 
     return _trace_mw

@@ -176,15 +176,17 @@ class Engine:
         ctx.workflow_params = wp
         ds, prep, algo_list, _ = self.make_components(engine_params)
 
-        td = ds.read_training(ctx)
-        self._maybe_sanity_check(td, "datasource", not wp.skip_sanity_check,
-                                 wp.nan_guard)
+        with telemetry.span("dase.read"):
+            td = ds.read_training(ctx)
+            self._maybe_sanity_check(td, "datasource",
+                                     not wp.skip_sanity_check, wp.nan_guard)
         if wp.stop_after_read:
             log.info("--stop-after-read: halting before prepare")
             return []
-        pd = prep.prepare(ctx, td)
-        self._maybe_sanity_check(pd, "preparator", not wp.skip_sanity_check,
-                                 wp.nan_guard)
+        with telemetry.span("dase.prepare"):
+            pd = prep.prepare(ctx, td)
+            self._maybe_sanity_check(pd, "preparator",
+                                     not wp.skip_sanity_check, wp.nan_guard)
         if wp.stop_after_prepare:
             log.info("--stop-after-prepare: halting before train")
             return []
@@ -212,15 +214,17 @@ class Engine:
                 # cost-based placement (--device=auto): _train_placed
                 # swaps the mesh for this stage and restores it on every
                 # exit path (workflow/placement.py)
-                model = self._train_placed(
-                    ctx, algo, name, pd, getattr(wp, "device", "auto"))
+                with telemetry.span("dase.algo_train",
+                                    algorithm=name or "default"):
+                    model = self._train_placed(
+                        ctx, algo, name, pd, getattr(wp, "device", "auto"))
+                    self._maybe_sanity_check(
+                        model, f"algorithm[{name or 'default'}]",
+                        not wp.skip_sanity_check, wp.nan_guard)
             finally:
                 if root_hook is not None:
                     ctx.checkpoint_hook.close()
                     ctx.checkpoint_hook = root_hook
-            self._maybe_sanity_check(
-                model, f"algorithm[{name or 'default'}]",
-                not wp.skip_sanity_check, wp.nan_guard)
             models.append(model)
         return models
 
@@ -294,42 +298,36 @@ class Deployment:
         self.serving = serving
 
     def query(self, q) -> Any:
-        # Stage telemetry: histogram observations per stage, and —
-        # when the HTTP layer sampled this request (trace context
-        # propagates through asyncio.to_thread) — one span per stage.
-        # Each stage opens with a chaos fault point (latency/hang/fail
-        # injection on the serving path, the overload harness's slow-
-        # model lever) and a deadline spend-point: a worker thread past
-        # its request's budget frees itself at the next stage boundary
-        # instead of finishing work for a client that already got 504.
+        # Stage telemetry: one span per stage (a child of the request's
+        # root: the open span propagates through the copied context into
+        # the executor thread) and the same duration into the stage
+        # histogram. Each stage opens with a chaos fault point
+        # (latency/hang/fail injection on the serving path, the overload
+        # harness's slow-model lever) and a deadline spend-point: a
+        # worker thread past its request's budget frees itself at the
+        # next stage boundary instead of finishing work for a client
+        # that already got 504.
         dl = deadline.current()
-        tr = telemetry.current_trace()
-        t0 = (time.perf_counter_ns()
-              if tr is not None else telemetry.timer_start())
-        faultinject.fault_point("query.featurize")
-        q = self.serving.supplement(q)
-        t1 = time.perf_counter_ns() if t0 else 0
-        _ST_FEATURIZE.observe_since(t0)
-        if dl is not None:
-            dl.check("query.predict")
-        faultinject.fault_point("query.predict")
-        predictions = [
-            algo.predict(model, q)
-            for (_, algo), model in zip(self.algo_list, self.models)
-        ]
-        t2 = time.perf_counter_ns() if t0 else 0
-        _ST_PREDICT.observe_since(t1)
-        if dl is not None:
-            dl.check("query.serve")
-        faultinject.fault_point("query.serve")
-        result = self.serving.serve(q, predictions)
-        _ST_SERVE.observe_since(t2)
-        if tr is not None:
-            t3 = time.perf_counter_ns()
-            tr.add_span("query.featurize", t1 - t0)
-            tr.add_span("query.predict", t2 - t1,
-                        algorithms=len(self.algo_list))
-            tr.add_span("query.serve", t3 - t2)
+        with telemetry.span("query.featurize") as sp:
+            faultinject.fault_point("query.featurize")
+            q = self.serving.supplement(q)
+        _ST_FEATURIZE.observe_raw(sp.dur_ns)
+        with telemetry.span("query.predict",
+                            algorithms=len(self.algo_list)) as sp:
+            if dl is not None:
+                dl.check("query.predict")
+            faultinject.fault_point("query.predict")
+            predictions = [
+                algo.predict(model, q)
+                for (_, algo), model in zip(self.algo_list, self.models)
+            ]
+        _ST_PREDICT.observe_raw(sp.dur_ns)
+        with telemetry.span("query.serve") as sp:
+            if dl is not None:
+                dl.check("query.serve")
+            faultinject.fault_point("query.serve")
+            result = self.serving.serve(q, predictions)
+        _ST_SERVE.observe_raw(sp.dur_ns)
         return result
 
     def batch_query(self, queries) -> list[Any]:

@@ -272,9 +272,6 @@ class ALSAlgorithm(Algorithm):
                 nan_guard=bool(ctx and ctx.workflow_params.nan_guard),
                 nan_guard_stage=getattr(ctx, "stage_label",
                                         "algorithm[als]"),
-                # bench.py measures the real product path by planting a
-                # timings dict on the context; absent in normal training.
-                timings=getattr(ctx, "bench_timings", None),
                 pipeline=pipeline_of(ctx),
             )
         model = ALSModel(factors=factors, users=pd.users, items=pd.items)

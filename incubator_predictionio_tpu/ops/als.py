@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from ..common import telemetry
 from ..common.faultinject import fault_point
 from ..parallel import supervisor as gang
 
@@ -729,7 +730,6 @@ def train_als(
     mesh: Optional[Mesh] = None,
     checkpoint_hook=None,
     resume: bool = False,
-    timings: Optional[dict] = None,
     nan_guard: bool = False,
     nan_guard_stage: str = "algorithm[als]",
     pipeline=None,
@@ -750,11 +750,12 @@ def train_als(
     bitwise-identical math to the single fori_loop. The reference cannot do
     this at all — a failed Spark ALS job restarts from zero (SURVEY.md §5.4).
 
-    ``timings``: pass a dict to get the bench-grade phase breakdown
-    (upload / compile / steady-state device seconds, each closed by a
-    scalar readback that depends on the result). This is how bench.py
-    measures the REAL product path: `pio train` → Engine.train →
-    ALSAlgorithm → here. Single-process, non-checkpoint-chunked runs only.
+    Phases are spans (common/telemetry.py): ``als.layout``, ``als.init``,
+    ``als.pack``, ``als.upload`` (the host's part of the transfer; no
+    barrier, so what the transfer still owes lies in the loop),
+    ``als.loop`` (dispatch until the factors are ready; one per dispatch
+    in the ``nan_guard`` and checkpoint-chunked branches, a compile its
+    ``xla.compile`` child) and ``als.readback``.
     """
     mesh = mesh or default_mesh()
     d_size, m_size = _mesh_dims(mesh)
@@ -773,10 +774,11 @@ def train_als(
         from ..workflow.input_pipeline import PipelineConfig
 
         pipeline = PipelineConfig.from_env()
-    plan_u, plan_i, arrs_u, arrs_i = plan_and_fill_both(
-        user_idx, item_idx, rating, n_users, n_items, d_size,
-        m_div=m_size, fill_vals=not params.binary_ratings,
-        parallel=pipeline.mode != "off")
+    with telemetry.span("als.layout"):
+        plan_u, plan_i, arrs_u, arrs_i = plan_and_fill_both(
+            user_idx, item_idx, rating, n_users, n_items, d_size,
+            m_div=m_size, fill_vals=not params.binary_ratings,
+            parallel=pipeline.mode != "off")
 
     k = params.rank
     x_shape = (plan_u.total_slots, k)
@@ -838,82 +840,58 @@ def train_als(
             )
 
     if x0 is None:
-        x0, y0 = _fresh_init(params, plan_u, plan_i, n_users, n_items)
+        with telemetry.span("als.init"):
+            x0, y0 = _fresh_init(params, plan_u, plan_i, n_users, n_items)
     fn, in_shardings = _cached_train_fn(mesh, params, plan_u, plan_i)
     binary = bool(params.binary_ratings)
-    flat = tuple(
-        _side_flat(arrs_u, plan_u, _host_lam(plan_u, params), binary,
-                   col_sentinel=plan_i.total_slots)
-        + _side_flat(arrs_i, plan_i, _host_lam(plan_i, params), binary,
-                     col_sentinel=plan_u.total_slots))
-    if jax.process_count() > 1:
-        # Multi-controller: every process holds the SAME full numpy
-        # arrays (the event store is shared), so build global jax.Arrays
-        # explicitly — jit refuses sharded numpy inputs across processes.
-        def _globalize(host, sharding):
-            return jax.make_array_from_callback(
-                host.shape, sharding, lambda idx: host[idx]
-            )
-
-        x0 = _globalize(np.asarray(x0), in_shardings[1])
-        y0 = _globalize(np.asarray(y0), in_shardings[2])
-        flat = tuple(
-            _globalize(np.asarray(b), s)
-            for b, s in zip(flat, in_shardings[3:])
-        )
-    chunk = checkpoint_hook.every_n if checkpoint_hook is not None and checkpoint_hook.enabled else 0
-    timed_path = (not nan_guard
-                  and timings is not None and jax.process_count() == 1
-                  and not (chunk and params.num_iterations - start_iter > chunk))
     # Single-device runs pack the slabs: 2-3 large transfers instead of
     # ~70 small ones (see _pack_flat). run_fn/run_args abstract over
     # packed vs per-slab.
     packed = jax.process_count() == 1 and mesh.devices.size == 1
-    if packed:
-        bufs, pack_key = _pack_flat(flat)
-        run_fn = _cached_packed_train_fn(mesh, params, plan_u, plan_i,
-                                         pack_key)
-        run_args = bufs
-        dev = mesh.devices.flat[0]
-        put_args = lambda: tuple(_cached_dev_put(b, dev) for b in run_args)  # noqa: E731
-    else:
-        run_fn = fn
-        run_args = flat
-        put_args = lambda: tuple(  # noqa: E731
-            fast_put(np.asarray(b), sh)
-            for b, sh in zip(run_args, in_shardings[3:]))
-    if jax.process_count() == 1 and not timed_path:
-        # Explicit transfers (plain single-device puts on a one-device
-        # mesh, see fast_put) instead of handing jit raw numpy inputs.
-        # The timed branch below does its own (timed) puts instead.
-        x0 = fast_put(np.asarray(x0), in_shardings[1])
-        y0 = fast_put(np.asarray(y0), in_shardings[2])
-        run_args = put_args()
-    if timed_path:
-        import time as _time
+    with telemetry.span("als.pack"):
+        flat = tuple(
+            _side_flat(arrs_u, plan_u, _host_lam(plan_u, params), binary,
+                       col_sentinel=plan_i.total_slots)
+            + _side_flat(arrs_i, plan_i, _host_lam(plan_i, params), binary,
+                         col_sentinel=plan_u.total_slots))
+        if packed:
+            bufs, pack_key = _pack_flat(flat)
+    run_fn = (_cached_packed_train_fn(mesh, params, plan_u, plan_i, pack_key)
+              if packed else fn)
+    # No barrier after the puts: what the transfer still owes when they
+    # return lies in als.loop.
+    with telemetry.span("als.upload"):
+        if jax.process_count() > 1:
+            # Multi-controller: every process holds the SAME full numpy
+            # arrays (the event store is shared), so build global
+            # jax.Arrays explicitly — jit refuses sharded numpy inputs
+            # across processes.
+            def _globalize(host, sharding):
+                return jax.make_array_from_callback(
+                    host.shape, sharding, lambda idx: host[idx]
+                )
 
-        t0 = _time.perf_counter()
-        dx0 = fast_put(np.asarray(x0), in_shardings[1])
-        dy0 = fast_put(np.asarray(y0), in_shardings[2])
-        dev_args = put_args()
-        jax.block_until_ready((dx0, dy0, dev_args))
-        timings["upload_seconds"] = _time.perf_counter() - t0
-
-        n = np.int32(params.num_iterations - start_iter)
-        t0 = _time.perf_counter()
-        compiled = run_fn.lower(n, dx0, dy0, *dev_args).compile()
-        timings["compile_seconds"] = _time.perf_counter() - t0
-
-        # Warm-up dispatch (n_iters is traced: same executable, zero work),
-        # then the timed run with a scalar readback as the completion
-        # barrier: the fetched slice depends on the whole loop.
-        warm = compiled(np.int32(0), dx0, dy0, *dev_args)
-        _ = jax.device_get(warm[0][:1, :1])
-        t0 = _time.perf_counter()
-        x, y = compiled(n, dx0, dy0, *dev_args)
-        _ = jax.device_get(x[:1, :1])
-        timings["device_train_seconds"] = _time.perf_counter() - t0
-    elif nan_guard:
+            x0 = _globalize(np.asarray(x0), in_shardings[1])
+            y0 = _globalize(np.asarray(y0), in_shardings[2])
+            run_args = tuple(
+                _globalize(np.asarray(b), s)
+                for b, s in zip(flat, in_shardings[3:])
+            )
+        else:
+            # Explicit transfers (plain single-device puts on a
+            # one-device mesh, see fast_put) instead of handing jit raw
+            # numpy inputs.
+            x0 = fast_put(np.asarray(x0), in_shardings[1])
+            y0 = fast_put(np.asarray(y0), in_shardings[2])
+            if packed:
+                dev = mesh.devices.flat[0]
+                run_args = tuple(_cached_dev_put(b, dev) for b in bufs)
+            else:
+                run_args = tuple(
+                    fast_put(np.asarray(b), sh)
+                    for b, sh in zip(flat, in_shardings[3:]))
+    chunk = checkpoint_hook.every_n if checkpoint_hook is not None and checkpoint_hook.enabled else 0
+    if nan_guard:
         # Sanitizer tier: one dispatch per iteration + a device-side
         # finite reduction (ONE scalar fetched per iteration, not the
         # full factor matrices), so the failure names the iteration that
@@ -925,12 +903,15 @@ def train_als(
         x, y = x0, y0
         for it in range(start_iter, params.num_iterations):
             fault_point("train.sweep")
-            x, y = run_fn(np.int32(1), x, y, *run_args)
-            # Beat AFTER the dispatch: the first sweep includes the XLA
-            # compile, and the supervisor's stall detector only arms at
-            # the first beat (init grace covers everything before it).
-            gang.beat()
-            if not bool(jax.device_get(finite_probe(x, y))):
+            with telemetry.span("als.loop"):
+                x, y = run_fn(np.int32(1), x, y, *run_args)
+                # Beat AFTER the dispatch: the first sweep includes the
+                # XLA compile, and the supervisor's stall detector only
+                # arms at the first beat (init grace covers everything
+                # before it).
+                gang.beat()
+                finite = bool(jax.device_get(finite_probe(x, y)))
+            if not finite:
                 raise NaNGuardError(
                     f"stage: {nan_guard_stage}, iteration {it + 1}: "
                     "non-finite factors (check input ratings for NaN/Inf "
@@ -961,8 +942,10 @@ def train_als(
         while it < params.num_iterations:
             fault_point("train.sweep")
             n = min(chunk, params.num_iterations - it)
-            x, y = run_fn(n, x, y, *run_args)
-            gang.beat()  # after the dispatch: sweep 1 includes compile
+            with telemetry.span("als.loop"):
+                x, y = run_fn(n, x, y, *run_args)
+                gang.beat()  # after the dispatch: sweep 1 includes compile
+                jax.block_until_ready((x, y))  # the save would wait anyway
             it += n
             if it < params.num_iterations:
                 checkpoint_hook.save(
@@ -973,15 +956,45 @@ def train_als(
                 if gang.drain_requested_global():
                     raise gang.GangDrainRequested(it)
     else:
-        x, y = run_fn(params.num_iterations - start_iter, x0, y0, *run_args)
-        gang.beat()
-    x, y = jax.device_get((x, y))
+        with telemetry.span("als.loop"):
+            x, y = run_fn(params.num_iterations - start_iter, x0, y0,
+                          *run_args)
+            gang.beat()
+            # the device_get below would block anyway; waiting here keeps
+            # the device loop and the readback apart
+            jax.block_until_ready((x, y))
+    with telemetry.span("als.readback"):
+        x, y = jax.device_get((x, y))
+        user_factors = np.asarray(x)[plan_u.slot_of_row]
+        item_factors = np.asarray(y)[plan_i.slot_of_row]
     return ALSFactors(
-        user_factors=np.asarray(x)[plan_u.slot_of_row],
-        item_factors=np.asarray(y)[plan_i.slot_of_row],
+        user_factors=user_factors,
+        item_factors=item_factors,
         n_users=n_users,
         n_items=n_items,
     )
+
+
+def train_phase_seconds(since_ns: int) -> dict[str, float]:
+    """Upload, compile and device-loop seconds of the train_als calls that
+    started after ``since_ns`` (a ``time.perf_counter_ns`` reading), read
+    from the span ring: what bench.py and tools/profile_similar.py print.
+    ``compile`` is the ``xla.compile`` children of ``als.loop`` (a cache
+    load counts) and ``device_train`` is the loop less them."""
+    spans = [s for s in telemetry.spans_snapshot() if s.t0_ns >= since_ns]
+    loops = {s.span_id for s in spans if s.name == "als.loop"}
+
+    def seconds(pick) -> float:
+        return sum(s.t1_ns - s.t0_ns for s in spans if pick(s)) * 1e-9
+
+    compiles = seconds(
+        lambda s: s.name == "xla.compile" and s.parent_id in loops)
+    return {
+        "upload_seconds": seconds(lambda s: s.name == "als.upload"),
+        "compile_seconds": compiles,
+        "device_train_seconds":
+            seconds(lambda s: s.name == "als.loop") - compiles,
+    }
 
 
 def process_row_ranges(n_rows: int, mesh: Optional[Mesh] = None

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..common import telemetry
+
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _topk_scores(user_vec, item_factors, exclude_mask, k: int):
@@ -52,10 +54,15 @@ def top_k_items(user_vec, item_factors, k: int, exclude=None):
     # arguments go to the jitted kernel RAW: jit's C++ dispatch commits
     # them to device far cheaper than eager jnp.asarray per query
     # (measured ~0.4 ms/query of lax_numpy/bind machinery saved)
-    out = _topk_scores(user_vec, item_factors, exclude, k)
+    # topk.dispatch: the enqueue (a first k's compile shows here, with
+    # an xla.compile child); topk.wait: the device's queue, the scan and
+    # the readback.
+    with telemetry.span("topk.dispatch"):
+        out = _topk_scores(user_vec, item_factors, exclude, k)
     # Single host transfer: each device_get is a round trip, so (scores,
     # idx) come back together.
-    return jax.device_get(out)
+    with telemetry.span("topk.wait"):
+        return jax.device_get(out)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

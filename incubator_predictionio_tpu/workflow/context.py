@@ -41,16 +41,27 @@ def enable_compilation_cache() -> None:
     first compile that finds no directory, so the compiling verbs call
     this before they import an engine module, and WorkflowContext calls
     it again for library users who never pass through the CLI.
-    PIO_COMPILATION_CACHE=0 opts out (and keeps jax un-imported);
-    sub-second compiles are skipped by JAX's default
-    jax_persistent_cache_min_compile_time_secs=1.
+    PIO_COMPILATION_CACHE=0 opts out; sub-second compiles are skipped
+    by JAX's default jax_persistent_cache_min_compile_time_secs=1.
+
+    The same once-per-process moment hands jax to common/telemetry.py
+    (which never imports it): open spans get a profiler annotation, and
+    every backend compile becomes an ``xla.compile`` span and counts in
+    ``pio_xla_*`` on ``/metrics``, cache or no cache.
     """
     global _cache_enabled
     if _cache_enabled:
         return
     _cache_enabled = True
-    from ..common import envknobs
+    from ..common import envknobs, telemetry
 
+    try:
+        import jax
+
+        telemetry.install_xla_hooks(jax)
+    except Exception:  # noqa: BLE001 - telemetry must not stop a train
+        log.warning("compiles will not show as spans or on /metrics",
+                    exc_info=True)
     if not envknobs.env_flag("PIO_COMPILATION_CACHE", True):
         return
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):

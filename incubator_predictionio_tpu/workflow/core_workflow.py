@@ -19,7 +19,7 @@ import pickle
 import socket
 from typing import Any, Optional
 
-from ..common import envknobs
+from ..common import envknobs, telemetry
 from ..controller.engine import Engine, EngineParams
 from ..controller.persistent_model import PersistentModel
 from ..data.storage.base import EngineInstance
@@ -368,51 +368,66 @@ def run_train(
         return contextlib.nullcontext()
 
     try:
-        models = _train_with_stale_checkpoint_fallback(
-            engine, engine_params, ctx, wp, cm=_profile_cm)
-        gang.beat()
-        if wp.stop_after_read or wp.stop_after_prepare:
-            instances.update(instance.with_status("ABORTED", _utcnow()))
-            if ctx.checkpoint_hook is not None:
-                ctx.checkpoint_hook.close()
-                ctx.checkpoint_hook = None
-            return instance_id
+        # the train's root span: every dase.* / als.* / xla.compile span
+        # beneath it shares its trace id, the engine-instance id
+        with telemetry.span("train.run", trace_id=instance_id,
+                            instance=instance_id,
+                            factory=engine_factory_name):
+            models = _train_with_stale_checkpoint_fallback(
+                engine, engine_params, ctx, wp, cm=_profile_cm)
+            gang.beat()
+            if wp.stop_after_read or wp.stop_after_prepare:
+                instances.update(instance.with_status("ABORTED", _utcnow()))
+                if ctx.checkpoint_hook is not None:
+                    ctx.checkpoint_hook.close()
+                    ctx.checkpoint_hook = None
+                return instance_id
 
-        # Persistence has no natural beat points, and at scale the
-        # device_get + pickle + storage insert can outlast the stall
-        # threshold — a background beat keeps the supervisor from
-        # gang-killing a job whose training already succeeded.
-        with gang.beat_while():
-            _, _, algo_list, _ = engine.make_components(engine_params)
-            persistent = 0
-            for (name, algo), model in zip(algo_list, models):
-                if isinstance(model, PersistentModel):
-                    if model.save(instance_id, algo.params):
-                        persistent += 1
-            blob = serialize_models(algo_list, models)
-            # Checksummed artifact via the single verifying-writer path
-            # (workflow/model_artifact.py). The Model row MUST land
-            # before the COMPLETED stamp below: a crash in between
-            # leaves a RUNNING row (never deployed) instead of a
-            # COMPLETED row without a model — and the verifying loader
-            # skips the latter anyway, for rows written by older code.
-            sha = model_artifact.write_model(storage, instance_id, blob)
-            log.info(
-                "models persisted: %d bytes pickled (sha256 %s), "
-                "%d self-persisted",
-                len(blob), sha[:12], persistent,
-            )
-            done = EngineInstance(
-                **{**instance.__dict__, "id": instance_id}
-            ).with_status("COMPLETED", _utcnow())
-            instances.update(done)
-            if ctx.checkpoint_hook is not None:
-                ctx.checkpoint_hook.delete_all()  # superseded by the model
-                ctx.checkpoint_hook = None
-        _persist_foldin_anchor(storage, foldin_anchor, ctx,
-                               engine_factory_name, engine_variant)
-        log.info("EngineInstance %s COMPLETED", instance_id)
-        return instance_id
+            # Persistence has no natural beat points, and at scale the
+            # device_get + pickle + storage insert can outlast the stall
+            # threshold — a background beat keeps the supervisor from
+            # gang-killing a job whose training already succeeded.
+            with gang.beat_while():
+                _, _, algo_list, _ = engine.make_components(engine_params)
+                persistent = sum(
+                    1 for (_, algo), model in zip(algo_list, models)
+                    if isinstance(model, PersistentModel)
+                    and model.save(instance_id, algo.params))
+                # The models and then the blob are dropped where their
+                # last use is timed, not when this function returns: at
+                # 2.4 GB each their release is a third of a second that
+                # would otherwise fall after train.run closed, and the
+                # models no longer sit beside two copies of the blob.
+                with telemetry.span("dase.serialize"):
+                    blob = serialize_models(algo_list, models)
+                    del models
+                # Checksummed artifact via the single verifying-writer path
+                # (workflow/model_artifact.py). The Model row MUST land
+                # before the COMPLETED stamp below: a crash in between
+                # leaves a RUNNING row (never deployed) instead of a
+                # COMPLETED row without a model — and the verifying loader
+                # skips the latter anyway, for rows written by older code.
+                n_bytes = len(blob)
+                with telemetry.span("dase.persist", bytes=n_bytes):
+                    sha = model_artifact.write_model(storage, instance_id,
+                                                     blob)
+                    del blob
+                log.info(
+                    "models persisted: %d bytes pickled (sha256 %s), "
+                    "%d self-persisted",
+                    n_bytes, sha[:12], persistent,
+                )
+                done = EngineInstance(
+                    **{**instance.__dict__, "id": instance_id}
+                ).with_status("COMPLETED", _utcnow())
+                instances.update(done)
+                if ctx.checkpoint_hook is not None:
+                    ctx.checkpoint_hook.delete_all()  # superseded by the model
+                    ctx.checkpoint_hook = None
+            _persist_foldin_anchor(storage, foldin_anchor, ctx,
+                                   engine_factory_name, engine_variant)
+            log.info("EngineInstance %s COMPLETED", instance_id)
+            return instance_id
     except Exception:
         # Best-effort ABORTED stamp: when the failure IS the storage
         # backend (dead store, open breaker), this second write fails

@@ -1155,8 +1155,9 @@ class EngineServer:
             return None
         return deadline.Deadline(budget_ms)
 
-    def _admit(self) -> None:
-        """Take one admission slot or refuse. A slot covers the query
+    def _admit(self) -> int:
+        """Take one admission slot or refuse; returns the admitted
+        queries now pending, this one included. A slot covers the query
         from acceptance until its compute FINISHES — including workers
         that overran their deadline after the client got its 504
         (threads can't be killed), so orphaned work keeps counting
@@ -1173,6 +1174,7 @@ class EngineServer:
             self._adm_pending += 1
             if self._adm_pending > self._adm_peak:
                 self._adm_peak = self._adm_pending
+            return self._adm_pending
 
     def _release_slot(self, fut=None) -> None:
         """Admission-slot release; done-callback on both asyncio and
@@ -1188,11 +1190,17 @@ class EngineServer:
         with self._adm_lock:
             self._adm_pending -= 1
 
-    def _run_admitted_query(self, deployment, query):
-        """Executor-thread entry. Re-checks the budget first: a query
-        that spent its whole deadline WAITING in the executor queue
-        frees the worker immediately instead of computing an answer
-        nobody is waiting for."""
+    def _run_admitted_query(self, deployment, query, admitted_ns=0,
+                            pending=0):
+        """Executor-thread entry. Closes ``query.admit_wait`` (admission
+        to a worker picking the query up; ``pending`` = admitted queries
+        at admission, this one included), then re-checks the budget: a
+        query that spent its whole deadline WAITING in the executor
+        queue frees the worker immediately instead of computing an
+        answer nobody is waiting for."""
+        if admitted_ns:
+            telemetry.add_span("query.admit_wait", admitted_ns,
+                               _time.perf_counter_ns(), pending=pending)
         dl = deadline.current()
         if dl is not None:
             dl.check("executor pickup")
@@ -1214,7 +1222,8 @@ class EngineServer:
         :class:`deadline.DeadlineExceeded` (→ 504)."""
         if dl is not None:
             dl.check("admission")
-        self._admit()
+        pending = self._admit()
+        admitted_ns = telemetry.timer_start()
         slot_owned_by_future = False
         try:
             timeout = dl.remaining() if dl is not None else None
@@ -1232,11 +1241,12 @@ class EngineServer:
                         dl.budget_ms, dl.overrun_ms(),
                         "batch queue") from None
             # deadline rides the copied context into the worker thread
-            # (same mechanism that carries the trace context)
+            # (same mechanism that carries the open span and the trace)
             with deadline.running(dl):
                 ctx = contextvars.copy_context()
             cfut = self._query_executor.submit(
-                ctx.run, self._run_admitted_query, deployment, query)
+                ctx.run, self._run_admitted_query, deployment, query,
+                admitted_ns, pending)
             cfut.add_done_callback(self._release_slot)
             slot_owned_by_future = True
             afut = asyncio.wrap_future(cfut)

@@ -89,18 +89,41 @@ def test_template_defaults_are_auto():
     assert ap.chunk_tiles == -1
 
 
-def test_timings_hook_through_train_als():
-    """The bench instrumentation path returns the same factors as the
-    plain path (same executable, explicit upload/compile phases)."""
+def test_phase_spans_through_train_als():
+    """The plain path — the only one — records its phases as spans
+    (upload, the loop with its compile beneath it), which is what
+    bench.py reads, and a second call returns the same factors."""
+    import time
+
+    from incubator_predictionio_tpu.common import telemetry
+    from incubator_predictionio_tpu.ops.als import train_phase_seconds
+    from incubator_predictionio_tpu.workflow.context import (
+        enable_compilation_cache,
+    )
+
+    enable_compilation_cache()  # hands jax to telemetry: xla.compile spans
     rng = np.random.default_rng(4)
     u = rng.integers(0, 25, 300).astype(np.int32)
     i = rng.integers(0, 15, 300).astype(np.int32)
     r = rng.random(300).astype(np.float32)
     mesh = mesh_from_devices(devices=jax.devices("cpu")[:4])
-    p = ALSParams(rank=4, num_iterations=3)
-    plain = train_als(u, i, r, 25, 15, p, mesh=mesh)
-    t = {}
-    timed = train_als(u, i, r, 25, 15, p, mesh=mesh, timings=t)
-    assert {"upload_seconds", "compile_seconds",
-            "device_train_seconds"} <= set(t)
-    np.testing.assert_array_equal(plain.user_factors, timed.user_factors)
+    p = ALSParams(rank=5, num_iterations=3)  # a rank no other test compiles
+    since_ns = time.perf_counter_ns()
+    first = train_als(u, i, r, 25, 15, p, mesh=mesh)
+    spans = [s for s in telemetry.spans_snapshot() if s.t0_ns >= since_ns]
+    names = [s.name for s in spans]
+    for name in ("als.layout", "als.init", "als.pack", "als.upload",
+                 "als.loop", "als.readback"):
+        assert names.count(name) == 1, (name, names)
+    loop = next(s for s in spans if s.name == "als.loop")
+    compiles = [s for s in spans if s.name == "xla.compile"
+                and s.parent_id == loop.span_id]
+    assert compiles and all(s.trace_id == loop.trace_id for s in compiles)
+    t = train_phase_seconds(since_ns)
+    assert set(t) == {"upload_seconds", "compile_seconds",
+                      "device_train_seconds"}
+    assert t["compile_seconds"] > 0 and t["device_train_seconds"] > 0
+    again_ns = time.perf_counter_ns()
+    second = train_als(u, i, r, 25, 15, p, mesh=mesh)
+    assert train_phase_seconds(again_ns)["compile_seconds"] == 0
+    np.testing.assert_array_equal(first.user_factors, second.user_factors)

@@ -355,6 +355,243 @@ def test_event_server_trace_header_echo(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# spans: the one primitive of training and serving
+# ---------------------------------------------------------------------------
+
+def _since(t0_ns):
+    return [s for s in telemetry.spans_snapshot() if s.t0_ns >= t0_ns]
+
+
+def _covered_share(root, spans):
+    """Share of ``root`` covered by the union of the spans beneath it that
+    name a piece of work (dase.algo_train only groups the als.* spans)."""
+    ivs = sorted((s.t0_ns, s.t1_ns) for s in spans
+                 if s.trace_id == root.trace_id and s is not root
+                 and s.name != "dase.algo_train")
+    covered, at = 0, root.t0_ns
+    for a, b in ivs:
+        a, b = max(a, at), min(b, root.t1_ns)
+        if b > a:
+            covered, at = covered + b - a, b
+    return covered / (root.t1_ns - root.t0_ns)
+
+
+def test_span_tree_crosses_copy_context_into_worker_thread():
+    """Trace id and parent ride the contextvars slot: a span opened in a
+    worker thread under a copied context is a child of the span that was
+    open when the context was copied — the query executor's mechanism."""
+    import contextvars
+    import time
+    from concurrent.futures import ThreadPoolExecutor
+
+    def work():
+        with telemetry.span("t.tree.child", where="worker"):
+            telemetry.add_span("t.tree.after", time.perf_counter_ns() - 10,
+                               time.perf_counter_ns())
+        return threading.get_ident()
+
+    t0 = time.perf_counter_ns()
+    with ThreadPoolExecutor(1) as pool:
+        with telemetry.span("t.tree.root", trace_id="inst-1") as root:
+            ctx = contextvars.copy_context()
+            tid = pool.submit(ctx.run, work).result()
+            bare = pool.submit(work).result()  # no copied context
+    assert tid == bare != threading.get_ident()
+    spans = {(s.name, s.trace_id): s for s in _since(t0)}
+    r = spans[("t.tree.root", "inst-1")]
+    child = spans[("t.tree.child", "inst-1")]
+    after = spans[("t.tree.after", "inst-1")]
+    assert r.parent_id is None and r.span_id == root.span_id
+    assert child.parent_id == r.span_id and after.parent_id == child.span_id
+    assert child.tags == {"where": "worker"}
+    assert r.t0_ns <= child.t0_ns <= child.t1_ns <= r.t1_ns
+    # without the copied context the worker's span is a root of its own,
+    # under a small integer id
+    orphan = [s for s in _since(t0) if s.name == "t.tree.child"
+              and s.trace_id != "inst-1"]
+    assert len(orphan) == 1 and orphan[0].parent_id is None
+    assert isinstance(orphan[0].trace_id, int)
+
+
+def test_span_ring_is_bounded():
+    assert telemetry.RING_SIZE == 65536
+    for _ in range(telemetry.RING_SIZE + 10):
+        with telemetry.span("t.ring"):
+            pass
+    snap = telemetry.spans_snapshot()
+    assert len(snap) == telemetry.RING_SIZE
+    assert all(s.name == "t.ring" for s in snap[:10])      # older ones left
+    assert snap[0].span_id < snap[-1].span_id               # oldest first
+    snap.clear()                                            # a copy
+    assert len(telemetry.spans_snapshot()) == telemetry.RING_SIZE
+
+
+def test_telemetry_import_does_not_import_jax():
+    """The event server records here and never loads jax: a fresh
+    interpreter that imports telemetry and opens a span has no jax."""
+    import subprocess
+
+    code = ("import sys\n"
+            "from incubator_predictionio_tpu.common import telemetry\n"
+            "with telemetry.span('x'):\n    pass\n"
+            "assert telemetry.spans_snapshot()[-1].name == 'x'\n"
+            "print('JAX' if 'jax' in sys.modules else 'CLEAN')\n")
+    root = os.path.dirname(os.path.dirname(
+        os.path.abspath(incubator_predictionio_tpu.__file__)))
+    r = subprocess.run([sys.executable, "-c", code], cwd=root,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.strip() == "CLEAN"
+
+
+def test_compiles_are_spans_and_counters():
+    """One jax.monitoring listener: every backend compile is an
+    xla.compile span under whatever was open, and counts on /metrics."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from incubator_predictionio_tpu.workflow.context import WorkflowContext
+
+    WorkflowContext()  # hands jax over, once per process
+    reg = telemetry.registry()
+    n = reg.counter("pio_xla_compiles_total", "").labels()
+    secs = reg.counter("pio_xla_compile_seconds_total", "").labels()
+    before, secs_before = n.value(), secs.value()
+    t0 = time.perf_counter_ns()
+    with telemetry.span("t.compile.parent") as parent:
+        jax.jit(lambda x: jnp.tanh(x) * 1.2345 + 6.789)(
+            jnp.ones((3, 7))).block_until_ready()
+    assert n.value() > before and secs.value() > secs_before
+    mine = [s for s in _since(t0) if s.name == "xla.compile"]
+    assert mine and all(s.parent_id == parent.span_id for s in mine)
+    assert all(s.tags["seconds"] > 0 and s.t0_ns < s.t1_ns for s in mine)
+    body = telemetry.render_all()
+    assert "# TYPE pio_xla_compiles_total counter" in body
+    assert "# TYPE pio_xla_cache_events_total counter" in body
+
+
+def test_run_train_leaves_its_spans_under_one_root(memory_storage):
+    """A tiny run_train on the CPU: the training table's spans all hang
+    under one train.run root whose trace id is the engine-instance id,
+    and they cover it (nothing large happens between them)."""
+    import time
+
+    from incubator_predictionio_tpu.models.recommendation import (
+        RecommendationEngine)
+    from incubator_predictionio_tpu.workflow.context import WorkflowContext
+    from incubator_predictionio_tpu.workflow.core_workflow import run_train
+
+    from incubator_predictionio_tpu.controller.engine import EngineParams
+
+    from test_dase_train_e2e import _seed_ratings
+
+    _seed_ratings(memory_storage)
+    engine = RecommendationEngine()()
+    ctx = WorkflowContext(app_name="testapp", storage=memory_storage)
+    # a rank no other test trains: the step compiles here, as in a first
+    # `pio train`, so the train is a second long and not 10 ms of glue
+    params = EngineParams.from_json({
+        "datasource": {"params": {"app_name": "testapp"}},
+        "algorithms": [{"name": "als", "params": {
+            "rank": 7, "numIterations": 3, "lambda": 0.05}}]})
+    t0 = time.perf_counter_ns()
+    instance_id = run_train(engine, params, ctx, engine_factory_name="rec")
+    spans = _since(t0)
+    roots = [s for s in spans if s.name == "train.run"]
+    assert len(roots) == 1
+    root = roots[0]
+    assert root.trace_id == instance_id and root.parent_id is None
+    assert root.tags == {"instance": instance_id, "factory": "rec"}
+    mine = [s for s in spans if s.trace_id == instance_id]
+    by_name = {s.name: s for s in mine}
+    assert {"dase.read", "dase.prepare", "dase.algo_train", "als.layout",
+            "als.init", "als.pack", "als.upload", "als.loop",
+            "als.readback", "dase.serialize", "dase.persist"} <= set(by_name)
+    ids = {s.span_id: s for s in mine}
+    for s in mine:
+        if s is not root:
+            assert s.parent_id in ids, s.name
+    algo = by_name["dase.algo_train"]
+    assert algo.tags["algorithm"] and algo.parent_id == root.span_id
+    assert by_name["als.loop"].parent_id == algo.span_id
+    assert by_name["xla.compile"].parent_id == by_name["als.loop"].span_id
+    assert by_name["dase.persist"].tags["bytes"] > 0
+    order = ["dase.read", "dase.prepare", "als.layout", "als.init",
+             "als.pack", "als.upload", "als.loop", "als.readback",
+             "dase.serialize", "dase.persist"]
+    starts = [by_name[n].t0_ns for n in order]
+    assert starts == sorted(starts)
+    assert _covered_share(root, mine) >= 0.95
+
+
+def test_served_query_leaves_wait_and_topk_spans_under_its_root(
+        memory_storage, tmp_path):
+    """One POST /queries.json: the root from the middleware, the wait for
+    an executor thread, the three stages and the top-k's two halves, one
+    tree. query.featurize starts before query.predict (the old Trace
+    stamped every start at the time of the call, after the last stage);
+    the sink's line keeps its four old fields and gains the two ids."""
+    import time
+
+    sink = tmp_path / "spans.jsonl"
+    telemetry.configure_tracer(rate=1.0, sink=str(sink))
+    try:
+        server = _trained_engine_server(memory_storage)
+        with ServerThread(server.app) as st:
+            t0 = time.perf_counter_ns()
+            r = requests.post(st.base + "/queries.json",
+                              json={"user": "1", "num": 2},
+                              headers={"X-Pio-Trace-Id": "feedface02"})
+            assert r.status_code == 200
+            missing = requests.post(st.base + "/nothing-here", json={})
+            assert missing.status_code == 404
+    finally:
+        telemetry.configure_tracer(rate=0.0)
+    mine = [s for s in _since(t0) if s.trace_id == "feedface02"]
+    by_name = {s.name: s for s in mine}
+    root = by_name["http POST /queries.json"]
+    assert root.parent_id is None and root.tags == {"status": 200}
+    wait = by_name["query.admit_wait"]
+    assert wait.parent_id == root.span_id and wait.tags["pending"] >= 1
+    for stage in ("query.featurize", "query.predict", "query.serve"):
+        assert by_name[stage].parent_id == root.span_id
+    assert (wait.t1_ns <= by_name["query.featurize"].t0_ns
+            <= by_name["query.featurize"].t1_ns
+            <= by_name["query.predict"].t0_ns
+            <= by_name["query.predict"].t1_ns
+            <= by_name["query.serve"].t0_ns)
+    ids = {s.span_id: s for s in mine}
+    for name in ("topk.dispatch", "topk.wait"):
+        up = by_name[name]
+        while up.parent_id is not None:     # somewhere beneath predict
+            up = ids[up.parent_id]
+            if up.name == "query.predict":
+                break
+        assert up.name == "query.predict", name
+    assert by_name["topk.dispatch"].t1_ns <= by_name["topk.wait"].t0_ns
+    assert _covered_share(root, mine) > 0
+    # the 404 is a root too, with its status
+    other = [s for s in _since(t0) if s.name == "http POST /nothing-here"]
+    assert other and other[0].tags == {"status": 404}
+
+    lines = [json.loads(x) for x in sink.read_text().splitlines()]
+    sunk = {x["span"]: x for x in lines if x["traceId"] == "feedface02"}
+    assert set(by_name) <= set(sunk)
+    for x in sunk.values():
+        assert {"traceId", "span", "startUs", "durUs", "spanId",
+                "parentId"} <= set(x)
+    assert (sunk["query.featurize"]["startUs"]
+            <= sunk["query.predict"]["startUs"]
+            <= sunk["query.serve"]["startUs"])
+    assert sunk["query.predict"]["parentId"] == root.span_id
+    assert sunk["http POST /queries.json"]["parentId"] is None
+    now_us = time.time_ns() // 1000
+    assert 0 < now_us - sunk["query.featurize"]["startUs"] < 600e6  # epoch
+
+
+# ---------------------------------------------------------------------------
 # disabled-path guarantees
 # ---------------------------------------------------------------------------
 
@@ -372,9 +609,14 @@ def test_disabled_path_no_allocations():
         t0 = telemetry.timer_start()
         c.inc()
         h.observe_since(t0)
+        with telemetry.span("t.noalloc") as sp:
+            telemetry.add_span("t.noalloc.child", t0, t0)
+        h.observe_raw(sp.dur_ns)
 
+    ring_before = len(telemetry.spans_snapshot())
     telemetry.set_metrics_enabled(False)
     try:
+        assert telemetry.span("a") is telemetry.span("b")  # one shared no-op
         for _ in range(100):   # warm frames, caches, freelists
             hot_request()
         gc.collect()
@@ -390,10 +632,13 @@ def test_disabled_path_no_allocations():
     assert c.value() == 0
     _counts, total, _sum = h.snapshot()
     assert total == 0
+    assert len(telemetry.spans_snapshot()) == ring_before
 
     # and the enabled path actually records
     hot_request()
     assert c.value() == 1
+    assert [x.name for x in telemetry.spans_snapshot()[-2:]] == [
+        "t.noalloc.child", "t.noalloc"]
 
 
 def test_disabled_metrics_skip_recording():
