@@ -10,7 +10,12 @@ per-shard local scores and a per-shard local top-k, then all_gathers only
 the k-candidate (score, global-index) pairs — never a full score row — and
 merges them with a two-key lexicographic sort that reproduces single-device
 ``lax.top_k`` semantics bit-for-bit (ties break toward the lowest global
-index, exactly as ``lax.top_k`` does). Per-query collective traffic is
+index, exactly as ``lax.top_k`` does). That stays true now that the flat
+single-device path no longer calls ``lax.top_k`` on a large row:
+``ops/topk._select_topk`` returns ``lax.top_k``'s values and indices by
+construction (its docstring has the argument) and ends in this same
+two-key sort, so "what ``lax.top_k`` gives on the unsharded row" is still
+the one contract all three layouts meet. Per-query collective traffic is
 O(shards * k * 8 bytes), independent of catalog size, so it rides ICI
 comfortably at serving rates.
 

@@ -5,17 +5,94 @@ driver-side BLAS dot products + sort (SURVEY.md §3.2 hot path). TPU-native:
 one fused matvec + lax.top_k per query, jitted once per (model-shape, k);
 the engine server calls the cached executable so per-query Python work is
 JSON parsing only.
+
+"lax.top_k" names the RESULT, not always the operator: on the TPU
+`lax.top_k` of a whole score row is a full stable sort of the row (21 of
+28 ms at 9.4M items, PERF.md §5), so on a large catalog `_topk_scores`
+reaches the same values and indices, ties included, through
+`_select_topk`: block maxima, the k best blocks, a sort of their k·L
+candidates. Small catalogs call `lax.top_k` itself (`_select_block_len`).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..common import telemetry
+
+
+_M_SELECT = telemetry.registry().counter(
+    "pio_topk_select_total",
+    "Single-query top-k calls by how the k best of the score row are "
+    "selected: blocks = block maxima, the k best blocks, a sort of their "
+    "candidates; direct = lax.top_k of the whole row (small catalogs).",
+    ("path",))
+
+#: shortest block: one row of the TPU's (8, 128) float32 tile
+_MIN_BLOCK_LEN = 128
+
+
+def _select_block_len(n_items: int, k: int) -> int:
+    """Block length L of the two-stage selection for a row of ``n_items``
+    scores, or 0 where plain ``lax.top_k`` is to run. The two stages sort
+    B = ceil(n/L) block maxima and k·L candidates; B + k·L is least at
+    L = sqrt(n/k), and the power of two nearest to that on the log scale
+    is taken (1024 for 9.4M items and k = 10: 9,180 maxima and 10,240
+    candidates). Where B + k·L is half the row or more (small catalogs,
+    large k) the stages would not sort markedly less than the row itself
+    and the answer is 0. Both arguments are static in the jit, so the
+    choice is made once per executable, from the shape alone."""
+    if not 1 <= k <= n_items:
+        return 0
+    length = max(_MIN_BLOCK_LEN, 2 ** round(math.log2(n_items / k) / 2))
+    n_blocks = -(-n_items // length)
+    return length if 2 * (n_blocks + k * length) < n_items else 0
+
+
+def _select_topk(scores, k: int, block_len: int):
+    """``lax.top_k(scores, k)`` of one score row ``[n]``, values and
+    indices bit for bit, without sorting the row: pad it with -inf to
+    ``[B, block_len]``, take each block's maximum, pick the kb = min(k, B)
+    blocks with the largest maxima (``lax.top_k``: ties toward the lowest
+    block index), and order only their kb·L candidates by (score
+    descending, global index ascending), the two-key sort that
+    ops/sharded_topk.py merges with.
+
+    Why this is exact, tie order included. ``lax.top_k`` orders elements
+    by (score descending, index ascending) and returns the first k. Let e
+    be an element of a block that was NOT chosen. Each of the kb = k
+    chosen blocks (kb < k only when every block is chosen) has a maximum
+    that is greater than e's block maximum, or equal to it in a block of
+    lower index — the order ``lax.top_k`` chose the blocks in — and e's
+    block maximum is at least e. So each chosen block holds an element that is greater than
+    e, or equal to e at a lower global index (a lower block lies wholly
+    below e's): k elements precede e in ``lax.top_k``'s own order and e
+    is not in the answer. The answer therefore lies among the candidates,
+    and the two-key sort orders them as ``lax.top_k`` would. Padded lanes
+    are -inf at indices above every real one, so they lose every tie,
+    and only the last block has any (at most L - 1 of them): the chosen
+    blocks hold at least k real elements, and no padded lane is
+    returned. With fewer than k finite scores the -inf rows come out in
+    index order, as ``lax.top_k`` gives them. One caveat, shared with the
+    sharded merges: ``lax.sort`` takes +0.0 and -0.0 for equal, so a row
+    that holds both orders them by index, where the CPU's ``lax.top_k``
+    puts +0.0 first."""
+    n = scores.shape[0]
+    n_blocks = -(-n // block_len)
+    blocks = jnp.pad(scores, (0, n_blocks * block_len - n),
+                     constant_values=-jnp.inf).reshape(n_blocks, block_len)
+    kb = min(k, n_blocks)
+    _, chosen = jax.lax.top_k(blocks.max(axis=1), kb)
+    cand = blocks[chosen].ravel()
+    idx = (chosen[:, None] * block_len
+           + jnp.arange(block_len, dtype=chosen.dtype)[None, :]).ravel()
+    neg, idx = jax.lax.sort((-cand, idx), num_keys=2)
+    return -neg[:k], idx[:k]
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -28,6 +105,9 @@ def _topk_scores(user_vec, item_factors, exclude_mask, k: int):
     # HBM-bandwidth-bound on reading the catalog either way.
     scores = (item_factors * user_vec[None, :]).sum(axis=1)  # [n_items]
     scores = jnp.where(exclude_mask, -jnp.inf, scores)
+    block_len = _select_block_len(scores.shape[0], k)
+    if block_len:
+        return _select_topk(scores, k, block_len)
     return jax.lax.top_k(scores, k)
 
 
@@ -56,8 +136,11 @@ def top_k_items(user_vec, item_factors, k: int, exclude=None):
     # (measured ~0.4 ms/query of lax_numpy/bind machinery saved)
     # topk.dispatch: the enqueue (a first k's compile shows here, with
     # an xla.compile child); topk.wait: the device's queue, the scan and
-    # the readback.
-    with telemetry.span("topk.dispatch"):
+    # the readback. ``select`` says which selection this (n_items, k)
+    # compiled to: the predicate _topk_scores itself traces with.
+    select = "blocks" if _select_block_len(n_items, k) else "direct"
+    _M_SELECT.labels(select).inc()
+    with telemetry.span("topk.dispatch", select=select):
         out = _topk_scores(user_vec, item_factors, exclude, k)
     # Single host transfer: each device_get is a round trip, so (scores,
     # idx) come back together.

@@ -533,3 +533,35 @@ def test_sharded_indicators_facade_layout_selection(monkeypatch):
                            exclude=None, item_boost=None)
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
     np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
+
+
+@pytest.mark.parametrize("k,excluded", [(10, False), (4, True)])
+def test_three_layouts_bit_identical_across_block_selection(
+        mesh8, monkeypatch, k, excluded):
+    """A catalog large enough that the flat layout selects by blocks
+    (ops/topk._select_topk) instead of ``lax.top_k`` of the whole row:
+    flat, mesh and host still answer bit for bit alike, through the
+    facade, with and without a business-rule mask, on heavy ties too."""
+    from incubator_predictionio_tpu.ops import topk
+
+    n_items, rank = 4099, 16  # not a multiple of 8, of 128 or of the shards
+    assert topk._select_block_len(n_items, k) == 128
+    rng = np.random.default_rng(19)
+    items = rng.normal(size=(n_items, rank)).astype(np.float32)
+    items[rng.integers(0, n_items, 600)] = items[7]  # duplicate rows: ties
+    exclude = (rng.random(n_items) < 0.4) if excluded else None
+    monkeypatch.delenv("PIO_SERVE_SHARD_ITEMS", raising=False)
+    flat = ShardedCatalog(items)
+    mesh = ShardedCatalog(items, serving_mesh=mesh8)
+    monkeypatch.setenv("PIO_SERVE_SHARD_ITEMS", "1000")
+    host = ShardedCatalog(items)
+    assert (flat.layout, mesh.layout, host.layout) == ("flat", "mesh", "host")
+    blocks = topk._M_SELECT.labels("blocks")
+    before = blocks.value()
+    for uv in (rng.normal(size=rank).astype(np.float32), items[7]):
+        s0, i0 = flat.top_k(uv, k, exclude=exclude)
+        for other in (mesh, host):
+            s1, i1 = other.top_k(uv, k, exclude=exclude)
+            np.testing.assert_array_equal(i0, i1)
+            np.testing.assert_array_equal(s0, s1)  # bitwise
+    assert blocks.value() == before + 2  # the flat calls, and only they
