@@ -41,14 +41,17 @@ def main(argv=None) -> int:
     if not args.rehearse and jax.devices()[0].platform != "tpu":
         print("knee.py: no TPU", file=sys.stderr)
         return 3
+    import serving
+
+    deployment = bench.load_module("deployments", config["deployment"])
     record = bench.Record(cell, config, traffic, args)
-    st, _server, _draw = bench.serve_setup(record)
+    st, _server = serving.serve_setup(record, deployment)
     try:
         for k, rate in enumerate(float(r) for r in args.rates.split(",")):
             with tempfile.TemporaryDirectory(prefix="knee_") as work:
-                offer = bench.Offer(st.base, dict(traffic, rate_qps=rate),
-                                    config["n_users"], args.seed + k,
-                                    args.seconds, work)
+                offer = serving.Offer(st.base, dict(traffic, rate_qps=rate),
+                                      config["n_users"], args.seed + k,
+                                      args.seconds, work, deployment.bodies)
                 offer.go()
                 s = offer.result()["summary"]
             lat = s["latency_ms"]

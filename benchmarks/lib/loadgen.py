@@ -7,6 +7,9 @@ exponential at the mix's rate, in the mix's own order), of ``num`` and of
 unknown users; the seed only turns the cycle and draws which known users ask. Latency is timed from when a request was DUE, not from when it
 was sent, and how late each send ran is reported beside it.
 
+The child posts ``job["body"][k]`` when ``job["due"][k]`` comes and builds no
+body itself: the deployment file made them from the schedule's rows.
+
 As a child:  python3 loadgen.py <job.json>   (writes job["out"])
 """
 
@@ -64,7 +67,7 @@ async def _drive(job: dict) -> dict:
     import aiohttp
 
     url = job["base_url"] + "/queries.json"
-    due, users, nums = job["due"], job["user"], job["num"]
+    due, body = job["due"], job["body"]
     keep = set(job.get("keep_bodies", ()))
     n = len(due)
     sent = [None] * n
@@ -81,8 +84,7 @@ async def _drive(job: dict) -> dict:
         await asyncio.gather(*[touch() for _ in range(8)])
 
         async def warm(k: int):
-            async with sess.post(
-                    url, json={"user": users[k], "num": nums[k]}) as r:
+            async with sess.post(url, json=body[k]) as r:
                 await r.read()
         # a few of the window's own requests over those connections, not
         # counted: the server's request path is then warm end to end
@@ -100,8 +102,7 @@ async def _drive(job: dict) -> dict:
                 await asyncio.sleep(delay)
             sent[k] = time.monotonic() - t0
             try:
-                async with sess.post(
-                        url, json={"user": users[k], "num": nums[k]}) as r:
+                async with sess.post(url, json=body[k]) as r:
                     raw = await r.read()
                     status[k] = r.status
                     if k in keep and r.status == 200:

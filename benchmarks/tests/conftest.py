@@ -1,5 +1,9 @@
 """CPU tests of the harness's own arithmetic. Run by hand from the root of
-the checkout (they are not part of the repository's tier-1 tests):
+the checkout (42 cases, under a minute with ``-n 6 --dist loadfile``). They
+are not yet part of the repository's tier-1 tests: that takes a file under
+``tests/``, which a ``benchmark`` PR may not add (``PERF.md`` section 7). No
+test file here imports this one by name, so a collector under ``tests/``,
+which has a ``conftest.py`` of its own, can take them as they are:
 
   JAX_PLATFORMS=cpu python3 -m pytest benchmarks/tests -q -p no:cacheprovider
 """

@@ -9,7 +9,6 @@ import json
 import numpy as np
 import pytest
 
-import control
 import run as bench
 
 RETRAIN, SERVE = "retrain-electronics-r128", "serve-catalog9m-steady"
@@ -27,8 +26,9 @@ def run_cell(capsys, cell, seed=123):
 
 
 def rehearsal(cell):
+    """(configuration, traffic, its deployment file) at the rehearsal size."""
     _cell, cfg, traffic = bench.load_cell(cell, rehearse=True)
-    return cfg, traffic
+    return cfg, traffic, bench.load_module("deployments", cfg["deployment"])
 
 
 def test_sound_runs_are_correct(capsys):
@@ -39,10 +39,8 @@ def test_sound_runs_are_correct(capsys):
 
 
 def test_retrain_control_in_lower_precision_is_not_correct():
-    cfg, _ = rehearsal(RETRAIN)
-    import datagen
-
-    got = control.als_readings(cfg, 5, datagen.degrees(cfg))
+    cfg, traffic, deployment = rehearsal(RETRAIN)
+    got = deployment.control(traffic["kind"], cfg, traffic, 5)
     lim = cfg["limits"]
     low = got["control_lower_precision"]
     assert any(low[k] > lim[k] for k in lim), low
@@ -51,8 +49,8 @@ def test_retrain_control_in_lower_precision_is_not_correct():
 
 
 def test_serve_control_in_lower_precision_is_not_correct():
-    cfg, traffic = rehearsal(SERVE)
-    got = control.topk_readings(cfg, traffic, 5)
+    cfg, traffic, deployment = rehearsal(SERVE)
+    got = deployment.control(traffic["kind"], cfg, traffic, 5)
     lim = cfg["limits"]
     low = got["control_lower_precision"]
     assert low["rank_gap"] > lim["rank_gap"] or \

@@ -39,7 +39,8 @@ def test_the_load_generator_never_imports_jax():
             "loadgen.schedule(%r, 100, 1, 1.0); "
             "assert 'jax' not in sys.modules" % (
                 os.path.dirname(loadgen.__file__), TRAFFIC))
-    assert subprocess.run([sys.executable, "-c", code]).returncode == 0
+    assert subprocess.run([sys.executable, "-c", code],
+                          timeout=120).returncode == 0
 
 
 class _Server:
@@ -47,12 +48,13 @@ class _Server:
 
     def __init__(self):
         self.n = 0
+        self.received = []
         self.loop = asyncio.new_event_loop()
         self.ready = threading.Event()
         self.thread = threading.Thread(target=self._run, daemon=True)
 
     async def _query(self, request):
-        await request.json()
+        self.received.append(await request.read())
         self.n += 1
         if self.n % 5 == 0:
             return web.json_response({"message": "shed"}, status=503)
@@ -89,9 +91,12 @@ def test_child_times_from_due_reports_lateness_counts_refusals(tmp_path):
     try:
         # one connection: requests queue behind each other, so a later
         # request's latency from its DUE time includes the wait
-        sched = {"due": [0.0, 0.0, 0.0, 0.0, 0.0], "user": ["1"] * 5,
-                 "num": [4] * 5}
-        job = dict(sched, base_url=f"http://127.0.0.1:{srv.port}",
+        # the child builds no body: it posts what the job holds, whatever
+        # the fields are, in the key order given
+        body = [{"num": 4, "blackList": [f"i{k}"], "user": "1"}
+                for k in range(5)]
+        job = dict(due=[0.0] * 5, body=body, warmup_requests=0,
+                   base_url=f"http://127.0.0.1:{srv.port}",
                    out=str(tmp_path / "out.json"), keep_bodies=[0, 1, 2, 3, 4],
                    answer_timeout_s=10.0, connections=1)
         (tmp_path / "job.json").write_text(json.dumps(job))
@@ -112,6 +117,8 @@ def test_child_times_from_due_reports_lateness_counts_refusals(tmp_path):
         assert lat[0] >= 50.0 and lat[3] >= 150.0  # queued behind the others
         assert all(v >= 0.0 for v in s["late_ms"])
         assert list(res["bodies"].values()) == [{"itemScores": []}] * 4
+        assert sorted(srv.received) == sorted(
+            json.dumps(b).encode() for b in body)
     finally:
         srv.loop.call_soon_threadsafe(srv.stop.set)
         srv.thread.join(10)
