@@ -26,6 +26,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..common import telemetry
 from ..controller import Algorithm, DataSource, Engine, EngineFactory, Params, SanityCheck
 from ..data.storage.bimap import BiMap
 from ..data.store.l_event_store import LEventStore
@@ -338,7 +339,7 @@ class URAlgorithm(Algorithm):
         names = list(pd.events.keys())
         primary_name = names[0]
         pu, pi = pd.events[primary_name]
-        # One fused device program for every event-type pair: the
+        # One fused scan over the user ranges for every event-type pair: the
         # primary's dedupe/partition/upload/membership slabs are shared
         # across pairs and the self-pair rides the primary slabs
         # outright (ops.llr.cco_indicators_multi; multi-chip meshes run
@@ -359,9 +360,10 @@ class URAlgorithm(Algorithm):
         )
         # Popularity backfill ranking: raw primary-event count per item
         # (reference UR's default "popular" popModel).
-        popularity = np.bincount(
-            np.asarray(pi, np.int64), minlength=len(pd.items)
-        ).astype(np.float32)
+        with telemetry.span("ur.popularity"):
+            popularity = np.bincount(
+                np.asarray(pi, np.int64), minlength=len(pd.items)
+            ).astype(np.float32)
         model = URModel(
             indicators=indicators, users=pd.users, items=pd.items,
             item_categories=pd.item_categories,

@@ -21,7 +21,8 @@ accumulation (f32 only from the LLR stage on). Two
 accumulation strategies, chosen by HBM budget: when the full [I, I]
 f32 matrix fits a fraction of device memory, one scan over user ranges
 builds each membership slab ONCE and accumulates the whole matrix
-(then LLR + top-k per stripe slice — all one dispatch); bigger
+(then LLR + top-k per stripe slice, a second dispatch over the resident
+matrix); bigger
 catalogs stream [item_block, I] stripes through a bounded accumulator
 (slabs rebuilt per stripe — the memory/compute trade). Both paths are
 bit-identical (counts are exact integers; tested). Either
@@ -38,6 +39,34 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..common import telemetry
+
+
+_M_PATH = telemetry.registry().counter(
+    "pio_cco_path_total",
+    "Cross-occurrence device programs by accumulation strategy: fused = "
+    "every pair of one primary in one scan, each with a resident "
+    "[I, I] matrix; pair_full = one pair, one resident matrix; striped = "
+    "one pair, [item_block, I] stripes through a bounded accumulator.",
+    ("path",))
+
+
+def _ladder(n: int) -> int:
+    """The smallest m * 2**s >= n with m in 8..15 (n itself up to 16): at
+    most an eighth over n. Slab widths are rounded up to it, so that a
+    retrain on events whose widest range differs by less than a rung
+    meets the executable already compiled. It holds only the widths
+    still: n_items, the number of user ranges and of heavy ranges key
+    the executable too, so a new item, the 2,049th new user or a user
+    crossing the heavy threshold compiles anew. What the rounding adds
+    is padding the sentinel already masks: at most an eighth more
+    scatter work a slab (the striped path pays it once a stripe)."""
+    n = max(int(n), 1)
+    if n <= 16:
+        return n
+    s = n.bit_length() - 4
+    return -(-n >> s) << s
 
 
 #: Heavy-user rank-range width: drives BOTH the heavy-slab partition
@@ -85,7 +114,8 @@ def _partition_by_user(u: np.ndarray, i: np.ndarray, u_chunk: int,
     Returns (eu [n_ranges, E], ei [n_ranges, E]): eu holds the user's
     LOCAL offset within its range (padding sentinel = u_chunk — no
     per-row base array needed on device), ei the item id (padding 0,
-    masked by the sentinel). Both upload uint16 when their value range
+    masked by the sentinel); E is the widest range's count rounded up
+    the ``_ladder``. Both upload uint16 when their value range
     fits (they nearly always do: u_chunk defaults to 2048, catalogs are
     rarely >65k items) — half the slab bytes of int32, which matters
     because the slab upload is a dominant warm-train cost on
@@ -104,7 +134,7 @@ def _partition_by_user(u: np.ndarray, i: np.ndarray, u_chunk: int,
         us, is_ = u[order], i[order]
     chunk_of = (us // u_chunk).astype(np.int64)
     counts = np.bincount(chunk_of, minlength=n_ranges)
-    e = max(int(counts.max()), 1) if counts.size else 1
+    e = _ladder(counts.max()) if counts.size else 1
 
     starts = np.zeros(n_ranges + 1, np.int64)
     np.cumsum(counts, out=starts[1:])
@@ -171,35 +201,6 @@ def _cooccurrence_stripe(peu, pei, seu, sei, lo_item,
     return c
 
 
-@functools.partial(jax.jit, static_argnames=("n_items", "u_chunk", "h_chunk"))
-def _full_cooccurrence(light, heavy, n_items: int, u_chunk: int,
-                       h_chunk: int):
-    """The whole [I, I] co-occurrence matrix in one scan over user
-    ranges — each range's slabs are built ONCE (the striped kernel
-    rebuilds them per stripe; at 20k items that redundant scatter was
-    ~60% of UR's device time). Costs n_items^2 * 4 bytes of HBM for
-    the accumulator, so ``cco_indicators`` only routes here when that
-    fits (PIO_UR_FULL_MATRIX_ELEMS caps it; the striped path remains
-    for big catalogs). Counts are exact integers in int32, so both
-    paths produce IDENTICAL results (tested)."""
-
-    def mk_body(chunk_rows: int):
-        def body(c, chunk):
-            eu_p, ei_p, eu_s, ei_s = chunk
-            ap = _slab(eu_p, ei_p, chunk_rows, n_items)
-            asec = _slab(eu_s, ei_s, chunk_rows, n_items)
-            c = c + jnp.einsum("ui,uj->ij", ap, asec,
-                               preferred_element_type=jnp.int32)
-            return c, None
-        return body
-
-    c0 = jnp.zeros((n_items, n_items), jnp.int32)
-    c, _ = jax.lax.scan(mk_body(u_chunk), c0, light)
-    if heavy is not None:
-        c, _ = jax.lax.scan(mk_body(h_chunk), c, heavy)
-    return c
-
-
 def _pad_ranges(arrs, mult: int, u_chunk: int):
     """Pad the leading (range) axis to a device-count multiple with
     sentinel-only rows (local offset u_chunk = padding → zero slab →
@@ -217,104 +218,88 @@ def _pad_ranges(arrs, mult: int, u_chunk: int):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "mesh", "n_items", "u_chunk", "h_chunk", "block", "k",
-    "llr_threshold"))
-def _full_cco_topk_sharded(light, heavy, lo_effs, n_i, n_j, n_total, *,
-                           mesh, n_items: int, u_chunk: int, h_chunk: int,
-                           block: int, k: int, llr_threshold: float):
-    """Multi-chip full-matrix path: user ranges shard over DATA_AXIS —
-    each device scans only its local ranges and the per-device partial
-    [I, I] counts psum over ICI (counts are exact small integers in
-    f32, so the psum is exact and the result is bit-identical to the
-    single-device path — tested on the virtual mesh). LLR + top-k run
-    replicated afterwards inside the SAME jit. ``mesh`` is a static
-    arg (Mesh is hashable), so repeat trains at the same shapes reuse
-    one executable like every other kernel here."""
-    from jax import shard_map
-    from jax.lax import pcast
-    from jax.sharding import PartitionSpec as _P
-    from ..parallel.mesh import DATA_AXIS as _D
-
-    def counts_fn(light_l, heavy_l):
-        def mk_body(chunk_rows: int):
-            def body(c, chunk):
-                eu_p, ei_p, eu_s, ei_s = chunk
-                ap = _slab(eu_p, ei_p, chunk_rows, n_items)
-                asec = _slab(eu_s, ei_s, chunk_rows, n_items)
-                return c + jnp.einsum(
-                    "ui,uj->ij", ap, asec,
-                    preferred_element_type=jnp.int32), None
-            return body
-
-        c0 = jnp.zeros((n_items, n_items), jnp.int32)
-        # shard_map's varying-manual-axes typing: the carry starts as a
-        # replicated constant but the body output varies over the data
-        # axis — mark it varying up front
-        c0 = pcast(c0, (_D,), to="varying")
-        c, _ = jax.lax.scan(mk_body(u_chunk), c0, light_l)
-        if heavy_l is not None:
-            c, _ = jax.lax.scan(mk_body(h_chunk), c, heavy_l)
-        return jax.lax.psum(c, _D)
-
-    spec_rows = _P(_D, None)
-    in_specs = (tuple(spec_rows for _ in light),
-                None if heavy is None else tuple(spec_rows for _ in heavy))
-    c = shard_map(
-        counts_fn, mesh=mesh,
-        in_specs=in_specs, out_specs=_P(),
-    )(light, heavy)
-
-    def body(carry, lo_eff):
-        counts = jax.lax.dynamic_slice(c, (lo_eff, 0), (block, n_items))
-        n_i_stripe = jax.lax.dynamic_slice(n_i, (lo_eff,), (block,))
-        s, ix = _stripe_topk(counts, n_i_stripe, n_j, lo_eff, n_total,
-                             k=k, llr_threshold=llr_threshold)
-        return carry, (s, ix)
-
-    _, (ss, ixs) = jax.lax.scan(body, 0, lo_effs)
-    return ss, ixs
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "n_items", "u_chunk", "h_chunk", "block", "k", "llr_threshold",
-    "self_flags"))
-def _full_cco_topk_multi(light_p, light_secs, heavy_p, heavy_secs, lo_effs,
-                         n_i, n_js, n_total, *, n_items: int, u_chunk: int,
-                         h_chunk: int, block: int, k: int,
-                         llr_threshold: float, self_flags: tuple):
-    """ALL of one primary's cross-occurrence pairs in ONE dispatch: the
-    user-range scan builds each range's PRIMARY membership slab once and
-    accumulates every secondary's [I, I] matrix against it (self-pairs
-    reuse the primary slab outright — no second scatter, no second
-    upload). The per-pair path scatters the primary slab S times and
-    uploads the primary events S times; for the UR bench (buy→buy +
-    buy→view) the fusion removes a third of the event-slab upload bytes
-    and half the primary scatters. Counts stay exact small integers in
-    f32 → bit-identical to per-pair calls (tested).
+    "mesh", "n_items", "u_chunk", "h_chunk", "self_flags"))
+def _cco_count_multi(light_p, light_secs, heavy_p, heavy_secs, *, mesh=None,
+                     n_items: int, u_chunk: int, h_chunk: int,
+                     self_flags: tuple):
+    """The [I, I] int32 count matrices of ALL of one primary's
+    cross-occurrence pairs from ONE scan over the user ranges — each
+    range's slabs are built ONCE (the striped kernel rebuilds them per
+    stripe; at 20k items that redundant scatter was ~60% of UR's device
+    time): each range's PRIMARY membership slab is built once and every
+    secondary's matrix accumulates against it (self-pairs reuse the
+    primary slab outright — no second scatter, no second upload). The
+    per-pair path scatters the primary slab S times and uploads the
+    primary events S times; for the UR bench (buy→buy + buy→view) the
+    fusion removes a third of the event-slab upload bytes and half the
+    primary scatters. Costs n_items^2 * 4 bytes of HBM a matrix, so the
+    callers only route here when that fits (PIO_UR_FULL_MATRIX_ELEMS caps
+    it; the striped path remains for big catalogs). Counts are exact
+    integers → bit-identical to per-pair calls and to the striped path
+    (tested).
 
     light_secs/heavy_secs: (eu, ei) pairs for NON-self secondaries, in
-    output order; self_flags marks which outputs take the primary slab.
-    n_js: [S, I] per-secondary distinct-user item counts."""
+    output order; self_flags marks which outputs take the primary slab;
+    heavy_p/heavy_secs use () for absent (static pytree shape).
 
-    n_sec = len(self_flags)
-    c0 = tuple(jnp.zeros((n_items, n_items), jnp.int32)
-               for _ in range(n_sec))
-    xs = tuple(light_p) + tuple(x for pair in light_secs for x in pair)
-    cs, _ = jax.lax.scan(_mk_multi_body(self_flags, n_items, u_chunk),
-                         c0, xs)
-    if heavy_p is not None:
-        xs_h = tuple(heavy_p) + tuple(x for pair in heavy_secs for x in pair)
-        cs, _ = jax.lax.scan(_mk_multi_body(self_flags, n_items, h_chunk),
-                             cs, xs_h)
+    With a multi-device ``mesh`` (a static arg — Mesh is hashable — so
+    repeat trains at the same shapes reuse one executable like every
+    other kernel here) the user ranges shard over DATA_AXIS: each device
+    scans only its local ranges and the per-device partial matrices psum
+    over ICI (exact int32 → bit-identical to the single device; tested on
+    the virtual mesh); every device then holds every matrix.
 
-    return _topk_per_secondary(cs, n_js, n_i, lo_effs, n_total,
-                               n_items=n_items, block=block, k=k,
-                               llr_threshold=llr_threshold)
+    The matrices are this dispatch's OUTPUTS and ``_cco_select``'s
+    inputs, not temporaries of one executable that counts and selects:
+    the runtime's ``memory_stats()`` count buffers and not an
+    executable's temporaries (measured on a v5e: 8.6 GB of temporaries
+    read 0.7 MB), so only so do the gigabytes this template keeps on the
+    device show to whoever watches the device's memory, and a profile
+    names the two halves; on one chip the two dispatches take 7% less
+    than the one did (docs/tpu.md)."""
+    if mesh is not None:
+        from jax import shard_map
+        from jax.lax import pcast
+        from jax.sharding import PartitionSpec as _P
+        from ..parallel.mesh import DATA_AXIS as _D
+
+    def counts_fn(lp, lsecs, hp, hsecs):
+        cs = tuple(jnp.zeros((n_items, n_items), jnp.int32)
+                   for _ in self_flags)
+        if mesh is not None:
+            # shard_map's varying-manual-axes typing: the carry starts as
+            # a replicated constant but the body output varies over the
+            # data axis — mark it varying up front
+            cs = tuple(pcast(c, (_D,), to="varying") for c in cs)
+        xs = tuple(lp) + tuple(x for pair in lsecs for x in pair)
+        cs, _ = jax.lax.scan(_mk_multi_body(self_flags, n_items, u_chunk),
+                             cs, xs)
+        if len(hp):
+            xs_h = tuple(hp) + tuple(x for pair in hsecs for x in pair)
+            cs, _ = jax.lax.scan(
+                _mk_multi_body(self_flags, n_items, h_chunk), cs, xs_h)
+        if mesh is not None:
+            cs = tuple(jax.lax.psum(c, _D) for c in cs)
+        return cs
+
+    if mesh is None:
+        return counts_fn(light_p, light_secs, heavy_p, heavy_secs)
+    rows = _P(_D, None)
+
+    def specs_like(tree):
+        return jax.tree.map(lambda _: rows, tree)
+
+    return shard_map(
+        counts_fn, mesh=mesh,
+        in_specs=(specs_like(light_p), specs_like(light_secs),
+                  specs_like(heavy_p), specs_like(heavy_secs)),
+        out_specs=tuple(_P() for _ in self_flags),
+    )(light_p, light_secs, heavy_p, heavy_secs)
 
 
 def _mk_multi_body(self_flags: tuple, n_items: int, chunk_rows: int):
-    """Scan body shared by the fused single-device and sharded kernels:
-    build the primary slab once, accumulate every pair against it."""
+    """``_cco_count_multi``'s scan body: build the primary slab once,
+    accumulate every pair against it."""
     def body(cs, chunk):
         ap = _slab(chunk[0], chunk[1], chunk_rows, n_items)
         outs, r = [], 2
@@ -331,15 +316,17 @@ def _mk_multi_body(self_flags: tuple, n_items: int, chunk_rows: int):
     return body
 
 
-def _topk_per_secondary(cs, n_js, n_i, lo_effs, n_total, *, n_items: int,
-                        block: int, k: int, llr_threshold: float):
-    """Per-secondary stripe LLR + top-k loop shared by every full-matrix
-    kernel variant (single/sharded, single-pair/fused)."""
+@functools.partial(jax.jit, static_argnames=(
+    "n_items", "block", "k", "llr_threshold"))
+def _cco_select(cs, n_js, n_i, lo_effs, n_total, *, n_items: int,
+                block: int, k: int, llr_threshold: float):
+    """G² and the k best of every row of the resident count matrices
+    ``cs`` (one per secondary; n_js: [S, I] per-secondary distinct-user
+    item counts), stripe by stripe, as ONE dispatch — one download
+    instead of a dispatch + device_get round trip per stripe. After a
+    mesh's count every device holds ``cs`` and this runs replicated."""
     outs = []
-    for s_idx in range(len(cs)):
-        c = cs[s_idx]
-        n_j = n_js[s_idx]
-
+    for c, n_j in zip(cs, n_js):
         def body(carry, lo_eff, c=c, n_j=n_j):
             counts = jax.lax.dynamic_slice(c, (lo_eff, 0), (block, n_items))
             n_i_stripe = jax.lax.dynamic_slice(n_i, (lo_eff,), (block,))
@@ -350,81 +337,6 @@ def _topk_per_secondary(cs, n_js, n_i, lo_effs, n_total, *, n_items: int,
         _, (ss, ixs) = jax.lax.scan(body, 0, lo_effs)
         outs.append((ss, ixs))
     return tuple(outs)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "mesh", "n_items", "u_chunk", "h_chunk", "block", "k",
-    "llr_threshold", "self_flags"))
-def _full_cco_topk_multi_sharded(light_p, light_secs, heavy_p, heavy_secs,
-                                 lo_effs, n_i, n_js, n_total, *, mesh,
-                                 n_items: int, u_chunk: int, h_chunk: int,
-                                 block: int, k: int, llr_threshold: float,
-                                 self_flags: tuple):
-    """Multi-chip variant of _full_cco_topk_multi: user ranges shard
-    over DATA_AXIS, every device scans only its local ranges building
-    the primary slab once per range for ALL pairs, and the per-device
-    partial count matrices psum over ICI (exact int32 → bit-identical
-    to per-pair and to single-device; tested on the virtual mesh).
-    heavy_p/heavy_secs use () for absent (static pytree shape)."""
-    from jax import shard_map
-    from jax.lax import pcast
-    from jax.sharding import PartitionSpec as _P
-    from ..parallel.mesh import DATA_AXIS as _D
-
-    n_sec = len(self_flags)
-
-    def counts_fn(lp, lsecs, hp, hsecs):
-        c0 = tuple(
-            pcast(jnp.zeros((n_items, n_items), jnp.int32),
-                  (_D,), to="varying")
-            for _ in range(n_sec))
-        xs = tuple(lp) + tuple(x for pair in lsecs for x in pair)
-        cs, _ = jax.lax.scan(_mk_multi_body(self_flags, n_items, u_chunk),
-                             c0, xs)
-        if len(hp):
-            xs_h = tuple(hp) + tuple(x for pair in hsecs for x in pair)
-            cs, _ = jax.lax.scan(
-                _mk_multi_body(self_flags, n_items, h_chunk), cs, xs_h)
-        return tuple(jax.lax.psum(c, _D) for c in cs)
-
-    rows = _P(_D, None)
-
-    def specs_like(tree):
-        return jax.tree.map(lambda _: rows, tree,
-                            is_leaf=lambda x: x is None)
-
-    cs = shard_map(
-        counts_fn, mesh=mesh,
-        in_specs=(specs_like(light_p), specs_like(light_secs),
-                  specs_like(heavy_p), specs_like(heavy_secs)),
-        out_specs=tuple(_P() for _ in range(n_sec)),
-    )(light_p, light_secs, heavy_p, heavy_secs)
-
-    return _topk_per_secondary(cs, n_js, n_i, lo_effs, n_total,
-                               n_items=n_items, block=block, k=k,
-                               llr_threshold=llr_threshold)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "n_items", "u_chunk", "h_chunk", "block", "k", "llr_threshold"))
-def _full_cco_topk(light, heavy, lo_effs, n_i, n_j, n_total,
-                   n_items: int, u_chunk: int, h_chunk: int,
-                   block: int, k: int, llr_threshold: float):
-    """Full-matrix accumulate + per-stripe LLR/top-k as ONE dispatch
-    (like the striped path's _all_stripes: no dispatch + readback per
-    stripe)."""
-    c = _full_cooccurrence(light, heavy, n_items=n_items,
-                           u_chunk=u_chunk, h_chunk=h_chunk)
-
-    def body(carry, lo_eff):
-        counts = jax.lax.dynamic_slice(c, (lo_eff, 0), (block, n_items))
-        n_i_stripe = jax.lax.dynamic_slice(n_i, (lo_eff,), (block,))
-        s, ix = _stripe_topk(counts, n_i_stripe, n_j, lo_eff, n_total,
-                             k=k, llr_threshold=llr_threshold)
-        return carry, (s, ix)
-
-    _, (ss, ixs) = jax.lax.scan(body, 0, lo_effs)
-    return ss, ixs
 
 
 def _full_matrix_elem_cap() -> int:
@@ -597,51 +509,17 @@ def cco_indicators(
 
     # Packed-key dedupe (native when available); output is
     # (user, item)-sorted, which every partition below relies on.
-    pu, pi, cnt_p = _dedupe_pair(primary_u, primary_i, n_users, n_items)
-    su, si, cnt_s = _dedupe_pair(secondary_u, secondary_i, n_users, n_items)
+    with telemetry.span("cco.dedupe"):
+        pu, pi, cnt_p = _dedupe_pair(primary_u, primary_i, n_users, n_items)
+        su, si, cnt_s = _dedupe_pair(secondary_u, secondary_i, n_users,
+                                     n_items)
     n_ranges = max((n_users + u_chunk - 1) // u_chunk, 1)
-
-    # Heavy-user extraction: a user with far more interactions than the
-    # mean would inflate every slab row's width E (user ranges cannot be
-    # split — a scan step's product needs the range's COMPLETE
-    # primary+secondary events to count every cross pair). Heavy users
-    # are renumbered onto a dense RANK space and processed through the
-    # SAME striped kernel with u_chunk-sized rank ranges: each rank range
-    # holds few (very active) users, so its slab width stays bounded
-    # while every heavy range fits the same [u_chunk+1, I] slab budget.
-    per_user = cnt_p + cnt_s
-    mean_pu = max(float(per_user.sum()) / max(n_users, 1), 1.0)
-    heavy_cap = max(int(16 * mean_pu), 256)
-    heavy_users = np.nonzero(per_user > heavy_cap)[0]
-    n_heavy = int(len(heavy_users))
-    if n_heavy:
-        rank = np.full(n_users, -1, np.int64)
-        rank[heavy_users] = np.arange(n_heavy)
-
-        def split_heavy(u, i):
-            hm = rank[u] >= 0
-            return (u[~hm], i[~hm],
-                    rank[u[hm]].astype(np.int32), i[hm].astype(np.int32))
-
-        pu_l, pi_l, hp_u, hp_i = split_heavy(pu, pi)
-        su_l, si_l, hs_u, hs_i = split_heavy(su, si)
-        # FEW heavy users per rank range (16), so one range's slab width
-        # stays ≈ 16 heavy histories, not u_chunk of them. The slab
-        # height is the range size, so heavy slabs are [17, I] — tiny.
-        h_ranges = max((n_heavy + _HEAVY_RANGE - 1) // _HEAVY_RANGE, 1)
-        h_per = _HEAVY_RANGE
-        hpeu, hpei = _partition_by_user(hp_u, hp_i, h_per, h_ranges,
-                                        n_items, assume_sorted=True)
-        hseu, hsei = _partition_by_user(hs_u, hs_i, h_per, h_ranges,
-                                        n_items, assume_sorted=True)
-    else:
-        pu_l, pi_l, su_l, si_l = pu, pi, su, si
-
-    peu, pei = _partition_by_user(pu_l, pi_l, u_chunk, n_ranges, n_items, assume_sorted=True)
-    seu, sei = _partition_by_user(su_l, si_l, u_chunk, n_ranges, n_items, assume_sorted=True)
-
-    n_i = np.bincount(pi, minlength=n_items).astype(np.float32)
-    n_j = jnp.asarray(np.bincount(si, minlength=n_items).astype(np.float32))
+    n_mesh_dev = int(mesh.devices.size) if mesh is not None else 1
+    full_fits = n_items * n_items <= _full_matrix_elem_cap()
+    with telemetry.span("cco.partition"):
+        light, heavy, n_i, n_j = _partition_pair(
+            pu, pi, cnt_p, su, si, cnt_s, n_users, n_items, u_chunk,
+            n_ranges, n_mesh_dev)
     n_total = jnp.float32(n_users)
 
     k = min(max_correlators, n_items)
@@ -651,51 +529,111 @@ def cco_indicators(
     # catalog edge and slice the overlap off (same compiled shape).
     los = list(range(0, n_items, block))
     lo_effs_np = np.array([min(lo, n_items - block) for lo in los], np.int32)
-    n_mesh_dev = int(mesh.devices.size) if mesh is not None else 1
-    full_fits = n_items * n_items <= _full_matrix_elem_cap()
-    if n_mesh_dev > 1:
-        # multi-chip prep, shared by both strategies: pad the range
-        # axis to a device multiple; the slabs upload ONCE, sharded by
-        # the jit (no eager single-device copy first)
-        light_sh = _pad_ranges((peu, pei, seu, sei), n_mesh_dev, u_chunk)
-        heavy_sh = None
-        if n_heavy:
-            heavy_sh = _pad_ranges((hpeu, hpei, hseu, hsei),
-                                   n_mesh_dev, _HEAVY_RANGE)
-        fn = _full_cco_topk_sharded if full_fits else _all_stripes_sharded
+    path = "pair_full" if full_fits else "striped"
+    _M_PATH.labels(path).inc()
+    with telemetry.span("cco.device", path=path, n_sec=1,
+                        **_layout_tags(light, heavy)):
+        n_i_dev, n_j_dev = jnp.asarray(n_i), jnp.asarray(n_j)
+        lo_effs = jnp.asarray(lo_effs_np)
+        on_mesh = dict(mesh=mesh) if n_mesh_dev > 1 else {}
         if full_fits:
-            ss, ixs = jax.device_get(fn(
-                light_sh, heavy_sh, jnp.asarray(lo_effs_np),
-                jnp.asarray(n_i), n_j, n_total, mesh=mesh,
-                n_items=n_items, u_chunk=u_chunk, h_chunk=_HEAVY_RANGE,
-                block=block, k=k, llr_threshold=llr_threshold))
+            # full-matrix path: every slab built once, then the selection
+            # over the resident matrix
+            heavy_p, heavy_s = (heavy[:2], (heavy[2:],)) if heavy else ((), ())
+            cs = _cco_count_multi(
+                light[:2], (light[2:],), heavy_p, heavy_s, n_items=n_items,
+                u_chunk=u_chunk, h_chunk=_HEAVY_RANGE, self_flags=(False,),
+                **on_mesh)
+            out, = _cco_select(cs, n_j_dev[None], n_i_dev, lo_effs, n_total,
+                               n_items=n_items, block=block, k=k,
+                               llr_threshold=llr_threshold)
+            del cs
         else:
-            ss, ixs = jax.device_get(fn(
-                jnp.asarray(lo_effs_np), light_sh, heavy_sh,
-                jnp.asarray(n_i), n_j, n_total, mesh=mesh,
-                n_items=n_items, u_chunk=u_chunk, block=block, k=k,
-                llr_threshold=llr_threshold, h_chunk=_HEAVY_RANGE))
-    else:
-        n_i_dev = jnp.asarray(n_i)
-        light_dev = tuple(map(jnp.asarray, (peu, pei, seu, sei)))
-        heavy_arg = (tuple(map(jnp.asarray, (hpeu, hpei, hseu, hsei)))
-                     if n_heavy else None)
-        if full_fits:
-            # full-matrix path: every slab built once (_full_cooccurrence)
-            ss, ixs = jax.device_get(_full_cco_topk(
-                light_dev, heavy_arg, jnp.asarray(lo_effs_np), n_i_dev,
-                n_j, n_total, n_items=n_items, u_chunk=u_chunk,
-                h_chunk=_HEAVY_RANGE, block=block, k=k,
-                llr_threshold=llr_threshold))
-        else:
-            ss, ixs = jax.device_get(_all_stripes(
-                jnp.asarray(lo_effs_np), light_dev, heavy_arg,
-                n_i_dev, n_j, n_total,
-                n_items=n_items, u_chunk=u_chunk, block=block, k=k,
-                llr_threshold=llr_threshold, h_chunk=_HEAVY_RANGE,
-            ))
+            stripes = _all_stripes_sharded if on_mesh else _all_stripes
+            out = stripes(lo_effs, light, heavy, n_i_dev, n_j_dev, n_total,
+                          n_items=n_items, u_chunk=u_chunk,
+                          h_chunk=_HEAVY_RANGE, block=block, k=k,
+                          llr_threshold=llr_threshold, **on_mesh)
+        ss, ixs = jax.device_get(out)
 
-    return _gather_indicators(ss, ixs, los, lo_effs_np, block, n_items)
+    with telemetry.span("cco.gather"):
+        return _gather_indicators(ss, ixs, los, lo_effs_np, block, n_items)
+
+
+def _layout_tags(light, heavy) -> dict:
+    """What ``cco.device`` says of the layout it was handed: the scan
+    lengths and the slab widths (the shapes that key the executable)."""
+    return {"ranges": int(light[0].shape[0]),
+            "heavy_ranges": int(heavy[0].shape[0]) if heavy else 0,
+            "E": int(max(a.shape[1] for a in light[::2])),
+            "heavy_E": int(max(a.shape[1] for a in heavy[::2]))
+            if heavy else 0}
+
+
+def _heavy_ranks(per_user: np.ndarray, n_users: int):
+    """(rank [n_users] or None, heavy range count). Heavy-user
+    extraction: a user with far more interactions than the mean would
+    inflate every slab row's width E (user ranges cannot be split — a
+    scan step's product needs the range's COMPLETE primary+secondary
+    events to count every cross pair). Heavy users are renumbered onto a
+    dense RANK space and processed through the SAME kernels with
+    _HEAVY_RANGE-sized rank ranges: FEW heavy users per range, so one
+    range's slab width stays ≈ 16 heavy histories, and the slab height
+    is the range size, so heavy slabs are [17, I] — tiny. The threshold
+    only shapes the layout, never the counts."""
+    mean_pu = max(float(per_user.sum()) / max(n_users, 1), 1.0)
+    heavy_cap = max(int(16 * mean_pu), 256)
+    heavy_users = np.nonzero(per_user > heavy_cap)[0]
+    n_heavy = int(len(heavy_users))
+    if not n_heavy:
+        return None, 0
+    rank = np.full(n_users, -1, np.int64)
+    rank[heavy_users] = np.arange(n_heavy)
+    return rank, -(-n_heavy // _HEAVY_RANGE)
+
+
+def _layout_event(u, i, rank, h_ranges: int, u_chunk: int, n_ranges: int,
+                  n_items: int):
+    """NumPy layout of one event's deduped, user-sorted pairs: ((eu, ei)
+    light slabs, (eu, ei) heavy slabs or ()), the heavy users of ``rank``
+    apart."""
+    if rank is None:
+        return _partition_by_user(u, i, u_chunk, n_ranges, n_items,
+                                  assume_sorted=True), ()
+    hm = rank[u] >= 0
+    light = _partition_by_user(u[~hm], i[~hm], u_chunk, n_ranges, n_items,
+                               assume_sorted=True)
+    return light, _partition_by_user(
+        rank[u[hm]].astype(np.int32), i[hm].astype(np.int32), _HEAVY_RANGE,
+        h_ranges, n_items, assume_sorted=True)
+
+
+def _partition_pair(pu, pi, cnt_p, su, si, cnt_s, n_users: int,
+                    n_items: int, u_chunk: int, n_ranges: int,
+                    n_mesh_dev: int):
+    """Host layout of ONE pair for ``cco_indicators``: (light, heavy or
+    None) as (peu, pei, seu, sei) tuples — device arrays on one device,
+    host arrays padded to a device multiple on a mesh (the jit uploads
+    them sharded, no eager single-device copy first) — and the distinct-
+    user counts n_i, n_j."""
+    rank, h_ranges = _heavy_ranks(cnt_p + cnt_s, n_users)
+    light_p, heavy_p = _layout_event(pu, pi, rank, h_ranges, u_chunk,
+                                     n_ranges, n_items)
+    light_s, heavy_s = _layout_event(su, si, rank, h_ranges, u_chunk,
+                                     n_ranges, n_items)
+    light = light_p + light_s
+    heavy = (heavy_p + heavy_s) or None
+    n_i = np.bincount(pi, minlength=n_items).astype(np.float32)
+    n_j = np.bincount(si, minlength=n_items).astype(np.float32)
+    if n_mesh_dev > 1:
+        light = _pad_ranges(light, n_mesh_dev, u_chunk)
+        if heavy is not None:
+            heavy = _pad_ranges(heavy, n_mesh_dev, _HEAVY_RANGE)
+    else:
+        light = tuple(map(jnp.asarray, light))
+        if heavy is not None:
+            heavy = tuple(map(jnp.asarray, heavy))
+    return light, heavy, n_i, n_j
 
 
 def _dedupe_pair(u, i, n_users: int, n_items: int):
@@ -750,16 +688,16 @@ def cco_indicators_multi(
     item_block: int = 4096,
     mesh=None,
 ) -> dict:
-    """All cross-occurrence indicator matrices of ONE primary event in a
-    single fused device program (reference: the UR trains Mahout
+    """All cross-occurrence indicator matrices of ONE primary event from
+    one fused scan over the user ranges (reference: the UR trains Mahout
     SimilarityAnalysis per event-type pair; here the pairs share the
     primary's dedupe, host partition, upload, and per-range membership
-    slab — see _full_cco_topk_multi). ``secondaries`` maps name →
+    slab — see _cco_count_multi). ``secondaries`` maps name →
     (u, i); passing the primary's OWN arrays (by identity) marks a
     self-pair, which reuses the primary slabs end to end.
 
     On a multi-device mesh the same fusion shards user ranges over
-    DATA_AXIS with psum'd partial counts (_full_cco_topk_multi_sharded).
+    DATA_AXIS with psum'd partial counts (the same two dispatches).
     Falls back to per-pair ``cco_indicators`` calls when the fused
     accumulators would not fit the HBM budget (each pair then gets the
     full-vs-striped choice independently). Results are bit-identical to
@@ -783,38 +721,26 @@ def cco_indicators_multi(
             for name, (su, si) in secondaries.items()
         }
 
-    pu, pi, per_user = _dedupe_pair(primary_u, primary_i, n_users, n_items)
-    per_user = per_user.astype(np.int64, copy=True)
-    deduped = {}
-    for name, (su, si) in secondaries.items():
-        if su is primary_u and si is primary_i:
-            deduped[name] = None  # self-pair: reuse primary everywhere
-        else:
-            du, di, cnt = _dedupe_pair(su, si, n_users, n_items)
-            deduped[name] = (du, di)
-            # Heavy-user extraction over the COMBINED activity (primary
-            # + every distinct secondary): the threshold only shapes the
-            # layout, never the counts, so any consistent choice keeps
-            # results identical.
-            per_user += cnt
-    mean_pu = max(float(per_user.sum()) / max(n_users, 1), 1.0)
-    heavy_cap = max(int(16 * mean_pu), 256)
-    heavy_users = np.nonzero(per_user > heavy_cap)[0]
-    n_heavy = int(len(heavy_users))
-    rank = None
-    if n_heavy:
-        rank = np.full(n_users, -1, np.int64)
-        rank[heavy_users] = np.arange(n_heavy)
-
-    def split_heavy(u, i):
-        if rank is None:
-            return u, i, None, None
-        hm = rank[u] >= 0
-        return (u[~hm], i[~hm],
-                rank[u[hm]].astype(np.int32), i[hm].astype(np.int32))
+    with telemetry.span("cco.dedupe"):
+        pu, pi, per_user = _dedupe_pair(primary_u, primary_i, n_users,
+                                        n_items)
+        per_user = per_user.astype(np.int64, copy=True)
+        deduped = {}
+        for name, (su, si) in secondaries.items():
+            if su is primary_u and si is primary_i:
+                deduped[name] = None  # self-pair: reuse primary everywhere
+            else:
+                du, di, cnt = _dedupe_pair(su, si, n_users, n_items)
+                deduped[name] = (du, di)
+                # Heavy-user extraction over the COMBINED activity
+                # (primary + every distinct secondary): the threshold only
+                # shapes the layout, never the counts, so any consistent
+                # choice keeps results identical.
+                per_user += cnt
+    rank, h_ranges = _heavy_ranks(per_user, n_users)
+    n_heavy = rank is not None
 
     n_ranges = max((n_users + u_chunk - 1) // u_chunk, 1)
-    h_ranges = max((n_heavy + _HEAVY_RANGE - 1) // _HEAVY_RANGE, 1)
 
     def partition_put(u, i):
         """Partition (one-pass native C when available — the numpy
@@ -828,13 +754,9 @@ def cco_indicators_multi(
                 u, i, rank, n_users, u_chunk, n_ranges, n_items,
                 _HEAVY_RANGE, h_ranges)
         except Exception:  # noqa: BLE001 - native optional; layout identical
-            lu, li, hu, hi = split_heavy(u, i)
-            light = _partition_by_user(lu, li, u_chunk, n_ranges, n_items,
-                                       assume_sorted=True)
-            heavy = None
-            if n_heavy:
-                heavy = _partition_by_user(hu, hi, _HEAVY_RANGE, h_ranges,
-                                           n_items, assume_sorted=True)
+            light, heavy = _layout_event(u, i, rank, h_ranges, u_chunk,
+                                         n_ranges, n_items)
+            heavy = heavy or None
             counts = np.bincount(i, minlength=n_items)
         if n_mesh_dev > 1:
             # multi-chip: pad the range axis to a device multiple and
@@ -849,49 +771,49 @@ def cco_indicators_multi(
                      if heavy is not None else None)
         return light_dev, heavy_dev, counts.astype(np.float32)
 
-    p_light, p_heavy, n_i = partition_put(pu, pi)
-    self_flags = tuple(deduped[name] is None for name in names)
-    sec_light, sec_heavy, n_js = [], [], []
-    for name in names:
-        pair = deduped[name]
-        if pair is None:
-            n_js.append(n_i)
-            continue
-        su, si = pair
-        sl, sh, cnt = partition_put(su, si)
-        sec_light.append(sl)
-        if n_heavy:
-            sec_heavy.append(sh)
-        n_js.append(cnt)
+    with telemetry.span("cco.partition"):
+        p_light, p_heavy, n_i = partition_put(pu, pi)
+        self_flags = tuple(deduped[name] is None for name in names)
+        sec_light, sec_heavy, n_js = [], [], []
+        for name in names:
+            pair = deduped[name]
+            if pair is None:
+                n_js.append(n_i)
+                continue
+            sl, sh, cnt = partition_put(*pair)
+            sec_light.append(sl)
+            if n_heavy:
+                sec_heavy.append(sh)
+            n_js.append(cnt)
     k = min(max_correlators, n_items)
     block = min(item_block, n_items)
     los = list(range(0, n_items, block))
     lo_effs_np = np.array([min(lo, n_items - block) for lo in los], np.int32)
 
-    if n_mesh_dev > 1:
-        outs = _full_cco_topk_multi_sharded(
-            p_light, tuple(sec_light),
-            p_heavy if p_heavy is not None else (),
-            tuple(sec_heavy) if n_heavy else (),
-            jnp.asarray(lo_effs_np), jnp.asarray(n_i),
-            jnp.asarray(np.stack(n_js)), jnp.float32(n_users),
-            mesh=mesh, n_items=n_items, u_chunk=u_chunk,
-            h_chunk=_HEAVY_RANGE, block=block, k=k,
-            llr_threshold=llr_threshold, self_flags=self_flags)
-    else:
-        outs = _full_cco_topk_multi(
-            p_light, tuple(sec_light),
-            p_heavy, tuple(sec_heavy) if n_heavy else (),
-            jnp.asarray(lo_effs_np), jnp.asarray(n_i),
-            jnp.asarray(np.stack(n_js)), jnp.float32(n_users),
-            n_items=n_items, u_chunk=u_chunk, h_chunk=_HEAVY_RANGE,
-            block=block, k=k, llr_threshold=llr_threshold,
-            self_flags=self_flags)
-    outs = jax.device_get(outs)
-    return {
-        name: _gather_indicators(ss, ixs, los, lo_effs_np, block, n_items)
-        for name, (ss, ixs) in zip(names, outs)
-    }
+    _M_PATH.labels("fused").inc()
+    with telemetry.span(
+            "cco.device", path="fused", n_sec=n_sec, **_layout_tags(
+                p_light + tuple(x for sl in sec_light for x in sl),
+                (p_heavy + tuple(x for sh in sec_heavy for x in sh))
+                if n_heavy else None)):
+        n_i_dev, n_js_dev = jnp.asarray(n_i), jnp.asarray(np.stack(n_js))
+        lo_effs, n_total = jnp.asarray(lo_effs_np), jnp.float32(n_users)
+        cs = _cco_count_multi(
+            p_light, tuple(sec_light), p_heavy if n_heavy else (),
+            tuple(sec_heavy), n_items=n_items, u_chunk=u_chunk,
+            h_chunk=_HEAVY_RANGE, self_flags=self_flags,
+            **(dict(mesh=mesh) if n_mesh_dev > 1 else {}))
+        outs = _cco_select(cs, n_js_dev, n_i_dev, lo_effs, n_total,
+                           n_items=n_items, block=block, k=k,
+                           llr_threshold=llr_threshold)
+        del cs
+        outs = jax.device_get(outs)
+    with telemetry.span("cco.gather"):
+        return {
+            name: _gather_indicators(ss, ixs, los, lo_effs_np, block,
+                                     n_items)
+            for name, (ss, ixs) in zip(names, outs)
+        }
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -1360,6 +1360,18 @@ void pio_ingest_free(void* h) { delete static_cast<IngestOut*>(h); }
 
 namespace {
 
+// The smallest m * 2^s >= n with m in 8..15 (n itself up to 16): the same
+// ladder as ops/llr.py's _ladder, so both layouts stay identical. Slab
+// widths go up it so that events whose widest range differs by less than a
+// rung meet the executable already compiled; the sentinel masks what the
+// rounding adds.
+int64_t ladder(int64_t n) {
+  if (n <= 16) return n < 1 ? 1 : n;
+  int s = 0;
+  for (int64_t t = n; t >= 16; t >>= 1) ++s;
+  return ((n + (int64_t{1} << s) - 1) >> s) << s;
+}
+
 struct CcoPart {
   std::vector<uint16_t> light_eu, light_ei;
   std::vector<uint16_t> heavy_eu, heavy_ei;
@@ -1401,6 +1413,8 @@ void* pio_cco_partition(const int32_t* u, const int32_t* ii, int64_t n,
   }
   for (int64_t c : lcount) out->light_e = std::max(out->light_e, c);
   for (int64_t c : hcount) out->heavy_e = std::max(out->heavy_e, c);
+  out->light_e = ladder(out->light_e);
+  out->heavy_e = ladder(out->heavy_e);
   // pass 2: fill (sentinel offset = chunk width, item 0)
   out->light_eu.assign(static_cast<size_t>(n_ranges * out->light_e),
                        static_cast<uint16_t>(u_chunk));
