@@ -703,21 +703,58 @@ def _cached_packed_train_fn(mesh: Mesh, params: ALSParams,
     return hit
 
 
+#: Rows of the float64 scratch the init is drawn through: at rank 128 it
+#: is 8 MiB, so a chunk is drawn, scaled and cast without leaving the cache.
+_INIT_SCRATCH_ROWS = 8192
+
+
 def _fresh_init(params: ALSParams, plan_u: LayoutPlan, plan_i: LayoutPlan,
-                n_users: int, n_items: int):
+                n_users: int, n_items: int, keep_users: bool = True):
     """MLlib-style init (scaled standard normal), drawn in GLOBAL row
     order and placed into layout slots — identical factors regardless of
     mesh shape or layout, and filler slots start at exactly 0 (so the
-    implicit-mode YᵀY term never sees garbage rows)."""
+    implicit-mode YᵀY term never sees garbage rows).
+
+    The stream is fixed: ``default_rng(seed)`` gives the ``(n_users, k)``
+    normals first, then the ``(n_items, k)``, each ``/ sqrt(k)`` in
+    float64 and cast to float32. Both blocks go through one reused scratch,
+    chunk by chunk (NumPy fills in order, so chunks give the stream of one
+    whole draw, bit for bit).
+
+    A sweep starts with ``x = one_side(y, ...)``, so a train of one
+    iteration or more never reads ``x0``: its caller passes
+    ``keep_users=False`` and gets ``(None, y0)``. The user block is then
+    still DRAWN, sample for sample, because ``y0`` has to come from where
+    the stream stands after it, and dropped: no divide, no cast, no
+    scatter and no ``[n_users, k]`` array on the host. A train of no
+    iteration, whose result IS the init, keeps it."""
     k = params.rank
+    scale = np.sqrt(k)
     rng = np.random.default_rng(params.seed)
-    x0 = np.zeros((plan_u.total_slots, k), np.float32)
-    y0 = np.zeros((plan_i.total_slots, k), np.float32)
-    x0[plan_u.slot_of_row] = (
-        rng.standard_normal((n_users, k)) / np.sqrt(k)).astype(np.float32)
-    y0[plan_i.slot_of_row] = (
-        rng.standard_normal((n_items, k)) / np.sqrt(k)).astype(np.float32)
+    scratch = np.empty((_INIT_SCRATCH_ROWS, k))
+
+    def block(n_rows: int, plan: LayoutPlan, keep: bool):
+        out = np.zeros((plan.total_slots, k), np.float32) if keep else None
+        for lo in range(0, n_rows, _INIT_SCRATCH_ROWS):
+            chunk = scratch[:n_rows - lo]
+            rng.standard_normal(out=chunk)
+            if keep:
+                chunk /= scale
+                out[plan.slot_of_row[lo:lo + len(chunk)]] = chunk
+        return out
+
+    x0 = block(n_users, plan_u, keep_users)
+    y0 = block(n_items, plan_i, True)
     return x0, y0
+
+
+def _zeros_on_device(shape, sharding):
+    """The ``x0`` of a train that never reads it: float32 zeros born where
+    ``fast_put`` would place them, with no host array and no transfer."""
+    devices = sharding.device_set
+    return jnp.zeros(
+        shape, jnp.float32,
+        device=next(iter(devices)) if len(devices) == 1 else sharding)
 
 
 def train_als(
@@ -839,9 +876,14 @@ def train_als(
                 "scratch or raise num_iterations"
             )
 
-    if x0 is None:
-        with telemetry.span("als.init"):
-            x0, y0 = _fresh_init(params, plan_u, plan_i, n_users, n_items)
+    if y0 is None:
+        # a fresh start (start_iter 0) of one sweep or more overwrites x0
+        # before it reads it: the user block is drawn and dropped
+        keep_users = params.num_iterations < 1
+        with telemetry.span("als.init",
+                            users="kept" if keep_users else "dropped"):
+            x0, y0 = _fresh_init(params, plan_u, plan_i, n_users, n_items,
+                                 keep_users=keep_users)
     fn, in_shardings = _cached_train_fn(mesh, params, plan_u, plan_i)
     binary = bool(params.binary_ratings)
     # Single-device runs pack the slabs: 2-3 large transfers instead of
@@ -871,7 +913,8 @@ def train_als(
                     host.shape, sharding, lambda idx: host[idx]
                 )
 
-            x0 = _globalize(np.asarray(x0), in_shardings[1])
+            x0 = (_zeros_on_device(x_shape, in_shardings[1]) if x0 is None
+                  else _globalize(np.asarray(x0), in_shardings[1]))
             y0 = _globalize(np.asarray(y0), in_shardings[2])
             run_args = tuple(
                 _globalize(np.asarray(b), s)
@@ -881,7 +924,8 @@ def train_als(
             # Explicit transfers (plain single-device puts on a
             # one-device mesh, see fast_put) instead of handing jit raw
             # numpy inputs.
-            x0 = fast_put(np.asarray(x0), in_shardings[1])
+            x0 = (_zeros_on_device(x_shape, in_shardings[1]) if x0 is None
+                  else fast_put(np.asarray(x0), in_shardings[1]))
             y0 = fast_put(np.asarray(y0), in_shardings[2])
             if packed:
                 dev = mesh.devices.flat[0]
@@ -1178,7 +1222,9 @@ def train_als_process_sharded(
         for b, s in zip(u_flat + i_flat, in_shardings[3:])
     )
 
-    x0, y0 = _fresh_init(params, plan_u, plan_i, n_users, n_items)
+    x_shape = (plan_u.total_slots, params.rank)
+    x0, y0 = _fresh_init(params, plan_u, plan_i, n_users, n_items,
+                         keep_users=params.num_iterations < 1)
 
     fingerprint = None
     if checkpoint_hook is not None:
@@ -1213,15 +1259,16 @@ def train_als_process_sharded(
             start_iter, tree = checkpoint_hook.restore(step)
             rx = np.asarray(tree["user_factors"])
             ry = np.asarray(tree["item_factors"])
-            if rx.shape != x0.shape or ry.shape != y0.shape or \
+            if rx.shape != x_shape or ry.shape != y0.shape or \
                     int(np.asarray(tree.get("fingerprint", -1))) != fingerprint:
                 raise CheckpointIncompatibleError(
                     "checkpoint does not match the current sharded layout/"
                     "data — retrain from scratch")
             x0, y0 = rx, ry
 
-    gx0 = jax.make_array_from_callback(
-        x0.shape, in_shardings[1], lambda idx: x0[idx])
+    gx0 = (_zeros_on_device(x_shape, in_shardings[1]) if x0 is None
+           else jax.make_array_from_callback(
+               x_shape, in_shardings[1], lambda idx: x0[idx]))
     gy0 = jax.make_array_from_callback(
         y0.shape, in_shardings[2], lambda idx: y0[idx])
 

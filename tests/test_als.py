@@ -12,12 +12,18 @@ from incubator_predictionio_tpu.ops.rowblocks import (
     plan_layout,
 )
 from incubator_predictionio_tpu.ops.als import (
+    _INIT_SCRATCH_ROWS,
     ALSParams,
     _fresh_init,
+    _zeros_on_device,
     predict_rmse,
     train_als,
 )
-from incubator_predictionio_tpu.parallel.mesh import default_mesh, mesh_from_devices
+from incubator_predictionio_tpu.parallel.mesh import (
+    DATA_AXIS,
+    default_mesh,
+    mesh_from_devices,
+)
 
 
 def _toy_ratings(n_users=60, n_items=40, density=0.3, seed=0):
@@ -337,3 +343,140 @@ def test_als_overflow_rows_train():
     x_ref = _numpy_als_step(y0[plan_i.slot_of_row].astype(np.float64),
                             u, i, r, n_users, 0.1)
     np.testing.assert_allclose(out.user_factors, x_ref, rtol=2e-3, atol=2e-4)
+
+
+# --- the init: what the loop never reads is not materialised ---------------
+
+def _legacy_init(params, plan_u, plan_i, n_users, n_items):
+    """The oracle: _fresh_init as it stood before the user block was drawn
+    through a scratch (two whole draws, each scaled, cast and scattered)."""
+    k = params.rank
+    rng = np.random.default_rng(params.seed)
+    x0 = np.zeros((plan_u.total_slots, k), np.float32)
+    y0 = np.zeros((plan_i.total_slots, k), np.float32)
+    x0[plan_u.slot_of_row] = (
+        rng.standard_normal((n_users, k)) / np.sqrt(k)).astype(np.float32)
+    y0[plan_i.slot_of_row] = (
+        rng.standard_normal((n_items, k)) / np.sqrt(k)).astype(np.float32)
+    return x0, y0
+
+
+def _init_case(n_users, k, keep_users):
+    n_items = n_users // 2 + 37  # the largest takes two chunks as well
+    rng = np.random.default_rng(n_users + k)
+    # three shards: none of the row counts divides, so filler slots exist
+    plan_u = plan_layout(rng.integers(0, 5, n_users), 3)
+    plan_i = plan_layout(rng.integers(1, 9, n_items), 3)
+    params = ALSParams(rank=k, seed=11)
+    legacy = _legacy_init(params, plan_u, plan_i, n_users, n_items)
+    got = _fresh_init(params, plan_u, plan_i, n_users, n_items,
+                      keep_users=keep_users)
+    return plan_u, plan_i, legacy, got
+
+
+# below, equal to, and above without being a multiple of the scratch length
+_INIT_USERS = (_INIT_SCRATCH_ROWS - 3000, _INIT_SCRATCH_ROWS,
+               2 * _INIT_SCRATCH_ROWS + 1234)
+
+
+@pytest.mark.parametrize("keep_users", [False, True], ids=["dropped", "kept"])
+@pytest.mark.parametrize("k", [4, 128])
+@pytest.mark.parametrize("n_users", _INIT_USERS)
+def test_fresh_init_y0_is_the_one_stream(n_users, k, keep_users):
+    """The user block is drawn sample for sample whether kept or dropped:
+    y0 comes from where the stream stands after it, bit for bit."""
+    _, plan_i, (_, y_legacy), (_, y0) = _init_case(n_users, k, keep_users)
+    assert y0.dtype == np.float32 and y0.shape == y_legacy.shape
+    assert np.array_equal(y0, y_legacy)
+    filler = np.ones(plan_i.total_slots, bool)
+    filler[plan_i.slot_of_row] = False
+    assert filler.any() and not y0[filler].any()
+
+
+@pytest.mark.parametrize("keep_users", [False, True], ids=["dropped", "kept"])
+@pytest.mark.parametrize("k", [4, 128])
+@pytest.mark.parametrize("n_users", _INIT_USERS)
+def test_fresh_init_x0_kept_bitwise_or_dropped(n_users, k, keep_users):
+    """Kept, the chunks give the x0 of one whole draw, filler slots exactly
+    0; dropped, no host array is made and the loop's x0 is zeros born on
+    the device, of the same shape and dtype."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    plan_u, _, (x_legacy, _), (x0, _) = _init_case(n_users, k, keep_users)
+    if keep_users:
+        assert x0.dtype == np.float32
+        assert np.array_equal(x0, x_legacy)
+        filler = np.ones(plan_u.total_slots, bool)
+        filler[plan_u.slot_of_row] = False
+        assert filler.any() and not x0[filler].any()
+        return
+    assert x0 is None
+    for n_dev, spec in ((1, P()), (3, P(DATA_AXIS, None))):
+        mesh = mesh_from_devices(devices=jax.devices()[:n_dev])
+        sharding = NamedSharding(mesh, spec)
+        z = _zeros_on_device(x_legacy.shape, sharding)
+        assert z.shape == x_legacy.shape and z.dtype == np.float32
+        assert z.sharding.is_equivalent_to(sharding, z.ndim)
+        assert not np.asarray(z).any()
+
+
+def _init_spans(since_ns):
+    from incubator_predictionio_tpu.common import telemetry
+
+    return [s.tags for s in telemetry.spans_snapshot()
+            if s.name == "als.init" and s.t0_ns >= since_ns]
+
+
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+@pytest.mark.parametrize("n_iters", [1, 3])
+def test_train_never_reads_x0(monkeypatch, n_iters, implicit):
+    """The same loop handed the legacy random x0 or the zeros gives the
+    same factors, bit for bit: a sweep overwrites x before it reads it
+    (implicit mode's first half-step needs YᵀY of y0 only)."""
+    import time
+
+    from incubator_predictionio_tpu.ops import als
+
+    u, i, r = _toy_ratings(n_users=50, n_items=30, density=0.3, seed=4)
+    if implicit:
+        r = np.ones_like(r)
+    params = ALSParams(rank=8, num_iterations=n_iters, reg=0.05, seed=5,
+                       implicit_prefs=implicit, alpha=10.0)
+    since = time.perf_counter_ns()
+    zeros = train_als(u, i, r, 50, 30, params)
+    assert _init_spans(since) == [{"users": "dropped"}]
+
+    handed = []
+
+    def legacy(params, plan_u, plan_i, n_users, n_items, keep_users=True):
+        x0, y0 = _legacy_init(params, plan_u, plan_i, n_users, n_items)
+        handed.append(x0)
+        return x0, y0
+
+    monkeypatch.setattr(als, "_fresh_init", legacy)
+    random = train_als(u, i, r, 50, 30, params)
+    assert len(handed) == 1 and handed[0].any()
+    assert np.array_equal(zeros.user_factors, random.user_factors)
+    assert np.array_equal(zeros.item_factors, random.item_factors)
+
+
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+def test_train_of_no_iteration_returns_the_legacy_init(implicit):
+    """num_iterations == 0: the result IS the init, so the user block is
+    kept, and equals the draw in global row order."""
+    import time
+
+    u, i, r = _toy_ratings(n_users=50, n_items=30, density=0.3, seed=4)
+    params = ALSParams(rank=8, num_iterations=0, seed=5,
+                       implicit_prefs=implicit)
+    since = time.perf_counter_ns()
+    out = train_als(u, i, r, 50, 30, params)
+    assert _init_spans(since) == [{"users": "kept"}]
+    rng = np.random.default_rng(5)
+    x = (rng.standard_normal((50, 8)) / np.sqrt(8)).astype(np.float32)
+    y = (rng.standard_normal((30, 8)) / np.sqrt(8)).astype(np.float32)
+    assert np.array_equal(out.user_factors, x)
+    assert np.array_equal(out.item_factors, y)
