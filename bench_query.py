@@ -458,222 +458,6 @@ def replica_bracket() -> dict:
     return out
 
 
-def catalog_bracket() -> dict:
-    """Same-run 10k/100k/1M-item catalog bracket (ISSUE 17).
-
-    For each catalog size: serve a synthetic rank-R ALS model through
-    the REAL EngineServer over HTTP and measure serial p50/p99, with
-    the flat (unsharded) layout AND the host-sharded layout
-    (`PIO_SERVE_SHARD_ITEMS`) — sharded-vs-unsharded at the small sizes
-    is the overhead-honesty control. Every sharded answer is compared
-    against the unsharded reference on the same query set
-    (bit-identity is asserted, not assumed). A zipfian user mix then
-    drives the served-result cache on the largest catalog: warm pass
-    (all misses = full dispatches) vs hot pass (all hits) gives the
-    cache-hit-vs-full-dispatch p50 gap from one run."""
-    import threading
-
-    import requests
-    from aiohttp import web
-
-    from server_utils import ServerThread
-
-    from incubator_predictionio_tpu.controller import Engine, EngineParams
-    from incubator_predictionio_tpu.data.storage.bimap import (
-        BiMap, IdentityBiMap)
-    from incubator_predictionio_tpu.models.recommendation import (
-        ALSAlgorithm, ALSModel, RecommendationDataSource)
-    from incubator_predictionio_tpu.ops.als import ALSFactors
-    from incubator_predictionio_tpu.workflow.create_server import EngineServer
-    from incubator_predictionio_tpu.workflow.plugins import (
-        EngineServerPluginContext)
-
-    sizes = [int(s) for s in os.environ.get(
-        "PIO_QBENCH_CATALOG_SIZES", "10000,100000,1000000").split(",")
-        if s.strip()]
-    rank = int(os.environ.get("PIO_QBENCH_CATALOG_RANK", "32"))
-    n_users = int(os.environ.get("PIO_QBENCH_CATALOG_USERS", "500"))
-    n_q = int(os.environ.get("PIO_QBENCH_CATALOG_N", "120"))
-    shard_rows = int(os.environ.get("PIO_QBENCH_SHARD_ROWS", "131072"))
-
-    class Ctx:
-        workflow_params = type("WP", (), {"resume": False,
-                                          "nan_guard": False})()
-
-        def get_mesh(self):
-            return None
-
-        def get_storage(self):
-            return None
-
-    def build_dep(n_items, rng):
-        item_factors = rng.standard_normal(
-            (n_items, rank), dtype=np.float32)
-        user_factors = rng.standard_normal(
-            (n_users, rank), dtype=np.float32)
-        model = ALSModel(
-            factors=ALSFactors(user_factors, item_factors,
-                               n_users, n_items),
-            users=BiMap({str(j): j for j in range(n_users)}),
-            items=IdentityBiMap(n_items))
-        engine = Engine(data_source_class=RecommendationDataSource,
-                        algorithm_class_map={"als": ALSAlgorithm})
-        ep = EngineParams.from_json({
-            "datasource": {"params": {"appName": "catbench"}},
-            "algorithms": [{"name": "als", "params": {
-                "rank": rank, "shardedServing": "never"}}],
-        })
-        return engine.prepare_deployment(Ctx(), ep, [model])
-
-    def skeleton_server(dep, **overload_kw):
-        srv = EngineServer.__new__(EngineServer)  # no storage-backed load
-        srv.deployment = dep
-        srv.instance = None
-        srv.plugins = EngineServerPluginContext()
-        srv._lock = threading.Lock()
-        srv._query_count = 0
-        srv.feedback = False
-        srv._batch_queue = None
-        srv._init_overload_state(query_deadline_ms=0, **overload_kw)
-        srv.app = web.Application()
-        srv.app.add_routes([web.post("/queries.json", srv.handle_query)])
-        return srv
-
-    def serve_and_measure(dep, users, check_users=(), cache_size=0,
-                          ttl_ms=60_000, passes=1):
-        """Serial closed-loop latencies per pass + the check-set
-        responses + the server's cache snapshot (if armed)."""
-        srv = skeleton_server(
-            dep, query_cache_size=cache_size,
-            query_cache_ttl_ms=ttl_ms if cache_size else None)
-        per_pass, checks = [], {}
-        with ServerThread(srv.app) as st:
-            sess = requests.Session()
-            for u in ("0", "1"):     # compile + pool warm-up
-                r = sess.post(st.base + "/queries.json",
-                              json={"user": u, "num": 10}, timeout=600)
-                assert r.status_code == 200, r.text
-            for _p in range(passes):
-                lat = []
-                for u in users:
-                    t0 = time.perf_counter()
-                    r = sess.post(st.base + "/queries.json",
-                                  json={"user": u, "num": 10}, timeout=600)
-                    lat.append((time.perf_counter() - t0) * 1000)
-                    assert r.status_code == 200, r.text
-                per_pass.append(lat)
-            for u in check_users:
-                checks[u] = sess.post(
-                    st.base + "/queries.json",
-                    json={"user": u, "num": 10}, timeout=600).json()
-        snap = (srv._query_cache.snapshot()
-                if srv._query_cache is not None else None)
-        srv._query_executor.shutdown(wait=False)
-        return per_pass, checks, snap
-
-    def pct(a, p):
-        return round(float(np.percentile(np.asarray(a), p)), 2)
-
-    rng = np.random.default_rng(42)
-    users = [str(int(v)) for v in rng.integers(0, n_users, n_q)]
-    check_users = [str(j) for j in range(0, n_users, n_users // 16)]
-
-    prev_knob = os.environ.get("PIO_SERVE_SHARD_ITEMS")
-    out: dict = {"rank": rank, "queries_per_point": n_q,
-                 "shard_rows": shard_rows, "sizes": {}}
-    try:
-        for n_items in sizes:
-            srng = np.random.default_rng(n_items)
-            os.environ.pop("PIO_SERVE_SHARD_ITEMS", None)
-            flat_dep = build_dep(n_items, srng)
-            (flat_lat,), flat_checks, _ = serve_and_measure(
-                flat_dep, users, check_users)
-            row = {"flat_p50_ms": pct(flat_lat, 50),
-                   "flat_p99_ms": pct(flat_lat, 99)}
-            # ≥8 shards at EVERY size: the small catalogs are the
-            # overhead-honesty control (what does scanning cost when
-            # nothing needed sharding?), capped by the env knob
-            rows = min(shard_rows, max(1, n_items // 8))
-            if rows < n_items:
-                # fresh deployment, SAME factors (seeded rng): the
-                # catalog facade picks the host-sharded layout now
-                os.environ["PIO_SERVE_SHARD_ITEMS"] = str(rows)
-                shard_dep = build_dep(n_items, np.random.default_rng(
-                    n_items))
-                (shard_lat,), shard_checks, _ = serve_and_measure(
-                    shard_dep, users, check_users)
-                cat = shard_dep.models[0].catalog()
-                assert cat.layout == "host", cat.layout
-                row.update({
-                    "sharded_p50_ms": pct(shard_lat, 50),
-                    "sharded_p99_ms": pct(shard_lat, 99),
-                    "shards": cat.n_shards,
-                    # the acceptance bar: sharded answers ARE the
-                    # unsharded answers, through the full HTTP path
-                    "identical_to_flat": shard_checks == flat_checks,
-                })
-                assert row["identical_to_flat"], (
-                    f"sharded != flat at {n_items} items")
-                del shard_dep
-            out["sizes"][str(n_items)] = row
-            log(f"[qbench:catalog] {n_items:,} items: "
-                + " ".join(f"{k}={v}" for k, v in row.items()))
-            del flat_dep
-
-        # -- cache on/off at a zipfian user mix, largest catalog ------
-        os.environ["PIO_SERVE_SHARD_ITEMS"] = str(shard_rows)
-        big = max(sizes)
-        dep = build_dep(big, np.random.default_rng(big))
-        zipf = [str(int(v) % n_users)
-                for v in np.random.default_rng(5).zipf(1.3, n_q)]
-        # cache OFF = every query a full sharded dispatch (the honest
-        # dispatch p50 — the cache-armed warm pass already hits on the
-        # zipf head's within-pass repeats)
-        (cold,), _c, _s = serve_and_measure(dep, zipf)
-        # cache ON: pass 1 warms, pass 2 repeats the identical mix
-        # (all hits)
-        (warm, hot), _c, snap = serve_and_measure(
-            dep, zipf, cache_size=4096, passes=2)
-        out["cache"] = {
-            "catalog_items": big,
-            "zipf_users": len(set(zipf)),
-            "dispatch_p50_ms": pct(cold, 50),
-            "mixed_p50_ms": pct(warm, 50),
-            "hit_p50_ms": pct(hot, 50),
-            "hit_speedup": round(pct(cold, 50) / max(pct(hot, 50), 1e-9),
-                                 1),
-            "hits": snap["hits"], "misses": snap["misses"],
-        }
-        assert snap["hits"] >= n_q, snap       # pass 2 must be all hits
-        assert out["cache"]["hit_p50_ms"] < out["cache"]["dispatch_p50_ms"]
-        log(f"[qbench:catalog] cache @ {big:,} items: "
-            f"dispatch p50={out['cache']['dispatch_p50_ms']}ms vs "
-            f"hit p50={out['cache']['hit_p50_ms']}ms "
-            f"({out['cache']['hit_speedup']}x)")
-        del dep
-    finally:
-        if prev_knob is None:
-            os.environ.pop("PIO_SERVE_SHARD_ITEMS", None)
-        else:
-            os.environ["PIO_SERVE_SHARD_ITEMS"] = prev_knob
-    out["note"] = (
-        f"{os.cpu_count()}-core host, serial closed-loop over HTTP; "
-        "absolute latencies are host-CPU-bound (the 2-core ceiling of "
-        "the PR 8/12 benches applies) — the signal is the WITHIN-RUN "
-        "shape: sharded-vs-flat overhead at small catalogs, bounded "
-        "growth to 1M items, and the cache-hit-vs-dispatch gap")
-    out["overhead_fix"] = (
-        "profiled the CPU-local stack (ISSUE 17 satellite): ~0.6 ms of "
-        "the per-query cost was eager jnp dispatch in ops/topk.py — a "
-        "fresh jnp.zeros exclude mask built per query plus jnp.asarray "
-        "wrappers bypassing jit's C++ argument path; caching the "
-        "no-exclude mask per catalog size and passing raw arrays cut "
-        "in-process predict p50 0.714→0.383 ms and full-HTTP p50 "
-        "2.36→2.06 ms on the 26744-item rank-32 reference (same "
-        "executable, bit-identical answers)")
-    return out
-
-
 def multitenant_bracket() -> dict:
     """Same-run 1/8/32-app multi-tenant bracket (ISSUE 19).
 
@@ -805,7 +589,7 @@ def multitenant_bracket() -> dict:
     out["note"] = (
         f"{os.cpu_count()}-core host, serial closed-loop over HTTP; "
         "absolute latencies are host-CPU-bound (the same 2-core "
-        "ceiling as the catalog/replica brackets) — the signal is the "
+        "ceiling as the replica bracket) — the signal is the "
         "WITHIN-RUN shape: resident-hit vs cold-load gap, hit p50 "
         "flat across 1/8/32 apps, eviction churn only past the "
         "residency bound, and classic-vs-mux routing overhead")
@@ -1014,14 +798,6 @@ def main() -> int:
     if os.environ.get("PIO_QBENCH_OVERLOAD", "1") != "0":
         overload_detail = overload_bracket(engine, storage, n_users)
 
-    # -- 10k/100k/1M catalog bracket + cache gap (ISSUE 17) ---------------
-    catalog_detail = None
-    if os.environ.get("PIO_QBENCH_CATALOG", "1") != "0":
-        try:
-            catalog_detail = catalog_bracket()
-        except Exception as e:  # noqa: BLE001 - bracket is additive
-            log(f"[qbench:catalog] bracket failed: {e}")
-
     # -- replica-fleet QPS bracket + ceiling control (ISSUE 12) -----------
     replica_detail = None
     if os.environ.get("PIO_QBENCH_REPLICAS", "1,2,4") != "0":
@@ -1052,22 +828,11 @@ def main() -> int:
             "dispatch_rtt_ms": round(rtt_ms, 2),
             **({"load": load_detail} if load_detail else {}),
             **({"overload": overload_detail} if overload_detail else {}),
-            **({"catalog": catalog_detail} if catalog_detail else {}),
             **({"replicas": replica_detail} if replica_detail else {}),
             **({"multitenant": tenant_detail} if tenant_detail else {}),
         },
     }))
     here = os.path.dirname(os.path.abspath(__file__))
-    if catalog_detail is not None:
-        try:
-            with open(os.path.join(here, "BASELINE.json")) as f:
-                doc = json.load(f)
-            doc.setdefault("published", {})[
-                "measured_query_catalog"] = catalog_detail
-            with open(os.path.join(here, "BASELINE.json"), "w") as f:
-                json.dump(doc, f, indent=2)
-        except Exception as e:  # noqa: BLE001
-            log(f"[qbench:catalog] could not persist to BASELINE: {e}")
     if replica_detail is not None:
         try:
             with open(os.path.join(here, "BASELINE.json")) as f:

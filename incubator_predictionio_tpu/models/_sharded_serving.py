@@ -7,18 +7,13 @@ the templates never see which kernel answered (lint rule
 ``sharded-topk-confinement``: only this module may touch
 ``ops.sharded_topk`` internals):
 
-- ``mesh`` — a serving mesh was assigned (catalog beyond one chip's
-  HBM): dim 0 split over every mesh device, candidates merged through
-  an all_gather.
-- ``host`` — ``PIO_SERVE_SHARD_ITEMS`` > 0 and the vocabulary is
-  larger: the catalog lives stacked [S, rows, rank] on ONE device and a
-  scanned per-shard partial top-k bounds peak score memory at one
-  shard — the million-item single-replica path.
-- ``flat`` — the replicated single-device matrix (the default; knob
-  unset ⇒ bit-identical to, and literally the same kernels as, the
-  pre-sharding engine).
+- ``mesh`` — a serving mesh was assigned (``serving_mesh_for``: the
+  catalog's bytes against ``parallel.mesh.device_memory_bytes``, or the
+  engine.json key ``shardedServing``): dim 0 split over every mesh
+  device, candidates merged through an all_gather.
+- ``flat`` — the whole matrix on one device (``ops/topk.py``).
 
-All three layouts answer bit-identically on the single-query and
+Both layouts answer bit-identically on the single-query and
 similarity paths, and with identical indices on the batched path (see
 ops/sharded_topk.py module docstring for the measured gemm-ULP caveat).
 
@@ -35,13 +30,6 @@ import numpy as np
 from ..ops.sharded_topk import (  # noqa: F401  (serving_mesh_for and
     # validate_serving_mode are re-exported: templates import the whole
     # sharding surface from HERE, never from ops.sharded_topk)
-    env_serve_shard_items,
-    host_sharded_batch_top_k,
-    host_sharded_score_user,
-    host_sharded_similar_items,
-    host_sharded_top_k_items,
-    put_host_sharded_catalog,
-    put_host_sharded_indicators,
     put_sharded_catalog,
     serving_mesh_for,
     sharded_batch_top_k,
@@ -52,34 +40,31 @@ from ..ops.sharded_topk import (  # noqa: F401  (serving_mesh_for and
 from ..ops.topk import batch_top_k, similar_items, top_k_items
 
 __all__ = [
-    "ShardedCatalog", "ShardedCatalogServing", "ShardedIndicators",
+    "ShardedCatalog", "ShardedCatalogServing",
     "serving_mesh_for", "validate_serving_mode",
 ]
 
 
 class ShardedCatalog:
-    """Layout-selecting serving catalog: factor rows resident on device
-    in whichever shard layout policy picked, scored through one API."""
+    """Layout-selecting serving catalog: factor rows resident on one
+    device (``flat``) or sharded over the serving mesh (``mesh``),
+    scored through one API."""
 
     def __init__(self, host_factors, serving_mesh=None):
         import jax
 
         x = np.asarray(host_factors, np.float32)
         self.n_items = int(x.shape[0])
-        rows = env_serve_shard_items()
         if serving_mesh is not None:
             self.layout = "mesh"
             self._cat = put_sharded_catalog(x, serving_mesh)
-        elif 0 < rows < self.n_items:
-            self.layout = "host"
-            self._cat = put_host_sharded_catalog(x, rows)
         else:
             self.layout = "flat"
             self._cat = jax.device_put(x)
 
     @property
     def n_shards(self) -> int:
-        return self._cat.n_shards if self.layout != "flat" else 1
+        return self._cat.n_shards if self.layout == "mesh" else 1
 
     def top_k(self, user_vec, k: int, exclude=None):
         """(scores[k'], idx[k']) host numpy; ``exclude`` an optional
@@ -88,9 +73,6 @@ class ShardedCatalog:
         if self.layout == "mesh":
             return sharded_top_k_items(user_vec, self._cat, k,
                                        exclude=exclude)
-        if self.layout == "host":
-            return host_sharded_top_k_items(user_vec, self._cat, k,
-                                            exclude=exclude)
         return top_k_items(user_vec, self._cat, k, exclude=exclude)
 
     def batch_top_k(self, user_vecs, k: int):
@@ -98,8 +80,6 @@ class ShardedCatalog:
         coalesced batch, whatever the layout."""
         if self.layout == "mesh":
             return sharded_batch_top_k(user_vecs, self._cat, k)
-        if self.layout == "host":
-            return host_sharded_batch_top_k(user_vecs, self._cat, k)
         return batch_top_k(user_vecs, self._cat, k)
 
     def similar(self, query_vecs, k: int, exclude=None):
@@ -108,54 +88,15 @@ class ShardedCatalog:
         if self.layout == "mesh":
             return sharded_similar_items(query_vecs, self._cat, k,
                                          exclude=exclude)
-        if self.layout == "host":
-            return host_sharded_similar_items(query_vecs, self._cat, k,
-                                              exclude=exclude)
         return similar_items(query_vecs, self._cat, k, exclude=exclude)
-
-
-class ShardedIndicators:
-    """The universal recommender's serve-side twin of ShardedCatalog:
-    its catalog is per-event-type correlator tables (ops.llr.Indicators),
-    not a factor matrix, so sharding stacks each type's [I, K] table and
-    the scorer merges per-shard partial top-ks. Unsharded (knob off or
-    small vocab) it delegates to ops.llr.score_user unchanged."""
-
-    def __init__(self, indicators: dict, n_items: int):
-        self.n_items = int(n_items)
-        self._plain = indicators
-        rows = env_serve_shard_items()
-        self._sharded = (
-            {name: put_host_sharded_indicators(ind, rows)
-             for name, ind in indicators.items()}
-            if 0 < rows < self.n_items else None)
-
-    @property
-    def layout(self) -> str:
-        return "host" if self._sharded is not None else "flat"
-
-    def score_user(self, entries, k: int, exclude, item_boost):
-        """``entries``: [(event name, membership[N] f32, boost)] in
-        scoring order; returns (scores[k'], idx[k']) bit-identical
-        across layouts."""
-        if self._sharded is None:
-            from ..ops.llr import score_user
-
-            lst = [(self._plain[n], m, b) for n, m, b in entries]
-            return score_user(lst, k, exclude=exclude,
-                              item_boost=item_boost)
-        lst = [(self._sharded[n], np.asarray(m, np.float32), b)
-               for n, m, b in entries]
-        return host_sharded_score_user(lst, k, self.n_items,
-                                       exclude, item_boost)
 
 
 class ShardedCatalogServing:
     """Caches the device-resident ``ShardedCatalog`` picked by the
-    deploy-time ``serving_mesh`` decision + the ``PIO_SERVE_SHARD_ITEMS``
-    knob. Without the cache every query would re-upload the whole
-    matrix and p50 blows past the 10 ms budget — the serving hot path
-    uploads only the rank-float query vector.
+    deploy-time ``serving_mesh`` decision. Without the cache every
+    query would re-upload the whole matrix and p50 blows past the 10 ms
+    budget — the serving hot path uploads only the rank-float query
+    vector.
 
     Subclasses override ``_host_catalog()`` when the served factors are
     not the raw item factors (similar-product serves row-normalized
@@ -170,15 +111,6 @@ class ShardedCatalogServing:
             self._sharded_cat = ShardedCatalog(
                 self._host_catalog(), self.serving_mesh)
         return self._sharded_cat
-
-    def device_item_factors(self):
-        """Back-compat single-device handle (tools/tests); the serving
-        paths go through ``catalog()``."""
-        if self._dev_items is None:
-            import jax
-
-            self._dev_items = jax.device_put(self._host_catalog())
-        return self._dev_items
 
     def sharded_catalog(self):
         """Back-compat mesh-layout handle (tools/big_catalog_demo)."""

@@ -82,7 +82,6 @@ class ECommerceModel(ShardedCatalogServing):
     item_categories: dict[str, set[str]]
     app_name: str
     seen_event_names: Sequence[str]
-    _dev_items: object = dataclasses.field(default=None, repr=False, compare=False)
     _storage: object = dataclasses.field(default=None, repr=False, compare=False)
     _cat_index: object = dataclasses.field(default=None, repr=False, compare=False)
     # PAlgorithm serving analog: when set, the catalog is sharded over

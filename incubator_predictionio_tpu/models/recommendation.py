@@ -79,8 +79,8 @@ class ALSModel(ShardedCatalogServing):
     factors: ALSFactors
     users: BiMap
     items: BiMap
-    # Catalog caching + layout selection: ShardedCatalogServing.
-    _dev_items: object = dataclasses.field(default=None, repr=False, compare=False)
+    # Catalog caching + layout selection: ShardedCatalogServing (its two
+    # fields are declared here: dataclass machinery needs them per class).
     # When set (a Mesh), the catalog is served SHARDED over every mesh
     # device instead of replicated on one chip — the PAlgorithm serving
     # analog for factor matrices beyond one chip's HBM (reference:
@@ -106,7 +106,7 @@ class ALSModel(ShardedCatalogServing):
         uidx = self.users.get(user)
         if uidx is None:
             return []
-        # one call whatever the layout (mesh / host-sharded / flat) —
+        # one call whatever the layout (mesh / flat) —
         # the ShardedCatalog facade owns the dispatch
         scores, idx = self.catalog().top_k(
             self.factors.user_factors[uidx], num)
@@ -488,8 +488,8 @@ class ALSAlgorithm(Algorithm):
         out = ALSModel(
             factors=ALSFactors(uf, itf, len(users), len(items)),
             users=users, items=items)
-        # same serving layout as the live model; device catalog caches
-        # (_dev_items/_sharded_cat) stay None and re-warm at the gate
+        # same serving layout as the live model; the device catalog cache
+        # (_sharded_cat) stays None and re-warms at the gate
         out.serving_mesh = model.serving_mesh
         return out
 

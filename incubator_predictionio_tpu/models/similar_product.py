@@ -119,7 +119,6 @@ class SimilarProductModel(ShardedCatalogServing):
     factors: ALSFactors
     items: BiMap
     item_categories: dict[str, set[str]]
-    _dev_items: object = dataclasses.field(default=None, repr=False, compare=False)
     _cat_index: object = dataclasses.field(default=None, repr=False, compare=False)
     # PAlgorithm serving analog: when set, the catalog is sharded over
     # every mesh device at serve time (ops.sharded_topk).

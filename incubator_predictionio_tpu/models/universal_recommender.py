@@ -31,9 +31,8 @@ from ..controller import Algorithm, DataSource, Engine, EngineFactory, Params, S
 from ..data.storage.bimap import BiMap
 from ..data.store.l_event_store import LEventStore
 from ..data.store.p_event_store import PEventStore
-from ..ops.llr import Indicators, cco_indicators_multi
+from ..ops.llr import Indicators, cco_indicators_multi, score_user
 from ._filters import CategoryIndex, build_exclude_mask
-from ._sharded_serving import ShardedIndicators
 
 
 @dataclasses.dataclass
@@ -127,15 +126,6 @@ class URModel:
     _storage: object = dataclasses.field(default=None, repr=False, compare=False)
     _cat_index: object = dataclasses.field(default=None, repr=False, compare=False)
     _date_arrays: object = dataclasses.field(default=None, repr=False, compare=False)
-    _ind_catalog: object = dataclasses.field(default=None, repr=False, compare=False)
-
-    def indicator_catalog(self) -> ShardedIndicators:
-        """Serve-side indicator layout (host-sharded beyond
-        PIO_SERVE_SHARD_ITEMS rows), cached like the ALS catalogs."""
-        if self._ind_catalog is None:
-            self._ind_catalog = ShardedIndicators(
-                self.indicators, len(self.items))
-        return self._ind_catalog
 
     def category_index(self) -> CategoryIndex:
         if self._cat_index is None:
@@ -176,7 +166,6 @@ class URModel:
         return self._date_arrays
 
     def warm_up(self, num: int = 10):
-        self.indicator_catalog()
         if len(self.users):
             self.recommend(next(iter(self.users.keys())), num)
 
@@ -299,11 +288,11 @@ class URModel:
             ]
 
         entries = [
-            (name, history[name], 1.0)
+            (self.indicators[name], history[name], 1.0)
             for name in self.event_names
             if name in self.indicators
         ]
-        scores, idx = self.indicator_catalog().score_user(
+        scores, idx = score_user(
             entries, num, exclude=exclude, item_boost=boost_vec
         )
         return [

@@ -77,8 +77,7 @@ def soak_cmd(args: list[str]) -> int:
                    help="item universe the floods rate against "
                         "(default 50; raise it for a large-catalog "
                         "scenario — the zipf head keeps the quality "
-                        "signal, and catalogs past the host-shard "
-                        "threshold serve through the sharded path)")
+                        "signal)")
     p.add_argument("--tenant-apps", type=int, default=0, metavar="N",
                    help="arm the multi-tenant serving scenario: "
                         "serve N apps through ONE engine process "

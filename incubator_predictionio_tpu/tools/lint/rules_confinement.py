@@ -338,7 +338,7 @@ _SHARDED_TOPK_FACADE = "models/_sharded_serving.py"
 @rule("sharded-topk-confinement",
       "template code under models/ touches ops.sharded_topk internals "
       "only through the models/_sharded_serving.py facade — the "
-      "mesh/host/flat layout choice (and its bit-identity contract) "
+      "mesh/flat layout choice (and its bit-identity contract) "
       "lives in exactly one place")
 def sharded_topk_confinement(project: Project) -> Iterable[Finding]:
     for m in project.modules("models/"):
@@ -355,7 +355,7 @@ def sharded_topk_confinement(project: Project) -> Iterable[Finding]:
                         "sharded-topk-confinement", disp, node.lineno,
                         "import from ops.sharded_topk outside the "
                         "_sharded_serving facade — score through "
-                        "ShardedCatalog/ShardedIndicators instead")
+                        "ShardedCatalog instead")
             elif isinstance(node, ast.Import):
                 for alias in node.names:
                     if alias.name.endswith("sharded_topk"):
@@ -363,7 +363,7 @@ def sharded_topk_confinement(project: Project) -> Iterable[Finding]:
                             "sharded-topk-confinement", disp, node.lineno,
                             "import of ops.sharded_topk outside the "
                             "_sharded_serving facade — score through "
-                            "ShardedCatalog/ShardedIndicators instead")
+                            "ShardedCatalog instead")
             elif (isinstance(node, ast.Attribute)
                     and isinstance(node.value, ast.Name)
                     and node.value.id == "sharded_topk"):
@@ -371,7 +371,7 @@ def sharded_topk_confinement(project: Project) -> Iterable[Finding]:
                     "sharded-topk-confinement", disp, node.lineno,
                     f"sharded_topk.{node.attr} referenced outside the "
                     "_sharded_serving facade — score through "
-                    "ShardedCatalog/ShardedIndicators instead")
+                    "ShardedCatalog instead")
 
 
 #: merged-view scan entries + shard-file access primitives banned on

@@ -168,16 +168,14 @@ class SoakConfig:
     quality_sample: float = 1.0
     quality_watch_ms: float = 6000.0
     # million-item serving (ISSUE 17): queries run with the served-
-    # result cache armed and the host-shard threshold set, so the
-    # kill/poison timeline fires AGAINST cached results — the
-    # cache-freshness SLO row asserts rollbacks never left stale
-    # entries serving. catalog_items widens the item universe the
-    # floods rate against (zipf keeps the popularity head, so the
-    # shadow scorer's NDCG signal survives a large catalog).
+    # result cache armed, so the kill/poison timeline fires AGAINST
+    # cached results — the cache-freshness SLO row asserts rollbacks
+    # never left stale entries serving. catalog_items widens the item
+    # universe the floods rate against (zipf keeps the popularity head,
+    # so the shadow scorer's NDCG signal survives a large catalog).
     catalog_items: int = _ITEMS
     query_cache_size: int = 256
     query_cache_ttl_ms: float = 30000.0
-    serve_shard_items: int = 131072
     # multi-tenant serving (ISSUE 19): tenant_apps > 0 widens the app
     # universe to that many apps, trains EVERY app its own instance,
     # arms the engine's tenant mux (PIO_TENANT_MAX_RESIDENT) and
@@ -275,8 +273,7 @@ class SoakPlan:
             f"{cfg.enqueue_frac:.0%} enqueue-acked), queries "
             f"{cfg.query_rps:.0f}/s with "
             f"{cfg.query_deadline_ms:.0f}ms deadlines",
-            f"  serving: {cfg.catalog_items} items (host shards past "
-            f"{cfg.serve_shard_items} rows); result cache "
+            f"  serving: {cfg.catalog_items} items; result cache "
             + (f"{cfg.query_cache_size} entries, TTL "
                f"{cfg.query_cache_ttl_ms:.0f}ms" if cfg.query_cache_size
                else "off"),
@@ -697,12 +694,11 @@ class SoakRunner:
             "PIO_QUALITY_RESOLVE_MS": "400",
             "PIO_QUALITY_MS": "100",
             "PIO_SWAP_MAX_ERROR_RATE": f"{cfg.swap_max_error_rate}",
-            # million-item serving: cache + host-shard threshold armed
-            # so the fault timeline fires against cached results (the
-            # cache-freshness SLO row grades the invalidation contract)
+            # million-item serving: cache armed so the fault timeline
+            # fires against cached results (the cache-freshness SLO row
+            # grades the invalidation contract)
             "PIO_QUERY_CACHE_SIZE": f"{cfg.query_cache_size:d}",
             "PIO_QUERY_CACHE_TTL_MS": f"{cfg.query_cache_ttl_ms:.0f}",
-            "PIO_SERVE_SHARD_ITEMS": f"{cfg.serve_shard_items:d}",
             "PIO_FLEET_SYNC_MS": f"{cfg.fleet_sync_ms:.0f}",
             "PIO_FLEET_READY_MS": "150",
             # starved-host slack: mid-relaunch workers/replicas and

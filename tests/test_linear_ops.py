@@ -226,6 +226,43 @@ def test_ur_boost_applied_before_topk(memory_storage):
 import pytest
 
 
+@pytest.mark.parametrize("excluded,boosted",
+                         [(False, False), (True, False), (True, True)])
+def test_ur_score_user_matches_plain_reference(excluded, boosted):
+    """The one scorer a Universal Recommender query runs (URModel calls
+    it on its indicator tables directly): two event types with their
+    own boosts, empty (-1) correlator slots, a business-rule mask and a
+    per-item boost, against the definition written out in float64."""
+    from incubator_predictionio_tpu.ops.llr import Indicators, score_user
+
+    rng = np.random.default_rng(17)
+    n_items, k = 101, 10
+    inds = [Indicators(
+        idx=rng.integers(-1, n_items, size=(n_items, kc)).astype(np.int32),
+        score=rng.random((n_items, kc)).astype(np.float32))
+        for kc in (6, 3)]
+    membs = [(rng.random(n_items) < 0.3).astype(np.float32) for _ in inds]
+    boosts = (1.0, 2.0)
+    item_boost = (np.where(rng.random(n_items) < 0.1, 2.0, 1.0)
+                  .astype(np.float32) if boosted else None)
+    exclude = (rng.random(n_items) < 0.2) if excluded else None
+    scores, idx = score_user(list(zip(inds, membs, boosts)), k,
+                             exclude=exclude, item_boost=item_boost)
+    want = np.zeros(n_items)
+    for ind, m, b in zip(inds, membs, boosts):
+        hit = np.where(ind.idx >= 0, m[np.maximum(ind.idx, 0)], 0.0)
+        want += (ind.score.astype(np.float64) * hit).sum(axis=1) * b
+    if boosted:
+        want *= item_boost
+    if excluded:
+        want[exclude] = -np.inf
+    order = np.argsort(-want, kind="stable")[:k]
+    np.testing.assert_array_equal(np.asarray(idx), order)
+    np.testing.assert_allclose(np.asarray(scores), want[order], rtol=1e-5)
+    if excluded:
+        assert not exclude[np.asarray(idx)].any()
+
+
 @pytest.mark.parametrize("use_native", [True, False])
 def test_fit_tf_coo_native_and_fallback_parity(use_native):
     """Both COO producers (C++ and the Python fallback) must emit the

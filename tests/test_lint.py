@@ -894,21 +894,21 @@ def test_spawn_confinement_still_fires_outside_the_soak_driver(tmp_path):
 def test_seeded_sharded_topk_confinement(tmp_path):
     """Template code under models/ may not reach ops.sharded_topk
     directly — the _sharded_serving facade is the single place the
-    mesh/host/flat layout choice (and its bit-identity contract)
+    mesh/flat layout choice (and its bit-identity contract)
     lives. The facade itself is exempt; ops/ code is out of scope."""
     rogue = '''
-        from ..ops.sharded_topk import host_sharded_top_k_items
+        from ..ops.sharded_topk import sharded_top_k_items
         from ..ops import sharded_topk
 
-        def score(vec, cat, k):
-            sharded_topk.put_host_sharded_catalog(cat, 64)
-            return host_sharded_top_k_items(vec, cat, k)
+        def score(vec, factors, mesh, k):
+            cat = sharded_topk.put_sharded_catalog(factors, mesh)
+            return sharded_top_k_items(vec, cat, k)
     '''
     fs = findings_for(tmp_path, {"models/rogue_template.py": rogue},
                       ["sharded-topk-confinement"])
     assert len(fs) == 3, [f.message for f in fs]
     assert all("_sharded_serving facade" in f.message for f in fs)
-    assert any("sharded_topk.put_host_sharded_catalog" in f.message
+    assert any("sharded_topk.put_sharded_catalog" in f.message
                for f in fs)
     # the facade is the ONE legal home
     assert findings_for(

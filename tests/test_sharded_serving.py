@@ -2,8 +2,8 @@
 
 Reference: core/.../controller/PAlgorithm.scala — batchPredict: models
 that stay distributed at serve time. Here: item-factor catalogs sharded
-over every device of the 8-CPU virtual mesh, queried via per-shard top-k
-+ k-candidate all_gather merge (ops/sharded_topk.py). The invariant under
+over a mesh of 2, 4 or 8 of the virtual CPU devices, queried via per-shard
+top-k + k-candidate all_gather merge (ops/sharded_topk.py). The invariant under
 test is bit-identity with the single-device kernels for the matvec and
 similarity paths, and identical indices/ordering (scores ≤2 ULP — gemm
 output-shape blocking, documented in the module) for the batched path.
@@ -14,6 +14,10 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
+from incubator_predictionio_tpu.models._sharded_serving import (  # noqa: E402
+    ShardedCatalog,
+)
+from incubator_predictionio_tpu.ops import sharded_topk  # noqa: E402
 from incubator_predictionio_tpu.ops.sharded_topk import (  # noqa: E402
     put_sharded_catalog,
     sharded_batch_top_k,
@@ -23,6 +27,7 @@ from incubator_predictionio_tpu.ops.sharded_topk import (  # noqa: E402
 )
 from incubator_predictionio_tpu.ops.topk import (  # noqa: E402
     batch_top_k,
+    normalize_rows,
     similar_items,
     top_k_items,
 )
@@ -44,22 +49,31 @@ def mesh8():
     return mesh_from_devices()  # 1-D over the 8 virtual CPU devices
 
 
+@pytest.fixture(scope="module", params=[2, 4, 8])
+def mesh(request):
+    """1-D mesh over the first 2, 4 or 8 virtual CPU devices: the
+    per-shard cut and the merge are held to the flat answer at several
+    shard counts (1003 rows pad differently under each)."""
+    return mesh_from_devices(devices=jax.devices()[:request.param])
+
+
 # -- kernel-level identity --------------------------------------------------
 
 
-def test_single_query_bit_identical(catalog, mesh8):
-    cat = put_sharded_catalog(catalog, mesh8)
+def test_single_query_bit_identical(catalog, mesh):
+    cat = put_sharded_catalog(catalog, mesh)
+    assert cat.n_shards == mesh.size
     rng = np.random.default_rng(1)
-    for _ in range(3):
+    for k in (1, 10, 37):
         uv = rng.normal(size=(catalog.shape[1],)).astype(np.float32)
-        s0, i0 = top_k_items(uv, catalog, 10)
-        s1, i1 = sharded_top_k_items(uv, cat, 10)
+        s0, i0 = top_k_items(uv, catalog, k)
+        s1, i1 = sharded_top_k_items(uv, cat, k)
         np.testing.assert_array_equal(i0, i1)
         np.testing.assert_array_equal(s0, s1)  # bitwise
 
 
-def test_single_query_with_exclude_bit_identical(catalog, mesh8):
-    cat = put_sharded_catalog(catalog, mesh8)
+def test_single_query_with_exclude_bit_identical(catalog, mesh):
+    cat = put_sharded_catalog(catalog, mesh)
     rng = np.random.default_rng(2)
     uv = rng.normal(size=(catalog.shape[1],)).astype(np.float32)
     excl = np.zeros(catalog.shape[0], bool)
@@ -68,13 +82,27 @@ def test_single_query_with_exclude_bit_identical(catalog, mesh8):
     s1, i1 = sharded_top_k_items(uv, cat, 25, exclude=excl)
     np.testing.assert_array_equal(i0, i1)
     np.testing.assert_array_equal(s0, s1)
+    assert not excl[np.asarray(i1)].any()
 
 
-def test_similarity_bit_identical(catalog, mesh8):
-    from incubator_predictionio_tpu.ops.topk import normalize_rows
+def test_all_filtered_shard(catalog, mesh):
+    """A shard whose every row a business rule excludes contributes only
+    -inf fillers and the merge still reproduces the unsharded answer."""
+    cat = put_sharded_catalog(catalog, mesh)
+    rows = cat.padded_rows // cat.n_shards
+    rng = np.random.default_rng(13)
+    uv = rng.normal(size=(catalog.shape[1],)).astype(np.float32)
+    excl = np.zeros(len(catalog), bool)
+    excl[rows:2 * rows] = True  # shard 1 fully suppressed
+    s0, i0 = top_k_items(uv, catalog, 10, exclude=excl)
+    s1, i1 = sharded_top_k_items(uv, cat, 10, exclude=excl)
+    np.testing.assert_array_equal(i0, i1)
+    np.testing.assert_array_equal(s0, s1)
 
+
+def test_similarity_bit_identical(catalog, mesh):
     normed = normalize_rows(catalog)
-    cat = put_sharded_catalog(normed, mesh8)
+    cat = put_sharded_catalog(normed, mesh)
     qv = catalog[[3, 77, 500]]
     excl = np.zeros(catalog.shape[0], bool)
     excl[[3, 77, 500]] = True
@@ -84,8 +112,8 @@ def test_similarity_bit_identical(catalog, mesh8):
     np.testing.assert_array_equal(s0, s1)
 
 
-def test_batch_identical_selection(catalog, mesh8):
-    cat = put_sharded_catalog(catalog, mesh8)
+def test_batch_identical_selection(catalog, mesh):
+    cat = put_sharded_catalog(catalog, mesh)
     rng = np.random.default_rng(3)
     uvs = rng.normal(size=(13, catalog.shape[1])).astype(np.float32)
     s0, i0 = batch_top_k(uvs, catalog, 7)
@@ -107,25 +135,25 @@ def test_2d_mesh_matches_1d(catalog):
     np.testing.assert_array_equal(s0, s1)
 
 
-def test_k_larger_than_shard_rows(mesh8):
+def test_k_larger_than_shard_rows(mesh):
     """k greater than a shard's local row count: every shard contributes
     all of its rows and the merge is still exact."""
     rng = np.random.default_rng(5)
-    items = rng.normal(size=(40, 4)).astype(np.float32)  # 5 rows/shard
-    cat = put_sharded_catalog(items, mesh8)
+    items = rng.normal(size=(40, 4)).astype(np.float32)  # 5-20 rows/shard
+    cat = put_sharded_catalog(items, mesh)
     uv = rng.normal(size=(4,)).astype(np.float32)
-    s0, i0 = top_k_items(uv, items, 20)
-    s1, i1 = sharded_top_k_items(uv, cat, 20)
+    s0, i0 = top_k_items(uv, items, 30)
+    s1, i1 = sharded_top_k_items(uv, cat, 30)
     np.testing.assert_array_equal(i0, i1)
     np.testing.assert_array_equal(s0, s1)
 
 
-def test_tie_break_matches_lax_top_k(mesh8):
+def test_tie_break_matches_lax_top_k(mesh):
     """Duplicate scores across shards: the merge must pick the lowest
     global index first, exactly like lax.top_k on the unsharded row."""
     items = np.zeros((64, 2), np.float32)
     items[:, 0] = np.repeat([5.0, 4.0, 3.0, 2.0], 16)  # many exact ties
-    cat = put_sharded_catalog(items, mesh8)
+    cat = put_sharded_catalog(items, mesh)
     uv = np.array([1.0, 0.0], np.float32)
     s0, i0 = top_k_items(uv, items, 24)
     s1, i1 = sharded_top_k_items(uv, cat, 24)
@@ -136,17 +164,27 @@ def test_tie_break_matches_lax_top_k(mesh8):
 # -- sharding policy --------------------------------------------------------
 
 
-def test_should_shard_policy(mesh8, monkeypatch):
+def test_should_shard_policy(mesh8):
     assert not should_shard_serving(10**6, 64, None, "always")
     assert not should_shard_serving(10**6, 64, mesh8, "never")
     assert should_shard_serving(100, 4, mesh8, "always")
-    monkeypatch.setenv("PIO_SHARDED_SERVING_BYTES", "1000000")
-    assert should_shard_serving(10**6, 64, mesh8, "auto")
     assert not should_shard_serving(100, 4, mesh8, "auto")
     single = mesh_from_devices(devices=jax.devices()[:1])
     assert not should_shard_serving(10**9, 128, single, "always")
     with pytest.raises(ValueError):
         should_shard_serving(1, 1, mesh8, "sometimes")
+
+
+def test_auto_follows_device_memory(mesh8, monkeypatch):
+    """``auto`` shards when the float32 catalog is over a quarter of
+    what the device reports, and nothing but ``shardedServing`` moves
+    that line."""
+    n_items, rank = 10**6, 64  # 256,000,000 bytes
+    monkeypatch.setattr(sharded_topk, "device_memory_bytes",
+                        lambda: 4 * n_items * rank * 4)
+    assert not should_shard_serving(n_items, rank, mesh8, "auto")
+    assert should_shard_serving(n_items + 1, rank, mesh8, "auto")
+    assert not should_shard_serving(n_items + 1, rank, mesh8, "never")
 
 
 # -- template-level: sharded deployment answers like a single chip ----------
@@ -351,197 +389,45 @@ def test_big_catalog_demo_smoke(monkeypatch):
     assert mod.main() == 0
 
 
-# -- host-sharded (stacked-scan) kernel identity ----------------------------
-# ISSUE 17: PIO_SERVE_SHARD_ITEMS stacks the catalog [S, rows, rank] on
-# ONE device and scans a per-shard partial top-k; exactness contract is
-# the same as the mesh path — bitwise identical on the matvec/similarity
-# paths, identical indices (scores ≤2 ULP) on the batched gemm path.
-
-from incubator_predictionio_tpu.models._sharded_serving import (  # noqa: E402
-    ShardedCatalog,
-    ShardedIndicators,
-)
-from incubator_predictionio_tpu.ops.llr import (  # noqa: E402
-    Indicators,
-    score_user,
-)
-from incubator_predictionio_tpu.ops.sharded_topk import (  # noqa: E402
-    host_sharded_batch_top_k,
-    host_sharded_score_user,
-    host_sharded_similar_items,
-    host_sharded_top_k_items,
-    put_host_sharded_catalog,
-    put_host_sharded_indicators,
-)
-from incubator_predictionio_tpu.ops.topk import normalize_rows  # noqa: E402
+# -- the facade: two layouts, one answer -------------------------------------
 
 
-def _rows_for(n_items: int, shards: int) -> int:
-    return -(-n_items // shards)
-
-
-@pytest.mark.parametrize("shards", [1, 2, 4])
-def test_host_sharded_single_query_bit_identical(catalog, shards):
-    cat = put_host_sharded_catalog(catalog, _rows_for(len(catalog), shards))
-    assert cat.n_shards == shards
-    rng = np.random.default_rng(11)
-    for k in (1, 10, 37):
-        uv = rng.normal(size=(catalog.shape[1],)).astype(np.float32)
-        s0, i0 = top_k_items(uv, catalog, k)
-        s1, i1 = host_sharded_top_k_items(uv, cat, k)
-        np.testing.assert_array_equal(i0, i1)
-        np.testing.assert_array_equal(s0, s1)  # bitwise
-
-
-@pytest.mark.parametrize("shards", [2, 4])
-def test_host_sharded_exclude_bit_identical(catalog, shards):
-    cat = put_host_sharded_catalog(catalog, _rows_for(len(catalog), shards))
-    rng = np.random.default_rng(12)
+def test_sharded_catalog_facade_layout_selection(catalog, mesh):
+    """ShardedCatalog is ``mesh`` when a serving mesh was assigned and
+    ``flat`` otherwise; every scoring call answers alike through it."""
+    flat = ShardedCatalog(catalog)
+    sharded = ShardedCatalog(catalog, serving_mesh=mesh)
+    assert (flat.layout, flat.n_shards) == ("flat", 1)
+    assert (sharded.layout, sharded.n_shards) == ("mesh", mesh.size)
+    rng = np.random.default_rng(18)
     uv = rng.normal(size=(catalog.shape[1],)).astype(np.float32)
-    exclude = rng.random(len(catalog)) < 0.5
-    s0, i0 = top_k_items(uv, catalog, 10, exclude=exclude)
-    s1, i1 = host_sharded_top_k_items(uv, cat, 10, exclude=exclude)
-    np.testing.assert_array_equal(i0, i1)
-    np.testing.assert_array_equal(s0, s1)
-    assert not exclude[np.asarray(i1)].any()
-
-
-def test_host_sharded_all_filtered_shard(catalog):
-    """An entirely business-rule-excluded shard contributes only -inf
-    fillers and the merge still reproduces the unsharded answer."""
-    rows = _rows_for(len(catalog), 4)
-    cat = put_host_sharded_catalog(catalog, rows)
-    rng = np.random.default_rng(13)
-    uv = rng.normal(size=(catalog.shape[1],)).astype(np.float32)
-    exclude = np.zeros(len(catalog), bool)
-    exclude[rows:2 * rows] = True  # shard 1 fully suppressed
-    s0, i0 = top_k_items(uv, catalog, 10, exclude=exclude)
-    s1, i1 = host_sharded_top_k_items(uv, cat, 10, exclude=exclude)
-    np.testing.assert_array_equal(i0, i1)
-    np.testing.assert_array_equal(s0, s1)
-
-
-def test_host_sharded_k_larger_than_shard_rows(catalog):
-    """k > rows-per-shard: per-shard partials are clamped to the shard
-    and the merge still assembles the exact global top-k."""
-    cat = put_host_sharded_catalog(catalog, 7)  # 144 shards of 7 rows
-    rng = np.random.default_rng(14)
-    uv = rng.normal(size=(catalog.shape[1],)).astype(np.float32)
-    s0, i0 = top_k_items(uv, catalog, 50)
-    s1, i1 = host_sharded_top_k_items(uv, cat, 50)
-    np.testing.assert_array_equal(i0, i1)
-    np.testing.assert_array_equal(s0, s1)
-
-
-def test_host_sharded_duplicate_scores_tie_break(catalog):
-    """Duplicate scores across shard boundaries: the two-key merge sort
-    must reproduce lax.top_k's tie order (lowest global index first)."""
-    items = np.ones((64, 4), np.float32)  # every item scores identically
-    uv = np.ones(4, np.float32)
-    for shards in (2, 4):
-        cat = put_host_sharded_catalog(items, _rows_for(64, shards))
-        s0, i0 = top_k_items(uv, items, 9)
-        s1, i1 = host_sharded_top_k_items(uv, cat, 9)
-        np.testing.assert_array_equal(i0, i1)
-        np.testing.assert_array_equal(s0, s1)
-
-
-@pytest.mark.parametrize("shards", [2, 4])
-def test_host_sharded_similarity_bit_identical(catalog, shards):
-    normed = normalize_rows(catalog)
-    cat = put_host_sharded_catalog(normed, _rows_for(len(catalog), shards))
-    rng = np.random.default_rng(15)
-    qvecs = catalog[rng.integers(0, len(catalog), size=3)]
-    exclude = np.zeros(len(catalog), bool)
-    exclude[:5] = True
-    s0, i0 = similar_items(qvecs, normed, 10, exclude=exclude)
-    s1, i1 = host_sharded_similar_items(qvecs, cat, 10, exclude=exclude)
-    np.testing.assert_array_equal(i0, i1)
-    np.testing.assert_array_equal(s0, s1)
-
-
-@pytest.mark.parametrize("shards", [2, 4])
-def test_host_sharded_batch_identical_selection(catalog, shards):
-    cat = put_host_sharded_catalog(catalog, _rows_for(len(catalog), shards))
-    rng = np.random.default_rng(16)
-    uvecs = rng.normal(size=(5, catalog.shape[1])).astype(np.float32)
-    s0, i0 = batch_top_k(uvecs, catalog, 10)
-    s1, i1 = host_sharded_batch_top_k(uvecs, cat, 10)
+    excl = rng.random(len(catalog)) < 0.5
+    for a, b in (
+            (flat.top_k(uv, 10), sharded.top_k(uv, 10)),
+            (flat.top_k(uv, 10, exclude=excl),
+             sharded.top_k(uv, 10, exclude=excl))):
+        np.testing.assert_array_equal(a[1], b[1])
+        np.testing.assert_array_equal(a[0], b[0])
+    uvs = rng.normal(size=(5, catalog.shape[1])).astype(np.float32)
+    (s0, i0), (s1, i1) = flat.batch_top_k(uvs, 10), sharded.batch_top_k(uvs, 10)
     np.testing.assert_array_equal(i0, i1)
     np.testing.assert_allclose(s0, s1, rtol=0, atol=4e-6)  # gemm ULPs
-
-
-def _toy_indicators(rng, n_items: int, kc: int = 6) -> Indicators:
-    idx = rng.integers(-1, n_items, size=(n_items, kc)).astype(np.int32)
-    score = rng.random((n_items, kc)).astype(np.float32)
-    return Indicators(idx=idx, score=score)
-
-
-@pytest.mark.parametrize("shards", [1, 2, 4])
-def test_host_sharded_ur_score_user_bit_identical(shards):
-    """Universal-recommender scoring: the per-type correlator tables
-    shard the same way and the merged answer is bitwise identical
-    (row-wise einsum reduction is row-count-invariant)."""
-    rng = np.random.default_rng(17)
-    n_items = 101
-    rows = _rows_for(n_items, shards)
-    inds = {"view": _toy_indicators(rng, n_items),
-            "buy": _toy_indicators(rng, n_items, kc=3)}
-    membership = {n: (rng.random(n_items) < 0.3).astype(np.float32)
-                  for n in inds}
-    boost = np.where(rng.random(n_items) < 0.1, 2.0, 1.0).astype(np.float32)
-    exclude = rng.random(n_items) < 0.2
-    plain = [(inds[n], membership[n], b)
-             for n, b in (("view", 1.0), ("buy", 2.0))]
-    s0, i0 = score_user(plain, 10, exclude=exclude, item_boost=boost)
-    sharded = [(put_host_sharded_indicators(inds[n], rows), membership[n], b)
-               for n, b in (("view", 1.0), ("buy", 2.0))]
-    s1, i1 = host_sharded_score_user(sharded, 10, n_items,
-                                     exclude, boost)
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-    np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
-
-
-def test_sharded_catalog_facade_layout_selection(catalog, monkeypatch):
-    """ShardedCatalog picks flat with the knob unset, host when the
-    knob is smaller than the vocabulary, flat when it is not."""
-    monkeypatch.delenv("PIO_SERVE_SHARD_ITEMS", raising=False)
-    assert ShardedCatalog(catalog).layout == "flat"
-    monkeypatch.setenv("PIO_SERVE_SHARD_ITEMS", "100")
-    cat = ShardedCatalog(catalog)
-    assert cat.layout == "host" and cat.n_shards == 11
-    s0, i0 = top_k_items(np.ones(catalog.shape[1], np.float32), catalog, 10)
-    s1, i1 = cat.top_k(np.ones(catalog.shape[1], np.float32), 10)
+    normed = normalize_rows(catalog)
+    (s0, i0), (s1, i1) = (
+        c.similar(catalog[[3, 77]], 9, exclude=excl)
+        for c in (ShardedCatalog(normed),
+                  ShardedCatalog(normed, serving_mesh=mesh)))
     np.testing.assert_array_equal(i0, i1)
     np.testing.assert_array_equal(s0, s1)
-    monkeypatch.setenv("PIO_SERVE_SHARD_ITEMS", str(len(catalog) + 1))
-    assert ShardedCatalog(catalog).layout == "flat"
-
-
-def test_sharded_indicators_facade_layout_selection(monkeypatch):
-    rng = np.random.default_rng(18)
-    inds = {"view": _toy_indicators(rng, 40)}
-    monkeypatch.delenv("PIO_SERVE_SHARD_ITEMS", raising=False)
-    assert ShardedIndicators(inds, 40).layout == "flat"
-    monkeypatch.setenv("PIO_SERVE_SHARD_ITEMS", "16")
-    si = ShardedIndicators(inds, 40)
-    assert si.layout == "host"
-    m = (rng.random(40) < 0.4).astype(np.float32)
-    s0, i0 = score_user([(inds["view"], m, 1.0)], 5,
-                        exclude=None, item_boost=None)
-    s1, i1 = si.score_user([("view", m, 1.0)], 5,
-                           exclude=None, item_boost=None)
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
-    np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
 
 
 @pytest.mark.parametrize("k,excluded", [(10, False), (4, True)])
-def test_three_layouts_bit_identical_across_block_selection(
-        mesh8, monkeypatch, k, excluded):
+def test_two_layouts_bit_identical_across_block_selection(
+        mesh8, k, excluded):
     """A catalog large enough that the flat layout selects by blocks
     (ops/topk._select_topk) instead of ``lax.top_k`` of the whole row:
-    flat, mesh and host still answer bit for bit alike, through the
-    facade, with and without a business-rule mask, on heavy ties too."""
+    flat and mesh still answer bit for bit alike, through the facade,
+    with and without a business-rule mask, on heavy ties too."""
     from incubator_predictionio_tpu.ops import topk
 
     n_items, rank = 4099, 16  # not a multiple of 8, of 128 or of the shards
@@ -550,18 +436,14 @@ def test_three_layouts_bit_identical_across_block_selection(
     items = rng.normal(size=(n_items, rank)).astype(np.float32)
     items[rng.integers(0, n_items, 600)] = items[7]  # duplicate rows: ties
     exclude = (rng.random(n_items) < 0.4) if excluded else None
-    monkeypatch.delenv("PIO_SERVE_SHARD_ITEMS", raising=False)
     flat = ShardedCatalog(items)
     mesh = ShardedCatalog(items, serving_mesh=mesh8)
-    monkeypatch.setenv("PIO_SERVE_SHARD_ITEMS", "1000")
-    host = ShardedCatalog(items)
-    assert (flat.layout, mesh.layout, host.layout) == ("flat", "mesh", "host")
+    assert (flat.layout, mesh.layout) == ("flat", "mesh")
     blocks = topk._M_SELECT.labels("blocks")
     before = blocks.value()
     for uv in (rng.normal(size=rank).astype(np.float32), items[7]):
         s0, i0 = flat.top_k(uv, k, exclude=exclude)
-        for other in (mesh, host):
-            s1, i1 = other.top_k(uv, k, exclude=exclude)
-            np.testing.assert_array_equal(i0, i1)
-            np.testing.assert_array_equal(s0, s1)  # bitwise
+        s1, i1 = mesh.top_k(uv, k, exclude=exclude)
+        np.testing.assert_array_equal(i0, i1)
+        np.testing.assert_array_equal(s0, s1)  # bitwise
     assert blocks.value() == before + 2  # the flat calls, and only they
