@@ -486,6 +486,109 @@ def _all_stripes_sharded(lo_effs, light, heavy, n_i, n_j, n_total, *,
     )(light, heavy)
 
 
+def _run_events(stage: str, events: dict, work, threaded: bool) -> dict:
+    """One host stage of a train over its distinct events:
+    ``{name: work(*args)}`` for ``events`` = name -> args, under ONE span
+    ``stage`` opened here on the calling thread — its duration is the
+    stage's wall whether the events ran side by side or in turn; tags
+    ``events`` and ``threads`` (1 = in turn) — with each event's own pass
+    a child span ``<stage>.event`` (tags ``event``, ``pairs`` = the length
+    of the pass's input). ``threaded`` runs them through
+    ``host_parallel``: the native passes are ctypes calls that release
+    the GIL and share nothing but read-only inputs. A worker thread
+    starts with an empty context, so each thunk runs in a copy of the
+    caller's — the child spans hang under the stage's span, in the
+    train's trace. The first exception reaches the caller, after every
+    thread was joined."""
+    import contextvars
+
+    from ..workflow.input_pipeline import host_parallel
+
+    def one(name, args):
+        with telemetry.span(stage + ".event", event=name,
+                            pairs=len(args[0])):
+            return work(*args)
+
+    with telemetry.span(stage, events=len(events),
+                        threads=len(events) if threaded else 1):
+        thunks = [functools.partial(contextvars.copy_context().run, one,
+                                    name, args)
+                  for name, args in events.items()]
+        done = host_parallel(*thunks) if threaded else [t() for t in thunks]
+    return dict(zip(events, done))
+
+
+def _prepare_events(events: dict, n_users: int, n_items: int, u_chunk: int,
+                    n_mesh_dev: int) -> dict:
+    """The host half of one cross-occurrence train: ``events`` = name ->
+    (u, i), the DISTINCT events (a self pair is its primary, and is not
+    here twice), become name -> (light (eu, ei), heavy (eu, ei) or None,
+    per-item distinct-user counts as float32).
+
+    Two stages, each over all the events at once (``_run_events``), side
+    by side as the ALS layout runs its two sides
+    (``rowblocks.plan_and_fill_both``: unless PIO_PIPELINE=off) when
+    there is more than one event:
+
+    - ``cco.dedupe``: ``_dedupe_pair`` of each event — looked up in the
+      module at call time, once an event: a traced benchmark run wraps
+      that attribute. Output is (user, item)-sorted, which the partition
+      relies on.
+    - the barrier: ``_heavy_ranks`` needs the SUMMED per-user counts of
+      every event (the threshold only shapes the layout, never the
+      counts, so any consistent choice keeps results identical — and
+      this one keeps the ranges, the widths and so the executables what
+      they were when the events ran in turn).
+    - ``cco.partition``: the [ranges, E] slabs of each event (one-pass
+      native C when available, else the identical NumPy layout; with the
+      start of its puts 0.26 s for the ML-20M retrain's 10.0M buy pairs
+      and 0.53-0.63 s for its 20.0M view pairs, side by side, span
+      ``cco.partition.event``; chip host, PR 32). On one
+      device each event STARTS its own ``device_put``s as soon as its
+      slabs exist, so one event's upload runs under another's layout; on
+      a mesh the slabs stay HOST arrays padded to a device multiple —
+      the jit uploads them sharded (an eager put would land everything
+      on one device first)."""
+    from ..workflow.input_pipeline import PipelineConfig
+
+    threaded = (len(events) > 1
+                and PipelineConfig.from_env().mode != "off")
+    n_ranges = max((n_users + u_chunk - 1) // u_chunk, 1)
+
+    deduped = _run_events(
+        "cco.dedupe", events,
+        lambda u, i: _dedupe_pair(u, i, n_users, n_items), threaded)
+    rank, h_ranges = _heavy_ranks(
+        sum(cnt for _u, _i, cnt in deduped.values()), n_users)
+
+    def partition_put(u, i):
+        try:
+            from ..native import cco_partition
+
+            light, heavy, counts = cco_partition(
+                u, i, rank, n_users, u_chunk, n_ranges, n_items,
+                _HEAVY_RANGE, h_ranges)
+        except Exception:  # noqa: BLE001 - native optional; layout identical
+            light, heavy = _layout_event(u, i, rank, h_ranges, u_chunk,
+                                         n_ranges, n_items)
+            heavy = heavy or None
+            counts = np.bincount(i, minlength=n_items)
+        if n_mesh_dev > 1:
+            light = _pad_ranges(light, n_mesh_dev, u_chunk)
+            if heavy is not None:
+                heavy = _pad_ranges(heavy, n_mesh_dev, _HEAVY_RANGE)
+        else:
+            light = tuple(jax.device_put(x) for x in light)
+            if heavy is not None:
+                heavy = tuple(jax.device_put(x) for x in heavy)
+        return light, heavy, counts.astype(np.float32)
+
+    return _run_events(
+        "cco.partition",
+        {name: (u, i) for name, (u, i, _cnt) in deduped.items()},
+        partition_put, threaded)
+
+
 def cco_indicators(
     primary_u: np.ndarray,
     primary_i: np.ndarray,
@@ -507,19 +610,15 @@ def cco_indicators(
     scans + one exact psum over ICI) — bit-identical results, linear
     range-scan scaling."""
 
-    # Packed-key dedupe (native when available); output is
-    # (user, item)-sorted, which every partition below relies on.
-    with telemetry.span("cco.dedupe"):
-        pu, pi, cnt_p = _dedupe_pair(primary_u, primary_i, n_users, n_items)
-        su, si, cnt_s = _dedupe_pair(secondary_u, secondary_i, n_users,
-                                     n_items)
-    n_ranges = max((n_users + u_chunk - 1) // u_chunk, 1)
     n_mesh_dev = int(mesh.devices.size) if mesh is not None else 1
     full_fits = n_items * n_items <= _full_matrix_elem_cap()
-    with telemetry.span("cco.partition"):
-        light, heavy, n_i, n_j = _partition_pair(
-            pu, pi, cnt_p, su, si, cnt_s, n_users, n_items, u_chunk,
-            n_ranges, n_mesh_dev)
+    # both sides go through the dedupe, even the same arrays twice: the
+    # per-pair program has no self pair
+    (light_p, heavy_p, n_i), (light_s, heavy_s, n_j) = _prepare_events(
+        {0: (primary_u, primary_i), 1: (secondary_u, secondary_i)},
+        n_users, n_items, u_chunk, n_mesh_dev).values()
+    light = light_p + light_s
+    heavy = heavy_p + heavy_s if heavy_p is not None else None
     n_total = jnp.float32(n_users)
 
     k = min(max_correlators, n_items)
@@ -608,41 +707,15 @@ def _layout_event(u, i, rank, h_ranges: int, u_chunk: int, n_ranges: int,
         h_ranges, n_items, assume_sorted=True)
 
 
-def _partition_pair(pu, pi, cnt_p, su, si, cnt_s, n_users: int,
-                    n_items: int, u_chunk: int, n_ranges: int,
-                    n_mesh_dev: int):
-    """Host layout of ONE pair for ``cco_indicators``: (light, heavy or
-    None) as (peu, pei, seu, sei) tuples — device arrays on one device,
-    host arrays padded to a device multiple on a mesh (the jit uploads
-    them sharded, no eager single-device copy first) — and the distinct-
-    user counts n_i, n_j."""
-    rank, h_ranges = _heavy_ranks(cnt_p + cnt_s, n_users)
-    light_p, heavy_p = _layout_event(pu, pi, rank, h_ranges, u_chunk,
-                                     n_ranges, n_items)
-    light_s, heavy_s = _layout_event(su, si, rank, h_ranges, u_chunk,
-                                     n_ranges, n_items)
-    light = light_p + light_s
-    heavy = (heavy_p + heavy_s) or None
-    n_i = np.bincount(pi, minlength=n_items).astype(np.float32)
-    n_j = np.bincount(si, minlength=n_items).astype(np.float32)
-    if n_mesh_dev > 1:
-        light = _pad_ranges(light, n_mesh_dev, u_chunk)
-        if heavy is not None:
-            heavy = _pad_ranges(heavy, n_mesh_dev, _HEAVY_RANGE)
-    else:
-        light = tuple(map(jnp.asarray, light))
-        if heavy is not None:
-            heavy = tuple(map(jnp.asarray, heavy))
-    return light, heavy, n_i, n_j
-
-
 def _dedupe_pair(u, i, n_users: int, n_items: int):
     """Distinct (user, item) pairs sorted by (user, item), out-of-range
     ids dropped. Native path: counting-sort by user + small per-user
-    sorts (two linear passes — a global 16-bit-radix sort was tried
-    first and LOST to numpy's introsort at 8M keys, 0.76 s vs 0.31 s;
-    the per-user bucketing beats both at ~0.15 s). The numpy packed-key
-    np.unique fallback is order-identical (tested).
+    sorts (two linear passes; a global 16-bit-radix sort was tried
+    first and LOST to numpy's introsort). One call is one thread: 0.51 s
+    for the 10.0M buy events and 1.07 s for the 20.0M view events of
+    the ML-20M retrain, side by side, span ``cco.dedupe.event`` (chip
+    host, PR 32) — the larger event is the stage's wall. The numpy
+    packed-key np.unique fallback is order-identical (tested).
 
     Returns (users, items, per_user_distinct_counts)."""
     try:
@@ -721,70 +794,22 @@ def cco_indicators_multi(
             for name, (su, si) in secondaries.items()
         }
 
-    with telemetry.span("cco.dedupe"):
-        pu, pi, per_user = _dedupe_pair(primary_u, primary_i, n_users,
-                                        n_items)
-        per_user = per_user.astype(np.int64, copy=True)
-        deduped = {}
-        for name, (su, si) in secondaries.items():
-            if su is primary_u and si is primary_i:
-                deduped[name] = None  # self-pair: reuse primary everywhere
-            else:
-                du, di, cnt = _dedupe_pair(su, si, n_users, n_items)
-                deduped[name] = (du, di)
-                # Heavy-user extraction over the COMBINED activity
-                # (primary + every distinct secondary): the threshold only
-                # shapes the layout, never the counts, so any consistent
-                # choice keeps results identical.
-                per_user += cnt
-    rank, h_ranges = _heavy_ranks(per_user, n_users)
-    n_heavy = rank is not None
-
-    n_ranges = max((n_users + u_chunk - 1) // u_chunk, 1)
-
-    def partition_put(u, i):
-        """Partition (one-pass native C when available — the numpy
-        fancy-index layout measured ~1.0 s of pure host time at the UR
-        bench's 10M pairs) + START the async uploads immediately, so a
-        later secondary's host partition overlaps this one's transfer."""
-        try:
-            from ..native import cco_partition
-
-            light, heavy, counts = cco_partition(
-                u, i, rank, n_users, u_chunk, n_ranges, n_items,
-                _HEAVY_RANGE, h_ranges)
-        except Exception:  # noqa: BLE001 - native optional; layout identical
-            light, heavy = _layout_event(u, i, rank, h_ranges, u_chunk,
-                                         n_ranges, n_items)
-            heavy = heavy or None
-            counts = np.bincount(i, minlength=n_items)
-        if n_mesh_dev > 1:
-            # multi-chip: pad the range axis to a device multiple and
-            # hand the jit the HOST arrays — it uploads them sharded
-            # (an eager put would land everything on one device first)
-            light = _pad_ranges(light, n_mesh_dev, u_chunk)
-            if heavy is not None:
-                heavy = _pad_ranges(heavy, n_mesh_dev, _HEAVY_RANGE)
-            return light, heavy, counts.astype(np.float32)
-        light_dev = tuple(jax.device_put(x) for x in light)
-        heavy_dev = (tuple(jax.device_put(x) for x in heavy)
-                     if heavy is not None else None)
-        return light_dev, heavy_dev, counts.astype(np.float32)
-
-    with telemetry.span("cco.partition"):
-        p_light, p_heavy, n_i = partition_put(pu, pi)
-        self_flags = tuple(deduped[name] is None for name in names)
-        sec_light, sec_heavy, n_js = [], [], []
-        for name in names:
-            pair = deduped[name]
-            if pair is None:
-                n_js.append(n_i)
-                continue
-            sl, sh, cnt = partition_put(*pair)
-            sec_light.append(sl)
-            if n_heavy:
-                sec_heavy.append(sh)
-            n_js.append(cnt)
+    # a secondary that is the primary's OWN arrays is the self pair: it
+    # reuses the primary's slabs and counts everywhere, and is no event
+    # of its own (the primary goes under the index 0, which is no name)
+    self_flags = tuple(su is primary_u and si is primary_i
+                       for su, si in secondaries.values())
+    others = [name for name, is_self in zip(names, self_flags)
+              if not is_self]
+    prepared = _prepare_events(
+        {0: (primary_u, primary_i), **{n: secondaries[n] for n in others}},
+        n_users, n_items, u_chunk, n_mesh_dev)
+    p_light, p_heavy, n_i = prepared[0]
+    n_heavy = p_heavy is not None
+    sec_light = [prepared[n][0] for n in others]
+    sec_heavy = [prepared[n][1] for n in others] if n_heavy else []
+    n_js = [n_i if is_self else prepared[name][2]
+            for name, is_self in zip(names, self_flags)]
     k = min(max_correlators, n_items)
     block = min(item_block, n_items)
     los = list(range(0, n_items, block))
