@@ -14,7 +14,15 @@ from typing import Mapping, Optional, Sequence
 
 import numpy as np
 
+from ..common import telemetry
 from ..data.storage.bimap import BiMap
+
+_M_RULES = telemetry.registry().counter(
+    "pio_query_rules_total",
+    "Serve-time business rules applied by build_exclude_mask, one count "
+    "a rule a query: categories, whiteList, blackList, extra (seen, "
+    "unavailable or query items handed in by the template), or none.",
+    ("rule",))
 
 
 class CategoryIndex:
@@ -45,6 +53,15 @@ class CategoryIndex:
         return out
 
 
+def _suppress(exclude: np.ndarray, items: BiMap,
+              ids: Optional[Sequence[str]]) -> int:
+    """Mark the catalog rows of ``ids`` in ``exclude``; ids the catalog
+    does not know are skipped. Returns how many rows were marked."""
+    rows = [j for j in map(items.get, ids or ()) if j is not None]
+    exclude[rows] = True
+    return len(rows)
+
+
 def build_exclude_mask(
     items: BiMap,
     category_index: Optional[CategoryIndex] = None,
@@ -56,25 +73,30 @@ def build_exclude_mask(
     """True = suppressed. Combines the reference templates' rules:
     category membership (must match one), whitelist (only these),
     blacklist, plus arbitrary extra item ids (seen/unavailable/query
-    items)."""
-    n = len(items)
-    exclude = np.zeros(n, dtype=bool)
-    if categories and category_index is not None:
-        exclude |= ~category_index.any_of(categories)
-    if white_list:
-        allowed = {items.get(w) for w in white_list} - {None}
-        mask = np.ones(n, dtype=bool)
-        if allowed:
-            mask[list(allowed)] = False
-        exclude |= mask
-    if black_list:
-        for b in black_list:
-            j = items.get(b)
-            if j is not None:
-                exclude[j] = True
-    if extra_excluded_items:
-        for x in extra_excluded_items:
-            j = items.get(x)
-            if j is not None:
-                exclude[j] = True
+    items).
+
+    Span ``query.mask_build`` (tags ``rules``: the rules this query
+    carried, joined by ``+``, or ``none``; ``excluded``: the catalog
+    rows that blackList and the extra ids resolved to, the sparse part
+    of the mask) and counter ``pio_query_rules_total{rule}``, one count
+    a rule a query."""
+    rules = [name for name, given in (
+        ("categories", categories and category_index is not None),
+        ("whiteList", white_list), ("blackList", black_list),
+        ("extra", extra_excluded_items)) if given] or ["none"]
+    for name in rules:
+        _M_RULES.labels(name).inc()
+    with telemetry.span("query.mask_build", rules="+".join(rules)) as sp:
+        n = len(items)
+        exclude = np.zeros(n, dtype=bool)
+        if categories and category_index is not None:
+            exclude |= ~category_index.any_of(categories)
+        if white_list:
+            allowed = {items.get(w) for w in white_list} - {None}
+            mask = np.ones(n, dtype=bool)
+            if allowed:
+                mask[list(allowed)] = False
+            exclude |= mask
+        sp.tag(excluded=_suppress(exclude, items, black_list)
+               + _suppress(exclude, items, extra_excluded_items))
     return exclude

@@ -19,6 +19,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..common import telemetry
 from ..controller import Algorithm, Engine, EngineFactory, Params
 from ..data.store.l_event_store import LEventStore
 from ..data.store.p_event_store import PEventStore
@@ -102,26 +103,30 @@ class ECommerceModel(ShardedCatalogServing):
     def _seen_items(self, user: str) -> set[str]:
         """Serve-time LEventStore read (reference: ECommAlgorithm.predict
         querying recent view events)."""
-        try:
-            events = LEventStore.find_by_entity(
-                self.app_name, "user", user,
-                event_names=list(self.seen_event_names),
-                limit=200, storage=self._storage,
-            )
-        except Exception:
-            return set()
+        with telemetry.span("query.store_read", what="seen") as sp:
+            try:
+                events = LEventStore.find_by_entity(
+                    self.app_name, "user", user,
+                    event_names=list(self.seen_event_names),
+                    limit=200, storage=self._storage,
+                )
+            except Exception:
+                return set()
+            sp.tag(events=len(events))
         return {e.target_entity_id for e in events if e.target_entity_id}
 
     def _unavailable_items(self) -> set[str]:
         """$set constraint entity (reference: ECommAlgorithm
         unavailableItems constraint)."""
-        try:
-            events = LEventStore.find_by_entity(
-                self.app_name, "constraint", "unavailableItems",
-                event_names=["$set"], limit=1, storage=self._storage,
-            )
-        except Exception:
-            return set()
+        with telemetry.span("query.store_read", what="unavailable") as sp:
+            try:
+                events = LEventStore.find_by_entity(
+                    self.app_name, "constraint", "unavailableItems",
+                    event_names=["$set"], limit=1, storage=self._storage,
+                )
+            except Exception:
+                return set()
+            sp.tag(events=len(events))
         for e in events:
             return set(e.properties.get_or_else("items", []))
         return set()

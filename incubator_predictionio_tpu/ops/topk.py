@@ -140,6 +140,13 @@ def top_k_items(user_vec, item_factors, k: int, exclude=None):
     # compiled to: the predicate _topk_scores itself traces with.
     select = "blocks" if _select_block_len(n_items, k) else "direct"
     _M_SELECT.labels(select).inc()
+    if isinstance(exclude, np.ndarray):
+        # a mask built on the host this query (the business rules of
+        # models/_filters.py): its bytes cross here, under a span of their
+        # own, so that topk.dispatch stays the enqueue alone. A resident
+        # mask (_no_exclude_mask) takes no put.
+        with telemetry.span("topk.mask_put", bytes=exclude.nbytes):
+            exclude = jax.device_put(exclude)
     with telemetry.span("topk.dispatch", select=select):
         out = _topk_scores(user_vec, item_factors, exclude, k)
     # Single host transfer: each device_get is a round trip, so (scores,

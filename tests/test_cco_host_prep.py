@@ -70,9 +70,16 @@ def fused_fits(monkeypatch):
     monkeypatch.delenv("PIO_PIPELINE", raising=False)
 
 
+def span_mark() -> int:
+    """The highest span id handed out so far: a cursor that still works
+    once the ring is full (its length then stands still; another test
+    file of the same worker may have filled it)."""
+    return max((s.span_id for s in telemetry.spans_snapshot()), default=0)
+
+
 def spans_since(seen: int, prefix: str = "cco."):
-    return [s for s in telemetry.spans_snapshot()[seen:]
-            if s.name.startswith(prefix)]
+    return [s for s in telemetry.spans_snapshot()
+            if s.span_id > seen and s.name.startswith(prefix)]
 
 
 @pytest.mark.parametrize("entry", list(ENTRIES))
@@ -91,7 +98,7 @@ def test_side_by_side_and_in_turn_agree_bit_for_bit(monkeypatch, entry):
     got = {}
     for mode in ("auto", "off"):
         monkeypatch.setenv("PIO_PIPELINE", mode)
-        seen = len(telemetry.spans_snapshot())
+        seen = span_mark()
         got[mode] = run(events)
         stages = [s for s in spans_since(seen)
                   if s.name in ("cco.dedupe", "cco.partition")]
@@ -124,7 +131,7 @@ def test_one_span_a_stage_and_one_child_an_event(monkeypatch, entry, mode):
     run, distinct = ENTRIES[entry]
     monkeypatch.setenv("PIO_PIPELINE", mode)
     events = seeded_events()
-    seen = len(telemetry.spans_snapshot())
+    seen = span_mark()
     with telemetry.span("train.run", trace_id="train-32") as root:
         run(events)
     spans = spans_since(seen)
@@ -155,7 +162,7 @@ def test_one_span_a_stage_and_one_child_an_event(monkeypatch, entry, mode):
 
 def test_a_self_pair_alone_runs_on_the_calling_thread():
     buy = seeded_events()["buy"]
-    seen = len(telemetry.spans_snapshot())
+    seen = span_mark()
     got = llr.cco_indicators_multi(
         *buy, {"buy": buy, "again": buy}, n_users=N_USERS, n_items=N_ITEMS,
         max_correlators=K, u_chunk=U_CHUNK, item_block=64)

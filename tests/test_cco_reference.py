@@ -39,9 +39,16 @@ def seeded_events(heavy: bool):
     return events
 
 
+def span_mark() -> int:
+    """The highest span id handed out so far: a cursor that still works
+    once the ring is full (its length then stands still; another test
+    file of the same worker may have filled it)."""
+    return max((s.span_id for s in telemetry.spans_snapshot()), default=0)
+
+
 def cco_device_spans(since: int):
-    return [s for s in telemetry.spans_snapshot()[since:]
-            if s.name == "cco.device"]
+    return [s for s in telemetry.spans_snapshot()
+            if s.span_id > since and s.name == "cco.device"]
 
 
 @pytest.mark.parametrize("laddered", [False, True],
@@ -63,7 +70,7 @@ def test_indicators_match_the_plain_reference(monkeypatch, path, heavy,
                             lambda *a, **kw: 1 / 0)
     events = seeded_events(heavy)
     primary = events["buy"]
-    seen = len(telemetry.spans_snapshot())
+    seen = span_mark()
     got = llr.cco_indicators_multi(
         *primary, events, n_users=N_USERS, n_items=N_ITEMS,
         max_correlators=K, u_chunk=U_CHUNK, item_block=64)
@@ -183,9 +190,10 @@ def test_run_train_deploy_and_predict_match_plain_scoring(memory_storage):
             # the two sides may break differently
             "appName": "ccoref", "maxCorrelatorsPerItem": n_items,
             "user_chunk": 16}}]})
-    seen = len(telemetry.spans_snapshot())
+    seen = span_mark()
     instance = run_train(engine, params, ctx)
-    names = {s.name for s in telemetry.spans_snapshot()[seen:]}
+    names = {s.name for s in telemetry.spans_snapshot()
+             if s.span_id > seen}
     assert {"cco.dedupe", "cco.partition", "cco.device", "cco.gather",
             "ur.popularity"} <= names
     dep, _inst, _ = load_deployment(engine, instance, ctx)
