@@ -1,21 +1,33 @@
 """Shared serving-time business-rule filters for the recommender
 templates (similar-product, e-commerce, universal recommender).
 
-One implementation of the category / whiteList / blackList exclude-mask
-(reference: each template's predict applies the same rules). Category
-membership is precomputed into per-category boolean masks at model
-build/restore time so the per-query cost is a few numpy vector ops, not a
-Python loop over the catalog.
+One implementation of the category / whiteList / blackList rules
+(reference: each template's predict applies the same rules):
+``_resolve`` turns a query's lists into a sparse description (`Rules`),
+and the two forms a kernel takes are made from that one description. The
+DENSE form is a fresh ``bool[n_items]`` on the host (`build_exclude_mask`:
+similar-product, the universal recommender, the ``mesh`` serving layout).
+The ROW form (`build_exclude` with ``rows=True``: the e-commerce template
+on the ``flat`` layout) hands the kernel the rows themselves and a
+category mask that already lives on the device, and nothing of catalog
+length is allocated or shipped for the query. Category membership is
+precomputed into per-category boolean masks at first use, so the
+per-query cost is a few vector ops or none, not a Python loop over the
+catalog.
 """
 
 from __future__ import annotations
 
-from typing import Mapping, Optional, Sequence
+import functools
+from typing import Mapping, NamedTuple, Optional, Sequence, Union
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..common import telemetry
 from ..data.storage.bimap import BiMap
+from ..ops.topk import RowExclude, row_capacity
 
 _M_RULES = telemetry.registry().counter(
     "pio_query_rules_total",
@@ -24,14 +36,39 @@ _M_RULES = telemetry.registry().counter(
     "unavailable or query items handed in by the template), or none.",
     ("rule",))
 
+_M_MASK_PATH = telemetry.registry().counter(
+    "pio_query_mask_path_total",
+    "Queries by where their exclude mask was composed: device = the rows "
+    "and a resident category mask handed to the top-k kernel; dense = a "
+    "bool[n_items] built on the host (lists over the row ladder's top, "
+    "the mesh layout, similar-product, the universal recommender).",
+    ("path",))
+
+
+@functools.lru_cache(maxsize=None)
+def _all_excluded(n_items: int):
+    """Device-resident all-True mask, one per catalog size: what a
+    category that holds no item excludes. Shared, so that category names
+    the catalog does not know (they come from queries) cannot fill the
+    device with masks."""
+    return jax.device_put(np.ones((n_items,), dtype=bool))
+
 
 class CategoryIndex:
-    """category name → bool mask [n_items] (lazily built, cached)."""
+    """category name → bool mask [n_items] (lazily built, cached).
+
+    Two forms a category, both built at first use and kept: ``mask`` /
+    ``any_of``, HOST arrays "in this category" for the dense form of the
+    rules; ``device_exclude``, a ``jax.Array`` "NOT in this category"
+    resident on the device (``n_items`` bytes a category: 24 x 9.4 MB =
+    226 MB for the Amazon catalog), which the row form passes to the
+    kernel as its base mask with no transfer."""
 
     def __init__(self, items: BiMap, item_categories: Mapping[str, set]):
         self._items = items
         self._cats = item_categories
         self._masks: dict[str, np.ndarray] = {}
+        self._device_not_in: dict[str, jax.Array] = {}
 
     def mask(self, category: str) -> np.ndarray:
         m = self._masks.get(category)
@@ -52,14 +89,120 @@ class CategoryIndex:
             out |= self.mask(c)
         return out
 
+    def device_exclude(self, categories: Sequence[str]) -> jax.Array:
+        """``~any_of(categories)`` as a device array: for ONE category
+        the resident mask itself; for several, their conjunction composed
+        on the device (one small dispatch a further category, nothing
+        crosses)."""
+        return functools.reduce(
+            jnp.logical_and, map(self._resident_not_in, categories))
 
-def _suppress(exclude: np.ndarray, items: BiMap,
-              ids: Optional[Sequence[str]]) -> int:
-    """Mark the catalog rows of ``ids`` in ``exclude``; ids the catalog
-    does not know are skipped. Returns how many rows were marked."""
-    rows = [j for j in map(items.get, ids or ()) if j is not None]
-    exclude[rows] = True
-    return len(rows)
+    def _resident_not_in(self, category: str) -> jax.Array:
+        m = self._device_not_in.get(category)
+        if m is None:
+            host = self.mask(category)
+            m = (jax.device_put(~host) if host.any()
+                 else _all_excluded(len(host)))
+            self._device_not_in[category] = m
+        return m
+
+
+class Rules(NamedTuple):
+    """What a query's rules come to, sparsely: ``deny``, the int32
+    catalog rows of blackList and the handed-in ids (ids the catalog does
+    not know skipped, duplicates kept); ``allow``, the rows of a whiteList
+    (None where none was given; EMPTY where the catalog knows none of its
+    ids, which suppresses everything); ``categories``, the names an item
+    must match one of (None where none was given, or no `CategoryIndex`
+    to look them up in); ``names``, the rules the query carried."""
+    deny: np.ndarray
+    allow: Optional[np.ndarray]
+    categories: Optional[Sequence[str]]
+    names: list[str]
+
+
+def _rows(items: BiMap, ids: Optional[Sequence[str]]) -> list[int]:
+    """The catalog rows of ``ids``; ids the catalog does not know are
+    skipped."""
+    return [j for j in map(items.get, ids or ()) if j is not None]
+
+
+def _resolve(items, category_index, categories, white_list, black_list,
+             extra_excluded_items) -> Rules:
+    if not (categories and category_index is not None):
+        categories = None
+    names = [name for name, given in (
+        ("categories", categories), ("whiteList", white_list),
+        ("blackList", black_list), ("extra", extra_excluded_items))
+        if given] or ["none"]
+    return Rules(
+        deny=np.asarray(_rows(items, black_list)
+                        + _rows(items, extra_excluded_items), np.int32),
+        allow=(np.asarray(_rows(items, white_list), np.int32)
+               if white_list else None),
+        categories=categories, names=names)
+
+
+def _dense(rules: Rules, n_items: int,
+           category_index: Optional[CategoryIndex]) -> np.ndarray:
+    """The description as a fresh ``bool[n_items]``, True = suppressed."""
+    exclude = np.zeros(n_items, dtype=bool)
+    if rules.categories:
+        exclude |= ~category_index.any_of(rules.categories)
+    if rules.allow is not None:
+        outside = np.ones(n_items, dtype=bool)
+        outside[rules.allow] = False
+        exclude |= outside
+    exclude[rules.deny] = True
+    return exclude
+
+
+def build_exclude(
+    items: BiMap,
+    category_index: Optional[CategoryIndex] = None,
+    categories: Optional[Sequence[str]] = None,
+    white_list: Optional[Sequence[str]] = None,
+    black_list: Optional[Sequence[str]] = None,
+    extra_excluded_items: Optional[Sequence[str]] = None,
+    *,
+    rows: bool = False,
+) -> Union[np.ndarray, RowExclude]:
+    """What `ops/topk.top_k_items` takes as ``exclude`` for these rules:
+    category membership (must match one), whitelist (only these),
+    blacklist, plus arbitrary extra item ids (seen/unavailable/query
+    items).
+
+    ``rows`` says whether the caller's kernel takes rows (the ``flat``
+    serving layout). Where it does and both lists fit
+    `ops/topk.row_capacity`, the answer is a `RowExclude`: the rows, and
+    the categories as `CategoryIndex.device_exclude`'s resident mask
+    (path ``device``). Otherwise (``rows`` False, or a list over the
+    ladder's top: observed from its length) it is the dense
+    ``bool[n_items]`` host mask of the same description (path ``dense``).
+
+    Span ``query.mask_build`` (tags ``rules``: the rules this query
+    carried, joined by ``+``, or ``none``; ``excluded``: the catalog
+    rows that blackList and the extra ids resolved to, the sparse part
+    of the mask; ``path``: ``device`` or ``dense``), counter
+    ``pio_query_rules_total{rule}``, one count a rule a query, and
+    counter ``pio_query_mask_path_total{path}``, one count a query."""
+    with telemetry.span("query.mask_build") as sp:
+        rules = _resolve(items, category_index, categories, white_list,
+                         black_list, extra_excluded_items)
+        for name in rules.names:
+            _M_RULES.labels(name).inc()
+        on_device = rows and row_capacity(rules.deny,
+                                          rules.allow) is not None
+        path = "device" if on_device else "dense"
+        _M_MASK_PATH.labels(path).inc()
+        sp.tag(rules="+".join(rules.names), excluded=len(rules.deny),
+               path=path)
+        if on_device:
+            return RowExclude(
+                base=(category_index.device_exclude(rules.categories)
+                      if rules.categories else None),
+                deny=rules.deny, allow=rules.allow)
+        return _dense(rules, len(items), category_index)
 
 
 def build_exclude_mask(
@@ -70,33 +213,11 @@ def build_exclude_mask(
     black_list: Optional[Sequence[str]] = None,
     extra_excluded_items: Optional[Sequence[str]] = None,
 ) -> np.ndarray:
-    """True = suppressed. Combines the reference templates' rules:
-    category membership (must match one), whitelist (only these),
-    blacklist, plus arbitrary extra item ids (seen/unavailable/query
-    items).
-
-    Span ``query.mask_build`` (tags ``rules``: the rules this query
-    carried, joined by ``+``, or ``none``; ``excluded``: the catalog
-    rows that blackList and the extra ids resolved to, the sparse part
-    of the mask) and counter ``pio_query_rules_total{rule}``, one count
-    a rule a query."""
-    rules = [name for name, given in (
-        ("categories", categories and category_index is not None),
-        ("whiteList", white_list), ("blackList", black_list),
-        ("extra", extra_excluded_items)) if given] or ["none"]
-    for name in rules:
-        _M_RULES.labels(name).inc()
-    with telemetry.span("query.mask_build", rules="+".join(rules)) as sp:
-        n = len(items)
-        exclude = np.zeros(n, dtype=bool)
-        if categories and category_index is not None:
-            exclude |= ~category_index.any_of(categories)
-        if white_list:
-            allowed = {items.get(w) for w in white_list} - {None}
-            mask = np.ones(n, dtype=bool)
-            if allowed:
-                mask[list(allowed)] = False
-            exclude |= mask
-        sp.tag(excluded=_suppress(exclude, items, black_list)
-               + _suppress(exclude, items, extra_excluded_items))
-    return exclude
+    """The DENSE form of the rules: a fresh ``bool[n_items]`` on the host,
+    True = suppressed (`build_exclude` with ``rows=False``, which see for
+    the rules, the span and the counters). For kernels that take a mask
+    per shard (the ``mesh`` layout) and the templates that have not moved
+    to rows (similar-product, the universal recommender); shipped by
+    `top_k_items` under ``topk.mask_put``."""
+    return build_exclude(items, category_index, categories, white_list,
+                         black_list, extra_excluded_items)

@@ -67,9 +67,16 @@ class ShardedCatalog:
         return self._cat.n_shards if self.layout == "mesh" else 1
 
     def top_k(self, user_vec, k: int, exclude=None):
-        """(scores[k'], idx[k']) host numpy; ``exclude`` an optional
-        bool [n_items] business-rule mask (True = suppressed), applied
-        per-shard BEFORE the partial top-k."""
+        """(scores[k'], idx[k']) host numpy. ``exclude`` is the optional
+        business-rule filter (True = suppressed), applied BEFORE the
+        (per-shard, partial) top-k, in one of the forms
+        `models/_filters.build_exclude` makes: on either layout a dense
+        ``bool[n_items]`` host mask, shipped whole every query; on the
+        ``flat`` layout also a ``bool[n_items]`` resident on the device
+        (nothing shipped) or an `ops/topk.RowExclude` (rows and a
+        resident base mask; the mask is composed on the device, see
+        `ops/topk.top_k_items`). The ``mesh`` layout's kernel takes the
+        dense mask only."""
         if self.layout == "mesh":
             return sharded_top_k_items(user_vec, self._cat, k,
                                        exclude=exclude)

@@ -31,7 +31,7 @@ from ._sharded_serving import (
     serving_mesh_for,
     validate_serving_mode,
 )
-from ._filters import CategoryIndex, build_exclude_mask
+from ._filters import CategoryIndex, build_exclude
 from .similar_product import (
     SimilarProductDataSource,
     DataSourceParams as SPDataSourceParams,
@@ -146,14 +146,18 @@ class ECommerceModel(ShardedCatalogServing):
         extra = list(self._unavailable_items())
         if unseen_only:
             extra += list(self._seen_items(user))
-        exclude = build_exclude_mask(
+        # flat layout: the rules go to the kernel as rows and a resident
+        # category mask, and the mask is composed on the device; the mesh
+        # layout's kernel takes a dense mask per shard. Either way the
+        # rules apply BEFORE the (partial) top-k (ShardedCatalog
+        # contract) — filtered items never inflate the candidate merge
+        catalog = self.catalog()
+        exclude = build_exclude(
             self.items, self.category_index(), categories,
             white_list, black_list, extra_excluded_items=extra,
+            rows=catalog.layout == "flat",
         )
-        # business-rule mask applied per-shard BEFORE each partial
-        # top-k (ShardedCatalog contract) — filtered items never
-        # inflate the candidate merge
-        scores, idx = self.catalog().top_k(
+        scores, idx = catalog.top_k(
             self.factors.user_factors[uidx], num, exclude=exclude)
         return [
             (self.items.inverse(int(j)), float(s))

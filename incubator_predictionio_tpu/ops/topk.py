@@ -12,12 +12,18 @@ JSON parsing only.
 reaches the same values and indices, ties included, through
 `_select_topk`: block maxima, the k best blocks, a sort of their k·L
 candidates. Small catalogs call `lax.top_k` itself (`_select_block_len`).
+
+What a query suppresses reaches `_topk_scores` as a resident ``bool[n_items]``
+mask, or as that plus ROWS (`RowExclude`): the mask the e-commerce rules
+describe is then composed on the device, inside the same executable, and
+the query ships a few KB of row indices, not a mask of catalog length.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -95,8 +101,82 @@ def _select_topk(scores, k: int, block_len: int):
     return -neg[:k], idx[:k]
 
 
+#: row capacities a `RowExclude`'s lists are padded to, smallest first:
+#: the rows' SHAPE is a step of this ladder and never the lists' lengths,
+#: so one executable per (k, step) serves every query. The floor holds a
+#: shop's usual query with room (serve-ecomm9m-rules-p4: at most 2,223
+#: suppressed rows, a whiteList of at most 500); the one step above it is
+#: 8 times that, where resolving the ids on the host (a dict lookup each)
+#: already costs more than a dense mask does. Longer lists take the dense
+#: host mask.
+_ROW_LADDER = (4096, 32768)
+
+
+def row_capacity(deny, allow) -> Optional[int]:
+    """The first step of `_ROW_LADDER` that holds each of the two row
+    lists (``allow`` may be None), or None where one is over the ladder's
+    top."""
+    longest = max(len(deny), 0 if allow is None else len(allow))
+    return next((step for step in _ROW_LADDER if longest <= step), None)
+
+
+class RowExclude(NamedTuple):
+    """What a query suppresses, as `_topk_scores` composes it on the
+    device: ``base``, a ``bool[n_items]`` RESIDENT on the device (True =
+    suppressed) or None for none; ``deny``, int32 catalog rows to
+    suppress (duplicates allowed); ``allow``, int32 rows outside which
+    EVERYTHING is suppressed, or None for no such list (an empty array
+    suppresses the whole catalog). Both lists must fit `row_capacity`."""
+    base: Optional[jax.Array]
+    deny: np.ndarray
+    allow: Optional[np.ndarray]
+
+
+def _pack_rows(deny, allow, capacity: int, n_items: int) -> np.ndarray:
+    """The two lists as ONE int32 array (a put costs by the array, not by
+    the byte, at this size): ``[deny | allow | whether allow was given]``,
+    each list SORTED (the scatter is told so and skips its own sort) and
+    filled up to ``capacity`` with the row ``n_items``: out of range, so
+    the scatter drops it, and the largest, so the order holds."""
+    out = np.full(2 * capacity + 1, n_items, np.int32)
+    out[:len(deny)] = np.sort(deny)
+    if allow is not None:
+        out[capacity:capacity + len(allow)] = np.sort(allow)
+    out[-1] = allow is not None
+    return out
+
+
+def _put_rows(exclude: RowExclude, n_items: int):
+    """`_pack_rows`'s array of the two lists, on the device. Span
+    ``topk.mask_put`` (tag ``bytes``), as for a dense host mask."""
+    _base, deny, allow = exclude
+    capacity = row_capacity(deny, allow)
+    if capacity is None:
+        raise ValueError(
+            f"{len(deny)} deny / {0 if allow is None else len(allow)} allow "
+            f"rows are over the ladder {_ROW_LADDER}")
+    with telemetry.span("topk.mask_put") as sp:
+        rows = _pack_rows(deny, allow, capacity, n_items)
+        sp.tag(bytes=rows.nbytes)
+        return jax.device_put(rows)
+
+
+def _suppress_rows(scores, rows):
+    """-inf at the rows of ``deny`` and, where an ``allow`` list was
+    given, at every row outside it: elementwise what the dense mask of
+    the same lists gives (models/_filters.py). Whether it was given is a
+    traced value, so a query with a whiteList and one without share the
+    executable. ``rows`` is `_pack_rows`'s array."""
+    capacity = rows.shape[0] // 2
+    deny, allow, has_allow = rows[:capacity], rows[capacity:-1], rows[-1] != 0
+    listed = jnp.zeros(scores.shape, bool).at[allow].set(
+        True, mode="drop", indices_are_sorted=True)
+    scores = jnp.where(has_allow & ~listed, -jnp.inf, scores)
+    return scores.at[deny].set(-jnp.inf, mode="drop", indices_are_sorted=True)
+
+
 @functools.partial(jax.jit, static_argnames=("k",))
-def _topk_scores(user_vec, item_factors, exclude_mask, k: int):
+def _topk_scores(user_vec, item_factors, exclude_mask, k: int, rows=None):
     # mul+reduce instead of a gemv: the reduction tree over rank is then
     # independent of the row count, so a MODEL_AXIS-sharded catalog
     # (ops/sharded_topk.py) produces bitwise-identical scores. A gemv's
@@ -105,6 +185,8 @@ def _topk_scores(user_vec, item_factors, exclude_mask, k: int):
     # HBM-bandwidth-bound on reading the catalog either way.
     scores = (item_factors * user_vec[None, :]).sum(axis=1)  # [n_items]
     scores = jnp.where(exclude_mask, -jnp.inf, scores)
+    if rows is not None:
+        scores = _suppress_rows(scores, rows)
     block_len = _select_block_len(scores.shape[0], k)
     if block_len:
         return _select_topk(scores, k, block_len)
@@ -124,10 +206,25 @@ def _no_exclude_mask(n_items: int):
 def top_k_items(user_vec, item_factors, k: int, exclude=None):
     """Returns (scores[k], indices[k]) as host numpy arrays.
 
-    ``exclude``: optional bool mask [n_items] of items to suppress
-    (seen-item filtering for the e-commerce template).
+    ``exclude`` says which items to suppress (True = suppressed), and its
+    TYPE which path the call takes; the answer is the same on each:
+
+    - None, or a ``bool[n_items]`` array resident on the device (a
+      ``jax.Array``: `_no_exclude_mask`, a `CategoryIndex` device mask):
+      nothing crosses to the device but the query vector;
+    - a host ``np.ndarray`` ``bool[n_items]`` (the dense mask of
+      `models/_filters.build_exclude_mask`): its ``n_items`` bytes are put
+      under span ``topk.mask_put``;
+    - a `RowExclude`: its row lists, padded to a step of `_ROW_LADDER`, are
+      put under the same span (tag ``bytes``: some KB) and `_topk_scores`
+      composes the mask from them and the resident base. Lists over the
+      ladder's top are the caller's to turn into a dense mask
+      (``row_capacity`` says so beforehand): ValueError here.
     """
     n_items = item_factors.shape[0]
+    rows = None
+    if isinstance(exclude, RowExclude):
+        exclude, rows = exclude.base, _put_rows(exclude, n_items)
     if exclude is None:
         exclude = _no_exclude_mask(n_items)
     k = min(int(k), n_items)
@@ -141,14 +238,14 @@ def top_k_items(user_vec, item_factors, k: int, exclude=None):
     select = "blocks" if _select_block_len(n_items, k) else "direct"
     _M_SELECT.labels(select).inc()
     if isinstance(exclude, np.ndarray):
-        # a mask built on the host this query (the business rules of
+        # a dense mask built on the host this query (the business rules of
         # models/_filters.py): its bytes cross here, under a span of their
         # own, so that topk.dispatch stays the enqueue alone. A resident
         # mask (_no_exclude_mask) takes no put.
         with telemetry.span("topk.mask_put", bytes=exclude.nbytes):
             exclude = jax.device_put(exclude)
     with telemetry.span("topk.dispatch", select=select):
-        out = _topk_scores(user_vec, item_factors, exclude, k)
+        out = _topk_scores(user_vec, item_factors, exclude, k, rows)
     # Single host transfer: each device_get is a round trip, so (scores,
     # idx) come back together.
     with telemetry.span("topk.wait"):

@@ -4,7 +4,10 @@ reference on seeded random factors. What the user has seen and what the
 ``unavailableItems`` constraint withdraws come from two blocking store reads
 a query; categories, whiteList and blackList from the query. The catalog is
 large enough (3,000 items, num 10) for ``_topk_scores`` to take the block
-selection, so whole blocks at -inf are selected under too.
+selection, so whole blocks at -inf are selected under too. On the ``flat``
+layout the rules reach the kernel as rows and a resident category mask
+(path ``device``; tests/test_topk_rows.py holds that form to the dense
+one); on the ``mesh`` layout as the dense host mask.
 """
 
 import numpy as np
@@ -180,6 +183,7 @@ def test_spans_and_the_counter_carry_their_tags(shop, monkeypatch):
     rule = lambda name: _filters._M_RULES.labels(name).value()
     before = {r: rule(r) for r in ("categories", "whiteList", "blackList",
                                    "extra", "none")}
+    device = _filters._M_MASK_PATH.labels("device").value()
     with telemetry.span("test.query") as root:
         shop.model.recommend("u0", 10, categories=["c1"],
                              black_list=[item(3), "nope"])
@@ -194,13 +198,16 @@ def test_spans_and_the_counter_carry_their_tags(shop, monkeypatch):
     # the withdrawn, the seen and the one blackList id the catalog knows
     assert mine[2].tags == {
         "rules": "categories+blackList+extra",
-        "excluded": len(shop.withdrawn) + len(shop.seen[0]) + 1}
-    assert mine[3].tags == {"bytes": N_ITEMS}
+        "excluded": len(shop.withdrawn) + len(shop.seen[0]) + 1,
+        "path": "device"}
+    # the rows at the ladder's floor and the whiteList flag, not N_ITEMS
+    assert mine[3].tags == {"bytes": 4 * (2 * topk._ROW_LADDER[0] + 1)}
     assert all(a.t1_ns <= b.t0_ns for a, b in zip(mine, mine[1:]))
     after = {r: rule(r) for r in before}
     assert {r: after[r] - before[r] for r in before} == {
         "categories": 1, "whiteList": 0, "blackList": 1, "extra": 1,
         "none": 0}
+    assert _filters._M_MASK_PATH.labels("device").value() == device + 1
     # a mask resident on the device (no rule: the recommendation template's
     # case) takes no put, and its dispatch no new work
     with telemetry.span("test.query") as root:
@@ -208,3 +215,26 @@ def test_spans_and_the_counter_carry_their_tags(shop, monkeypatch):
     assert [s.name for s in telemetry.spans_snapshot()
             if s.trace_id == root.trace_id and s.parent_id == root.span_id
             ] == ["topk.dispatch", "topk.wait"]
+
+
+def test_the_mesh_layout_takes_the_dense_mask_and_answers_the_same(
+        shop, monkeypatch):
+    """A model whose catalog is sharded over a serving mesh hands its
+    kernel the dense host mask (path ``dense``), observed from the layout:
+    same rules, same answer as the flat layout's rows."""
+    import dataclasses
+
+    from incubator_predictionio_tpu.parallel.mesh import mesh_from_devices
+
+    monkeypatch.setattr(telemetry._STATE, "metrics_on", True)
+    sharded = dataclasses.replace(
+        shop.model, serving_mesh=mesh_from_devices(devices=jax.devices()[:4]),
+        _sharded_cat=None, _storage=shop.storage)
+    assert (sharded.catalog().layout, shop.model.catalog().layout) == (
+        "mesh", "flat")
+    rules = dict(categories=["c0", "c2"], black_list=_top(shop, 1, 3),
+                 white_list=[item(j) for j in range(0, N_ITEMS, 3)])
+    dense = _filters._M_MASK_PATH.labels("dense").value()
+    got = sharded.recommend("u1", 10, **rules)
+    assert _filters._M_MASK_PATH.labels("dense").value() == dense + 1
+    assert len(got) == 10 and got == shop.model.recommend("u1", 10, **rules)
