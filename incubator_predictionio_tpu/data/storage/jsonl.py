@@ -28,6 +28,7 @@ from typing import Iterable, Iterator, Optional, Sequence
 
 import numpy as np
 
+from ...common import telemetry
 from ...common.faultinject import fault_point
 from ...native import ColumnarEvents, parse_events
 from . import base
@@ -37,6 +38,22 @@ from .memory import event_matches
 
 _EPOCH = _dt.datetime(1970, 1, 1, tzinfo=_dt.timezone.utc)
 _TIME_ABSENT = np.iinfo(np.int64).min
+
+_M_SCAN_BYTES = telemetry.registry().counter(
+    "pio_store_scan_bytes_total",
+    "Bytes of JSONL event log that a cached scan's loads brought in "
+    "(cold, snapshot + tail, or the tail appended since the last read)")
+_M_SCAN_EVENTS = telemetry.registry().counter(
+    "pio_store_scan_events_total",
+    "Events a log scan supplied, by where they came from: decoded from "
+    "JSON (parse), a committed columnar snapshot (+ its tail), or the "
+    "scan this process had cached", ("source",))
+
+
+def _parse(buf: bytes) -> ColumnarEvents:
+    """``parse_events`` under the span ``store.parse`` (tag ``bytes``)."""
+    with telemetry.span("store.parse", bytes=len(buf)):
+        return parse_events(buf)
 
 
 def _to_us(t: Optional[_dt.datetime]) -> Optional[int]:
@@ -121,6 +138,11 @@ class _LogScan:
             dest[tid] = max(dest.get(tid, -1), int(pos) + offset)
 
     def refresh(self, path: str) -> None:
+        """Bring the cached scan up to the file. Span ``store.scan``:
+        tags ``source`` (``parse``: decoded from JSON, cold or the tail;
+        ``snapshot``: a committed columnar snapshot and its tail;
+        ``cached``: nothing to load), ``bytes`` of log the load brought
+        in and ``events`` it supplied (a cached read: all it serves)."""
         try:
             size = os.path.getsize(path)
         except OSError:
@@ -128,16 +150,29 @@ class _LogScan:
             self.skip_kills = {}
             self._reset_indexes()
             return
+        warm = self.cols is not None and size >= self.size
+        had_bytes = self.size if warm else 0
+        had_events = len(self.cols) if warm else 0
+        with telemetry.span("store.scan") as sp:
+            source = self._load(path, size)
+            n_bytes = self.size - had_bytes
+            n_events = len(self.cols) - (
+                0 if source == "cached" else had_events)
+            sp.tag(source=source, bytes=n_bytes, events=n_events)
+        _M_SCAN_BYTES.labels().inc(n_bytes)
+        _M_SCAN_EVENTS.labels(source).inc(n_events)
+
+    def _load(self, path: str, size: int) -> str:
+        """The load itself; returns the ``source`` of ``refresh``."""
         if self.cols is not None and size == self.size:
-            return
+            return "cached"
         if self.cols is not None and size > self.size:
             with open(path, "rb") as f:
                 f.seek(self.size)
                 tail = f.read()
-            new = parse_events(tail)
-            self._extend(new)
+            self._extend(_parse(tail))
             self.size = size
-            return
+            return "parse"
         # cold (or replaced) load: a committed columnar snapshot — the
         # event-log compactor's crash-safe rewrite of the log prefix
         # (data/api/event_log.py) — replaces the JSON re-parse of
@@ -158,9 +193,9 @@ class _LogScan:
                 with open(path, "rb") as f:
                     f.seek(covered)
                     tail = f.read()
-                self._extend(parse_events(tail))
+                self._extend(_parse(tail))
                 self.size = size
-            return
+            return "snapshot"
         # retention-aware fallback: the JSON parse must start at the
         # byte after the retired-generation prefix, or expired data
         # would resurrect through the slow path
@@ -169,12 +204,13 @@ class _LogScan:
             if floor:
                 f.seek(floor)
             buf = f.read()
-        self.cols = parse_events(buf)
+        self.cols = _parse(buf)
         self.tombstones = {}
         self.skip_kills = {}
         self._merge_tombstones(self.tombstones, self.cols)
         self._reset_indexes()
         self.size = size
+        return "parse"
 
     @staticmethod
     def _try_snapshot(path: str):
@@ -1117,16 +1153,22 @@ class JSONLEvents(base.LEvents):
         if cols is None:
             empty = parse_events(b"")
             return empty, np.empty(0, np.int64)
-        mask = scan.live_mask()
-        if event_names is not None:
-            table = cols.table(ColumnarEvents.TABLE_EVENT)
-            codes = [table.index(n) for n in event_names if n in table]
-            mask = mask & np.isin(cols.event, np.asarray(codes, np.int32))
-        if s_us is not None:
-            mask = mask & (cols.time_us != _TIME_ABSENT) & (cols.time_us >= s_us)
-        if u_us is not None:
-            mask = mask & (cols.time_us != _TIME_ABSENT) & (cols.time_us < u_us)
-        return cols, np.nonzero(mask)[0]
+        with telemetry.span("store.select", step="mask") as sp:
+            mask = scan.live_mask()
+            if event_names is not None:
+                table = cols.table(ColumnarEvents.TABLE_EVENT)
+                codes = [table.index(n) for n in event_names if n in table]
+                mask = mask & np.isin(cols.event,
+                                      np.asarray(codes, np.int32))
+            if s_us is not None:
+                mask = (mask & (cols.time_us != _TIME_ABSENT)
+                        & (cols.time_us >= s_us))
+            if u_us is not None:
+                mask = (mask & (cols.time_us != _TIME_ABSENT)
+                        & (cols.time_us < u_us))
+            rows = np.nonzero(mask)[0]
+            sp.tag(events=len(cols), selected=int(rows.size))
+        return cols, rows
 
     def aggregate_properties(self, app_id, entity_type, channel_id=None,
                              start_time=None, until_time=None,

@@ -14,6 +14,7 @@ from typing import Iterator, Optional, Sequence
 
 import numpy as np
 
+from ...common import telemetry
 from ..storage.bimap import BiMap
 from ..storage.datamap import PropertyMap
 from ..storage.event import Event
@@ -196,41 +197,45 @@ class PEventStore:
             cols, rows = pe.scan_columnar(
                 app_id, channel_id, event_names, start_time, until_time
             )
-            rows = rows[cols.eid[rows] >= 0]  # malformed records: no entityId
-            # The row path iterates events time-sorted (LEvents.find
-            # semantics); order the selection the same way so BiMap
-            # first-seen index assignment matches bit-for-bit.
-            rows = rows[np.argsort(cols.time_us[rows], kind="stable")]
-            # BiMap membership and index order must match the row path
-            # exactly: users cover ALL scanned events (even target-less
-            # ones), items only events with a target; both indexed in
-            # first-seen order within the selection (BiMap.string_int).
-            keep_mask = cols.teid[rows] >= 0
-            keep = rows[keep_mask]
-            if rating_from_props:
-                r = cols.rating[keep].astype(np.float32, copy=True)
-                # Codec sentinel semantics: NaN = "rating" key absent
-                # (event-default applies, like the row path injecting into
-                # properties), -inf = key present but not coercible
-                # (row path's _coerce → plain default_rating).
-                missing = np.isnan(r)
-                unusable = np.isneginf(r)
-                if unusable.any():
-                    r[unusable] = np.float32(default_rating)
-                if missing.any():
-                    fill = np.full(keep.shape, np.float32(default_rating))
-                    if event_default_ratings:
-                        ev_table = cols.table(cols.TABLE_EVENT)
-                        ev = cols.event[keep]
-                        for name, val in event_default_ratings.items():
-                            if name in ev_table:
-                                fill = np.where(
-                                    ev == ev_table.index(name),
-                                    np.float32(val), fill,
-                                )
-                    r[missing] = fill[missing]
-            else:
-                r = np.full(keep.shape, default_rating, np.float32)
+            # span store.select: the selection put in time order and
+            # its ratings filled in (the masks are scan_columnar's)
+            with telemetry.span("store.select", step="order"):
+                # malformed records: no entityId
+                rows = rows[cols.eid[rows] >= 0]
+                # The row path iterates events time-sorted (LEvents.find
+                # semantics); order the selection the same way so BiMap
+                # first-seen index assignment matches bit-for-bit.
+                rows = rows[np.argsort(cols.time_us[rows], kind="stable")]
+                # BiMap membership and index order must match the row path
+                # exactly: users cover ALL scanned events (even target-less
+                # ones), items only events with a target; both indexed in
+                # first-seen order within the selection (BiMap.string_int).
+                keep_mask = cols.teid[rows] >= 0
+                keep = rows[keep_mask]
+                if rating_from_props:
+                    r = cols.rating[keep].astype(np.float32, copy=True)
+                    # Codec sentinel semantics: NaN = "rating" key absent
+                    # (event-default applies, like the row path injecting into
+                    # properties), -inf = key present but not coercible
+                    # (row path's _coerce → plain default_rating).
+                    missing = np.isnan(r)
+                    unusable = np.isneginf(r)
+                    if unusable.any():
+                        r[unusable] = np.float32(default_rating)
+                    if missing.any():
+                        fill = np.full(keep.shape, np.float32(default_rating))
+                        if event_default_ratings:
+                            ev_table = cols.table(cols.TABLE_EVENT)
+                            ev = cols.event[keep]
+                            for name, val in event_default_ratings.items():
+                                if name in ev_table:
+                                    fill = np.where(
+                                        ev == ev_table.index(name),
+                                        np.float32(val), fill,
+                                    )
+                        r[missing] = fill[missing]
+                else:
+                    r = np.full(keep.shape, default_rating, np.float32)
 
             def densify(codes: np.ndarray, table: list[str]):
                 uniq, first_pos, inv = np.unique(
@@ -243,10 +248,17 @@ class PEventStore:
                                for k, c in enumerate(uniq[order])})
                 return rank[inv], bimap
 
-            u_all, users = densify(cols.eid[rows], cols.table(cols.TABLE_EID))
-            u = u_all[keep_mask]
-            i, items = densify(cols.teid[keep], cols.table(cols.TABLE_TEID))
-            return u.astype(np.int32), i.astype(np.int32), r, users, items
+            # span store.index: codes to dense rows in first-seen
+            # order and the string BiMaps of both sides
+            with telemetry.span("store.index") as sp:
+                u_all, users = densify(cols.eid[rows],
+                                       cols.table(cols.TABLE_EID))
+                u = u_all[keep_mask]
+                i, items = densify(cols.teid[keep],
+                                   cols.table(cols.TABLE_TEID))
+                u, i = u.astype(np.int32), i.astype(np.int32)
+                sp.tag(users=len(users), items=len(items))
+            return u, i, r, users, items
 
         batch = PEventStore.find_batch(
             app_name, event_names=event_names, storage=storage,

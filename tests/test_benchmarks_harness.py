@@ -42,6 +42,12 @@ RED = {
         "its cell appended to dase.persist_s and train.window_compiles, and "
         "a model_config PR may edit no file the benchmark has: the next "
         "benchmark PR turns == [cell] into `in`",
+    ("test_ecomm_deployment", "test_the_cell_went_in_by_files_alone"):
+        "pins PR 34's cell, configuration and three metrics as the LAST "
+        "entries of the manifest ([-1], [-3:]); ISSUE 36 has its own "
+        "appended after them, and a model_config PR may edit no file the "
+        "benchmark has: the next benchmark PR turns the positions into "
+        "membership",
 }
 
 
