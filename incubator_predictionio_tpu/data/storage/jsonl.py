@@ -50,10 +50,24 @@ _M_SCAN_EVENTS = telemetry.registry().counter(
     "scan this process had cached", ("source",))
 
 
+_M_PARSE = telemetry.registry().counter(
+    "pio_store_parse_total",
+    "Parses of JSONL event log by a cached scan's loads, by how the codec "
+    "ran: one pass on the calling thread (whole), pieces cut at newlines "
+    "and parsed side by side (split), or pieces thrown away for the one "
+    "pass because one of them failed (fallback)", ("mode",))
+
+
 def _parse(buf: bytes) -> ColumnarEvents:
-    """``parse_events`` under the span ``store.parse`` (tag ``bytes``)."""
-    with telemetry.span("store.parse", bytes=len(buf)):
-        return parse_events(buf)
+    """``parse_events`` under the span ``store.parse``: tags ``bytes``
+    and how the codec ran, ``mode`` (``whole`` | ``split`` |
+    ``fallback``), ``pieces``, ``threads`` and ``merge_ms`` (the merge
+    of the pieces' id tables; 0 unless ``split``)."""
+    with telemetry.span("store.parse", bytes=len(buf)) as sp:
+        cols = parse_events(buf)
+        sp.tag(**cols.parse_stats)
+    _M_PARSE.labels(cols.parse_stats["mode"]).inc()
+    return cols
 
 
 def _to_us(t: Optional[_dt.datetime]) -> Optional[int]:

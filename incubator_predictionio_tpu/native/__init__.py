@@ -5,9 +5,11 @@ HBase client + TableInputFormat scan play in the reference (storage/hbase/
 .../HBPEvents.scala). ``parse_events_jsonl`` decodes a JSONL buffer into
 ``ColumnarEvents``: interned id codes + timestamps + ratings as numpy
 arrays, the exact host-side layout the input pipeline uploads to device.
+A large buffer is cut at newlines and decoded as pieces on native threads
+(ctypes releases the GIL for the call), merged to the one pass's codes.
 
 Build strategy: the .so is compiled lazily on first use (one translation
-unit, ~1s with g++ -O3) into ``_lib/`` next to this file, keyed by the ABI
+unit, ~1s with g++ -O3 -pthread) into ``_lib/`` next to this file, keyed by the ABI
 version the library exports and by a hash of the source it was built
 from; `make -C native` does the same for packaging. When no C++ toolchain is available ``parse_events_jsonl``
 raises ``NativeUnavailable`` and callers fall back to the pure-Python
@@ -27,7 +29,7 @@ from typing import Optional
 
 import numpy as np
 
-_EXPECTED_VERSION = 18
+_EXPECTED_VERSION = 19
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -75,28 +77,23 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.pio_codec_version.restype = ctypes.c_int32
     lib.pio_parse_events_jsonl.restype = ctypes.c_void_p
     lib.pio_parse_events_jsonl.argtypes = [
-        ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int64,
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int32, ctypes.c_char_p,
+        ctypes.c_int64,
     ]
     lib.pio_col_count.restype = ctypes.c_int64
     lib.pio_col_count.argtypes = [ctypes.c_void_p]
-    for name in ("pio_col_event", "pio_col_etype", "pio_col_eid",
-                 "pio_col_tetype", "pio_col_teid", "pio_col_event_id"):
-        fn = getattr(lib, name)
-        fn.restype = ctypes.POINTER(ctypes.c_int32)
-        fn.argtypes = [ctypes.c_void_p]
-    for name in ("pio_col_time_us", "pio_col_props", "pio_col_span"):
-        fn = getattr(lib, name)
-        fn.restype = ctypes.POINTER(ctypes.c_int64)
-        fn.argtypes = [ctypes.c_void_p]
-    lib.pio_col_rating.restype = ctypes.POINTER(ctypes.c_float)
-    lib.pio_col_rating.argtypes = [ctypes.c_void_p]
+    lib.pio_parse_stats.restype = None
+    lib.pio_parse_stats.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64)]
+    lib.pio_threads_started.restype = ctypes.c_int64
+    lib.pio_threads_started.argtypes = []
+    lib.pio_export_columns.restype = None
+    lib.pio_export_columns.argtypes = (
+        [ctypes.c_void_p] + [ctypes.POINTER(ctypes.c_int32)] * 6
+        + [ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_float),
+           ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64)])
     lib.pio_table_size.restype = ctypes.c_int32
     lib.pio_table_size.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-    lib.pio_table_get.restype = ctypes.POINTER(ctypes.c_char)
-    lib.pio_table_get.argtypes = [
-        ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
-        ctypes.POINTER(ctypes.c_int32),
-    ]
     lib.pio_table_blob.restype = ctypes.POINTER(ctypes.c_char)
     lib.pio_table_blob.argtypes = [
         ctypes.c_void_p, ctypes.c_int32, ctypes.POINTER(ctypes.c_int64),
@@ -204,8 +201,8 @@ def _build() -> str:
     out = _lib_path()
     os.makedirs(os.path.dirname(out), exist_ok=True)
     tmp = out + f".tmp{os.getpid()}"
-    cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-shared",
-           "-o", tmp, _src_path()]
+    cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-pthread",
+           "-shared", "-o", tmp, _src_path()]
     proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     if proc.returncode != 0:
         raise NativeUnavailable(f"g++ build failed: {proc.stderr[-2000:]}")
@@ -317,6 +314,9 @@ class ColumnarEvents:
     _tables: list
     tombstones: list[str]
     tombstone_pos: np.ndarray  # int64, record count before each tombstone
+    # how the parse that made these columns ran (``parse_events_jsonl``);
+    # None for columns that no parse made (a snapshot, an extended scan)
+    parse_stats: Optional[dict] = None
 
     def __len__(self) -> int:
         return int(self.event.shape[0])
@@ -359,19 +359,40 @@ def _np_copy(ptr, n, dtype):
     return np.ctypeslib.as_array(ptr, shape=(n,)).astype(dtype, copy=True)
 
 
-def parse_events_jsonl(buf: bytes) -> ColumnarEvents:
+_PARSE_MODES = ("whole", "split", "fallback")
+
+
+def parse_events_jsonl(buf: bytes,
+                       pieces: Optional[int] = None) -> ColumnarEvents:
     """Parse a JSONL buffer of event objects (native fast path).
+
+    A large buffer is cut at newlines into pieces that are parsed side
+    by side on threads and merged to the codes of one pass: every table
+    numbers its strings in order of first occurrence in the file, so
+    the result is the one pass's, field for field. How many pieces and
+    threads comes from the buffer's bytes and the CPUs this process may
+    run on (``derive_pieces`` in event_codec.cc); a buffer under the
+    floor takes the one pass on the calling thread, and so does one of
+    which any piece fails (a record that spans lines, a raw newline in
+    a string, a malformed record: the one pass then gives the true
+    columns or the true error). ``pieces`` forces a count, for tests.
+    ``ColumnarEvents.parse_stats`` says how it ran: ``mode``
+    (``whole`` | ``split`` | ``fallback``), ``pieces``, ``threads``,
+    ``merge_ms``.
 
     Raises NativeUnavailable when no toolchain/library, EventParseError on
     malformed input. Pure-Python equivalent: ``parse_events_jsonl_py``.
     """
     lib = _load()
     err = ctypes.create_string_buffer(512)
-    handle = lib.pio_parse_events_jsonl(buf, len(buf), err, len(err))
+    handle = lib.pio_parse_events_jsonl(
+        buf, len(buf), pieces or 0, err, len(err))
     if not handle:
         raise EventParseError(err.value.decode(errors="replace") or "parse failed")
     try:
         n = lib.pio_col_count(handle)
+        raw_stats = (ctypes.c_int64 * 4)()
+        lib.pio_parse_stats(handle, raw_stats)
         tables = []
         for which in range(6):
             size = lib.pio_table_size(handle, which)
@@ -390,21 +411,29 @@ def parse_events_jsonl(buf: bytes) -> ColumnarEvents:
             ptr = lib.pio_tombstone_get(handle, idx, ctypes.byref(ln))
             tombstones.append(ctypes.string_at(ptr, ln.value).decode("utf-8"))
         tombstone_pos = _np_copy(lib.pio_tombstone_pos(handle), n_tomb, np.int64)
+        out = {name: np.empty(n, np.int32) for name in (
+            "event", "etype", "eid", "tetype", "teid", "event_id")}
+        out["time_us"] = np.empty(n, np.int64)
+        out["rating"] = np.empty(n, np.float32)
+        out["props"] = np.empty((n, 2), np.int64)
+        out["span"] = np.empty((n, 2), np.int64)
+        # the pieces copy themselves out side by side, codes rewritten
+        # to the merged tables on the way
+        lib.pio_export_columns(handle, *(
+            a.ctypes.data_as(ctypes.POINTER(
+                np.ctypeslib.as_ctypes_type(a.dtype)))
+            for a in out.values()))
         return ColumnarEvents(
-            raw=buf,
-            event=_np_copy(lib.pio_col_event(handle), n, np.int32),
-            etype=_np_copy(lib.pio_col_etype(handle), n, np.int32),
-            eid=_np_copy(lib.pio_col_eid(handle), n, np.int32),
-            tetype=_np_copy(lib.pio_col_tetype(handle), n, np.int32),
-            teid=_np_copy(lib.pio_col_teid(handle), n, np.int32),
-            event_id=_np_copy(lib.pio_col_event_id(handle), n, np.int32),
-            time_us=_np_copy(lib.pio_col_time_us(handle), n, np.int64),
-            rating=_np_copy(lib.pio_col_rating(handle), n, np.float32),
-            props=_np_copy(lib.pio_col_props(handle), 2 * n, np.int64).reshape(n, 2),
-            span=_np_copy(lib.pio_col_span(handle), 2 * n, np.int64).reshape(n, 2),
+            raw=buf, **out,
             _tables=tables,
             tombstones=tombstones,
             tombstone_pos=tombstone_pos,
+            parse_stats={
+                "mode": _PARSE_MODES[raw_stats[0]],
+                "pieces": int(raw_stats[1]),
+                "threads": int(raw_stats[2]),
+                "merge_ms": round(raw_stats[3] / 1000.0, 3),
+            },
         )
     finally:
         lib.pio_free(handle)
@@ -697,13 +726,15 @@ def parse_events_jsonl_py(buf: bytes) -> ColumnarEvents:
         _tables=tables,
         tombstones=tombstones,
         tombstone_pos=np.asarray(tombstone_pos, np.int64),
+        parse_stats={"mode": "whole", "pieces": 1, "threads": 1,
+                     "merge_ms": 0.0},
     )
 
 
-def parse_events(buf: bytes) -> ColumnarEvents:
-    """Native when possible, Python otherwise."""
+def parse_events(buf: bytes, pieces: Optional[int] = None) -> ColumnarEvents:
+    """Native when possible, Python otherwise (always one pass)."""
     try:
-        return parse_events_jsonl(buf)
+        return parse_events_jsonl(buf, pieces)
     except NativeUnavailable:
         return parse_events_jsonl_py(buf)
 

@@ -6,7 +6,8 @@
 // append-only JSONL log and the scan is this parser, which decodes event
 // JSON straight into interned id codes + timestamps + ratings — the exact
 // columnar layout the TPU input pipeline uploads — without materializing
-// per-event Python objects.
+// per-event Python objects. A large buffer is cut at newlines and parsed as
+// pieces on threads, merged to the very columns one pass gives (parse_split).
 //
 // C ABI (ctypes-friendly); no external dependencies; C++17.
 //
@@ -29,43 +30,133 @@
 // tombstone) so deletes only affect records appended BEFORE them — a
 // re-insert after a delete is live again, matching the upsert backends.
 
+#include <sched.h>
+#include <sys/mman.h>
+
+#include <algorithm>
+#include <atomic>
+#include <chrono>
 #include <cmath>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <new>
 #include <string>
-#include <unordered_map>
-#include <algorithm>
+#include <string_view>
+#include <system_error>
+#include <thread>
 #include <vector>
 
 namespace {
 
-struct Interner {
-  std::unordered_map<std::string, int32_t> map;
-  std::vector<std::string> table;
+// The arrays of a parse. From kMapBytes up an array is mapped and unmapped
+// directly, so that what a parse frees is the system's again at once: freed
+// through malloc, the pieces' arrays of a split parse stay resident in their
+// threads' arenas (up to 64 MB each, which no other thread can use) and
+// teach malloc's thresholds to keep more.
+template <class T>
+struct MapAlloc {
+  using value_type = T;
+  static constexpr size_t kMapBytes = size_t{128} << 10;
+  MapAlloc() = default;
+  template <class U>
+  MapAlloc(const MapAlloc<U>&) {}
+  T* allocate(size_t n) {
+    const size_t bytes = n * sizeof(T);
+    void* p = bytes < kMapBytes
+                  ? malloc(bytes)
+                  : mmap(nullptr, bytes, PROT_READ | PROT_WRITE,
+                         MAP_PRIVATE | MAP_ANONYMOUS, -1, 0);
+    if (p == nullptr || p == MAP_FAILED) throw std::bad_alloc();
+    return static_cast<T*>(p);
+  }
+  void deallocate(T* p, size_t n) {
+    const size_t bytes = n * sizeof(T);
+    if (bytes < kMapBytes) free(p); else munmap(p, bytes);
+  }
+  template <class U>
+  bool operator==(const MapAlloc<U>&) const { return true; }
+  template <class U>
+  bool operator!=(const MapAlloc<U>&) const { return false; }
+};
+template <class T>
+using Vec = std::vector<T, MapAlloc<T>>;
 
-  int32_t intern(std::string&& s) {
-    auto it = map.find(s);
-    if (it != map.end()) return it->second;
-    int32_t id = static_cast<int32_t>(table.size());
-    map.emplace(s, id);
-    table.push_back(std::move(s));
-    return id;
+// A table of distinct strings, numbered in order of first occurrence.
+// The strings lie end to end in one blob (string c is
+// blob[offs[c], offs[c + 1])), which is also what leaves through
+// pio_table_blob / pio_table_offsets; the index is open addressing over
+// (hash << 32 | code) slots. No allocation a string, so a table of
+// millions is a handful of arrays to grow and to free.
+struct Interner {
+  Vec<char> blob;
+  Vec<int64_t> offs{0};
+  Vec<uint32_t> hashes;  // by code: the merge of a split parse
+  Vec<uint64_t> slots;   // power of two; kEmpty = free
+  static constexpr uint64_t kEmpty = ~uint64_t{0};
+
+  size_t size() const { return offs.size() - 1; }
+
+  std::string_view get(size_t c) const {
+    return {blob.data() + offs[c], static_cast<size_t>(offs[c + 1] - offs[c])};
+  }
+
+  int32_t intern(std::string_view s) {
+    if (size() * 2 >= slots.size()) grow();
+    const size_t hv64 = std::hash<std::string_view>{}(s);
+    const uint32_t hv = static_cast<uint32_t>(hv64 ^ (hv64 >> 32));
+    const size_t mask = slots.size() - 1;
+    size_t i = hv & mask;
+    for (; slots[i] != kEmpty; i = (i + 1) & mask) {
+      if (static_cast<uint32_t>(slots[i] >> 32) == hv &&
+          get(slots[i] & 0xFFFFFFFF) == s)
+        return static_cast<int32_t>(slots[i] & 0xFFFFFFFF);
+    }
+    const uint64_t id = size();
+    slots[i] = static_cast<uint64_t>(hv) << 32 | id;
+    hashes.push_back(hv);
+    blob.insert(blob.end(), s.begin(), s.end());
+    offs.push_back(static_cast<int64_t>(blob.size()));
+    return static_cast<int32_t>(id);
+  }
+
+  void grow() {
+    Vec<uint64_t> old = std::move(slots);
+    slots.assign(old.empty() ? 16 : old.size() * 2, kEmpty);
+    const size_t mask = slots.size() - 1;
+    for (uint64_t v : old) {
+      if (v == kEmpty) continue;
+      size_t i = (v >> 32) & mask;
+      while (slots[i] != kEmpty) i = (i + 1) & mask;
+      slots[i] = v;
+    }
   }
 };
 
 constexpr int kNumTables = 6;  // event, etype, eid, tetype, teid, eventId
 
 struct Columns {
-  std::vector<int32_t> event, etype, eid, tetype, teid, event_id;
-  std::vector<int64_t> time_us;
-  std::vector<float> rating;
-  std::vector<int64_t> props;  // 2n offsets
-  std::vector<int64_t> span;   // 2n offsets
+  Vec<int32_t> event, etype, eid, tetype, teid, event_id;
+  Vec<int64_t> time_us;
+  Vec<float> rating;
+  Vec<int64_t> props;  // 2n offsets
+  Vec<int64_t> span;   // 2n offsets
   Interner tables[kNumTables];
   std::vector<std::string> tombstones;
   std::vector<int64_t> tombstone_pos;  // records parsed before each tombstone
+
+  // Room for n records at once: a column that doubles its way up is
+  // copied and unmapped at every step, and on several threads at once
+  // in a process's fresh arenas that cost more than the parse.
+  void reserve(size_t n) {
+    for (auto* v : {&event, &etype, &eid, &tetype, &teid, &event_id})
+      v->reserve(n);
+    time_us.reserve(n);
+    rating.reserve(n);
+    props.reserve(2 * n);
+    span.reserve(2 * n);
+  }
 };
 
 struct Parser {
@@ -448,30 +539,30 @@ struct Parser {
         ws();
         if (key == "event") {
           if (!parse_string(sval)) return false;
-          ev = c.tables[0].intern(std::move(sval));
+          ev = c.tables[0].intern(sval);
         } else if (key == "entityType") {
           if (!parse_string(sval)) return false;
-          et = c.tables[1].intern(std::move(sval));
+          et = c.tables[1].intern(sval);
         } else if (key == "entityId") {
           if (!parse_string(sval)) return false;
-          ei = c.tables[2].intern(std::move(sval));
+          ei = c.tables[2].intern(sval);
         } else if (key == "targetEntityType") {
           if (p < end && *p == 'n') {
             if (!skip_value()) return false;
           } else {
             if (!parse_string(sval)) return false;
-            tet = c.tables[3].intern(std::move(sval));
+            tet = c.tables[3].intern(sval);
           }
         } else if (key == "targetEntityId") {
           if (p < end && *p == 'n') {
             if (!skip_value()) return false;
           } else {
             if (!parse_string(sval)) return false;
-            tei = c.tables[4].intern(std::move(sval));
+            tei = c.tables[4].intern(sval);
           }
         } else if (key == "eventId") {
           if (!parse_string(sval)) return false;
-          eid_code = c.tables[5].intern(std::move(sval));
+          eid_code = c.tables[5].intern(sval);
         } else if (key == "eventTime") {
           if (!parse_string(sval)) return false;
           t_us = parse_iso8601(sval);
@@ -515,32 +606,298 @@ struct Parser {
   }
 };
 
-struct Handle {
+// One piece of a parse, in file order. A buffer parsed in one pass is one
+// piece whose codes are already the result's (no lut).
+struct Piece {
   Columns cols;
-  std::string err;
-  // lazily materialized bulk exports (one ctypes call per table instead of
-  // one per string)
-  std::string table_blob[kNumTables];
-  std::vector<int64_t> table_offsets[kNumTables];
-  bool table_packed[kNumTables] = {};
+  // local code -> code in the merged table; empty = the codes stand
+  Vec<int32_t> lut[kNumTables];
+  int64_t row0 = 0;  // records in the earlier pieces
+};
 
-  void pack(int which) {
-    if (table_packed[which]) return;
-    auto& t = cols.tables[which].table;
-    auto& blob = table_blob[which];
-    auto& offs = table_offsets[which];
-    size_t total = 0;
-    for (auto& s : t) total += s.size();
-    blob.reserve(total);
-    offs.reserve(t.size() + 1);
-    offs.push_back(0);
-    for (auto& s : t) {
-      blob += s;
-      offs.push_back(static_cast<int64_t>(blob.size()));
-    }
-    table_packed[which] = true;
+enum ParseMode : int32_t { kWhole = 0, kSplit = 1, kFallback = 2 };
+
+struct Handle {
+  std::vector<Piece> pieces;
+  int64_t n = 0;  // records over all pieces
+  std::vector<std::string> tombstones;
+  std::vector<int64_t> tombstone_pos;
+  // the tables of a split parse (blob and offs alone); after one pass the
+  // tables are the piece's own
+  Interner merged[kNumTables];
+  // how the parse ran (pio_parse_stats)
+  int32_t mode = kWhole;
+  int32_t tried = 1;    // pieces the buffer was cut into
+  int32_t threads = 1;  // threads that worked, the caller's among them
+  int64_t merge_us = 0;
+
+  const Interner& table(int which) const {
+    return mode == kSplit ? merged[which] : pieces[0].cols.tables[which];
   }
 };
+
+// ---------------------------------------------------------------------------
+// Split parse: a large buffer is cut at newlines, the pieces parsed side by
+// side, their id tables merged to the codes ONE pass would have given (a
+// table's strings numbered in order of first occurrence in the file).
+// ---------------------------------------------------------------------------
+
+std::atomic<int64_t> g_threads_started{0};
+
+// fn(0) .. fn(n_tasks - 1), taken in turn by up to n_threads threads, the
+// calling one among them; returns once all are done. A thread the system
+// will not start is work the others take.
+template <class F>
+void parallel_for(int n_tasks, int n_threads, F&& fn) {
+  std::atomic<int> next{0};
+  auto work = [&] {
+    for (int i; (i = next.fetch_add(1, std::memory_order_relaxed)) < n_tasks;)
+      fn(i);
+  };
+  std::vector<std::thread> started;
+  for (int k = 1; k < std::min(n_threads, n_tasks); ++k) {
+    try {
+      started.emplace_back(work);
+      g_threads_started.fetch_add(1, std::memory_order_relaxed);
+    } catch (const std::system_error&) {
+      break;
+    }
+  }
+  work();
+  for (auto& t : started) t.join();
+}
+
+// CPUs this process may run on: the affinity mask, which a container's
+// cpuset narrows and hardware_concurrency does not see.
+int usable_cpus() {
+  cpu_set_t set;
+  if (sched_getaffinity(0, sizeof set, &set) == 0) {
+    int n = CPU_COUNT(&set);
+    if (n > 0) return n;
+  }
+  return 1;
+}
+
+// Under kSplitFloor a buffer takes the one pass on the calling thread: the
+// event server's tail reads, log_tail, a small app's find, a compaction
+// slice. Above it, pieces of about kPieceBytes, at most kPiecesPerCpu a
+// CPU so that a slow piece leaves the other threads work.
+constexpr int64_t kSplitFloor = int64_t{32} << 20;
+constexpr int64_t kPieceBytes = int64_t{32} << 20;
+constexpr int kPiecesPerCpu = 4;
+
+int derive_pieces(int64_t len, int cpus) {
+  if (len < kSplitFloor || cpus < 2) return 1;
+  int64_t want = len / kPieceBytes;
+  return static_cast<int>(
+      std::max<int64_t>(2, std::min<int64_t>(want, int64_t{kPiecesPerCpu} * cpus)));
+}
+
+// Piece boundaries: `pieces` even cuts, each moved on to just after the
+// next '\n'. Cuts that meet are one.
+std::vector<const char*> cut_at_newlines(const char* buf, int64_t len,
+                                         int pieces) {
+  const char* end = buf + len;
+  std::vector<const char*> cuts{buf};
+  for (int i = 1; i < pieces; ++i) {
+    const char* at = std::max(buf + len / pieces * i, cuts.back());
+    const void* nl = memchr(at, '\n', static_cast<size_t>(end - at));
+    if (nl == nullptr) break;
+    const char* cut = static_cast<const char*>(nl) + 1;
+    if (cut > cuts.back() && cut < end) cuts.push_back(cut);
+  }
+  cuts.push_back(end);
+  return cuts;
+}
+
+// Merge one table of all pieces. Every string of every piece belongs to
+// one shard by its hash; a shard, one thread, walks the pieces in file
+// order and finds for each of its strings the first (piece, local code)
+// that holds it. A piece's firsts then take the codes after those of the
+// earlier pieces, in its own local order, which is the order of first
+// occurrence in the file; the rest take their first's. The merged blob
+// and offsets are written straight from the firsts, and a piece's table
+// is freed by the thread that copied it out.
+void merge_table(Handle& h, int which, int n_threads) {
+  const int P = static_cast<int>(h.pieces.size());
+  auto table_of = [&](int p) -> Interner& {
+    return h.pieces[p].cols.tables[which];
+  };
+  size_t total = 0;
+  for (int p = 0; p < P; ++p) total += table_of(p).size();
+  if (total < (1 << 16)) n_threads = 1;  // event names, entity types
+
+  const int S = n_threads;
+  auto shard_of = [S](uint32_t hv) {
+    return static_cast<int>((static_cast<uint64_t>(hv) * S) >> 32);
+  };
+  // a piece's local codes grouped by shard, ascending inside a shard:
+  // shard s of piece p is order[p][start[p][s] .. start[p][s + 1])
+  std::vector<Vec<uint32_t>> order(P);
+  std::vector<std::vector<size_t>> start(P);
+  // -1: the first of its string; else first's piece << 32 | local code
+  std::vector<Vec<int64_t>> rep(P);
+  parallel_for(P, n_threads, [&](int p) {
+    const auto& hashes = table_of(p).hashes;
+    order[p].resize(hashes.size());
+    rep[p].resize(hashes.size());
+    start[p].assign(static_cast<size_t>(S) + 1, 0);
+    for (uint32_t hv : hashes) ++start[p][shard_of(hv) + 1];
+    for (int s = 0; s < S; ++s) start[p][s + 1] += start[p][s];
+    std::vector<size_t> at(start[p].begin(), start[p].end() - 1);
+    for (size_t c = 0; c < hashes.size(); ++c)
+      order[p][at[shard_of(hashes[c])]++] = static_cast<uint32_t>(c);
+  });
+
+  // firsts and their bytes, by shard and piece
+  std::vector<std::vector<int64_t>> n_first(S, std::vector<int64_t>(P, 0));
+  std::vector<std::vector<int64_t>> b_first(S, std::vector<int64_t>(P, 0));
+  parallel_for(S, n_threads, [&](int s) {
+    size_t mine = 0;
+    for (int p = 0; p < P; ++p) mine += start[p][s + 1] - start[p][s];
+    size_t cap = 16;
+    while (cap < mine * 2) cap <<= 1;
+    // open addressing, linear probe; the slot from the hash's low bits,
+    // the shard from its high bits
+    Vec<int64_t> slot(cap, -1);
+    Vec<uint32_t> slot_hash(cap);
+    for (int p = 0; p < P; ++p) {
+      const Interner& t = table_of(p);
+      for (size_t k = start[p][s]; k < start[p][s + 1]; ++k) {
+        const size_t c = order[p][k];
+        const uint32_t hv = t.hashes[c];
+        const std::string_view str = t.get(c);
+        size_t i = hv & (cap - 1);
+        int64_t found = -1;
+        for (; slot[i] >= 0; i = (i + 1) & (cap - 1)) {
+          if (slot_hash[i] == hv &&
+              table_of(static_cast<int>(slot[i] >> 32))
+                      .get(slot[i] & 0xFFFFFFFF) == str) {
+            found = slot[i];
+            break;
+          }
+        }
+        if (found < 0) {
+          slot[i] = static_cast<int64_t>(p) << 32 | static_cast<int64_t>(c);
+          slot_hash[i] = hv;
+          ++n_first[s][p];
+          b_first[s][p] += static_cast<int64_t>(str.size());
+        }
+        rep[p][c] = found;
+      }
+    }
+  });
+
+  std::vector<int64_t> code0(P + 1, 0), byte0(P + 1, 0);
+  for (int p = 0; p < P; ++p) {
+    code0[p + 1] = code0[p];
+    byte0[p + 1] = byte0[p];
+    for (int s = 0; s < S; ++s) {
+      code0[p + 1] += n_first[s][p];
+      byte0[p + 1] += b_first[s][p];
+    }
+  }
+  Interner& out = h.merged[which];
+  out.blob.resize(static_cast<size_t>(byte0[P]));
+  out.offs.resize(static_cast<size_t>(code0[P]) + 1);
+  parallel_for(P, n_threads, [&](int p) {
+    const Interner& t = table_of(p);
+    auto& lut = h.pieces[p].lut[which];
+    lut.resize(t.size());
+    int64_t code = code0[p], at = byte0[p];
+    for (size_t c = 0; c < t.size(); ++c) {
+      if (rep[p][c] >= 0) continue;
+      const std::string_view str = t.get(c);
+      memcpy(out.blob.data() + at, str.data(), str.size());
+      at += static_cast<int64_t>(str.size());
+      lut[c] = static_cast<int32_t>(code);
+      out.offs[static_cast<size_t>(++code)] = at;
+    }
+    table_of(p) = Interner();
+  });
+  parallel_for(P, n_threads, [&](int p) {
+    auto& lut = h.pieces[p].lut[which];
+    for (size_t c = 0; c < lut.size(); ++c) {
+      const int64_t r = rep[p][c];
+      if (r >= 0)
+        lut[c] = h.pieces[static_cast<int>(r >> 32)].lut[which][r & 0xFFFFFFFF];
+    }
+  });
+}
+
+// The records of [from, to) onto cols, offsets counted from buf; false at
+// the first that fails (the parser holds the error) or once `stop` is set.
+bool parse_records(Parser& parser, const char* from, Columns& cols,
+                   const std::atomic<bool>* stop = nullptr) {
+  parser.p = from;
+  cols.reserve(static_cast<size_t>((parser.end - from) / 128));
+  while (!parser.at_end()) {
+    if (stop != nullptr && stop->load(std::memory_order_relaxed)) return false;
+    if (!parser.parse_event(cols)) return false;
+  }
+  return true;
+}
+
+// The one pass over the whole buffer: the small-buffer route, and what a
+// split parse falls back to when any piece fails.
+Handle* parse_whole(const char* buf, int64_t len, char* errbuf,
+                    int64_t errcap) {
+  auto* h = new Handle();
+  h->pieces.resize(1);
+  Columns& cols = h->pieces[0].cols;
+  Parser parser(buf, len);
+  if (!parse_records(parser, buf, cols)) {
+    if (errbuf && errcap > 0)
+      snprintf(errbuf, static_cast<size_t>(errcap), "%s", parser.err.c_str());
+    delete h;
+    return nullptr;
+  }
+  h->n = static_cast<int64_t>(cols.event.size());
+  h->tombstones = std::move(cols.tombstones);
+  h->tombstone_pos = std::move(cols.tombstone_pos);
+  return h;
+}
+
+// The pieces side by side, or nullptr if any of them failed: a cut inside
+// a record or a string, or a malformed record, which only the one pass
+// can report at its true byte and record. A piece that parses to its own
+// end began and ended between records, so if all do the pieces are the
+// one pass's records in the one pass's order.
+Handle* parse_split(const char* buf, const std::vector<const char*>& cuts,
+                    int n_threads) {
+  const int P = static_cast<int>(cuts.size()) - 1;
+  auto* h = new Handle();
+  h->pieces.resize(static_cast<size_t>(P));
+  h->mode = kSplit;
+  h->tried = P;
+  h->threads = n_threads;
+  std::atomic<bool> failed{false};
+  parallel_for(P, n_threads, [&](int p) {
+    // base stays the buffer's start: span and props offsets are the
+    // whole buffer's
+    Parser parser(buf, cuts[p + 1] - buf);
+    if (!parse_records(parser, cuts[p], h->pieces[p].cols, &failed))
+      failed.store(true);
+  });
+  if (failed.load()) {
+    delete h;
+    return nullptr;
+  }
+  auto t0 = std::chrono::steady_clock::now();
+  for (int p = 0; p < P; ++p) {
+    Piece& piece = h->pieces[p];
+    piece.row0 = h->n;
+    h->n += static_cast<int64_t>(piece.cols.event.size());
+    for (auto& s : piece.cols.tombstones) h->tombstones.push_back(std::move(s));
+    for (int64_t pos : piece.cols.tombstone_pos)
+      h->tombstone_pos.push_back(pos + piece.row0);
+  }
+  for (int which = 0; which < kNumTables; ++which)
+    merge_table(*h, which, h->threads);
+  h->merge_us = std::chrono::duration_cast<std::chrono::microseconds>(
+                    std::chrono::steady_clock::now() - t0).count();
+  return h;
+}
 
 }  // namespace
 
@@ -548,7 +905,7 @@ extern "C" {
 
 // Bump when the ABI or semantics change — the Python wrapper rebuilds the
 // cached .so when this does not match its expected version.
-int32_t pio_codec_version() { return 18; }
+int32_t pio_codec_version() { return 19; }
 
 namespace {
 // FNV-1a over a byte range, continuing from a running state.
@@ -730,79 +1087,109 @@ int32_t pio_fill_entries(
   return 0;
 }
 
-void* pio_parse_events_jsonl(const char* buf, int64_t len, char* errbuf,
-                             int64_t errcap) {
-  auto* h = new Handle();
-  Parser parser(buf, len);
-  while (!parser.at_end()) {
-    if (!parser.parse_event(h->cols)) {
-      if (errbuf && errcap > 0) {
-        snprintf(errbuf, static_cast<size_t>(errcap), "%s",
-                 parser.err.c_str());
-      }
-      delete h;
-      return nullptr;
-    }
+// `pieces` 0: derived from the buffer's bytes and the CPUs the process may
+// run on; n > 0: cut into n (the tests' way to a split of a small buffer).
+void* pio_parse_events_jsonl(const char* buf, int64_t len, int32_t pieces,
+                             char* errbuf, int64_t errcap) {
+  const int cpus = usable_cpus();
+  if (pieces <= 0) pieces = derive_pieces(len, cpus);
+  const auto cuts = cut_at_newlines(buf, len, pieces);
+  const int P = static_cast<int>(cuts.size()) - 1;
+  if (P < 2) return parse_whole(buf, len, errbuf, errcap);
+  const int n_threads = std::min(P, std::max(cpus, 2));
+  Handle* h = parse_split(buf, cuts, n_threads);
+  if (h == nullptr && (h = parse_whole(buf, len, errbuf, errcap)) != nullptr) {
+    h->mode = kFallback;
+    h->tried = P;
+    h->threads = n_threads;
   }
   return h;
 }
 
 static Handle* H(void* h) { return static_cast<Handle*>(h); }
 
-int64_t pio_col_count(void* h) {
-  return static_cast<int64_t>(H(h)->cols.event.size());
+int64_t pio_col_count(void* h) { return H(h)->n; }
+
+// {mode (0 whole, 1 split, 2 fallback), pieces the buffer was cut into,
+// threads that worked, microseconds of the merge}
+void pio_parse_stats(void* h, int64_t* out) {
+  out[0] = H(h)->mode;
+  out[1] = H(h)->tried;
+  out[2] = H(h)->threads;
+  out[3] = H(h)->merge_us;
 }
-const int32_t* pio_col_event(void* h) { return H(h)->cols.event.data(); }
-const int32_t* pio_col_etype(void* h) { return H(h)->cols.etype.data(); }
-const int32_t* pio_col_eid(void* h) { return H(h)->cols.eid.data(); }
-const int32_t* pio_col_tetype(void* h) { return H(h)->cols.tetype.data(); }
-const int32_t* pio_col_teid(void* h) { return H(h)->cols.teid.data(); }
-const int32_t* pio_col_event_id(void* h) { return H(h)->cols.event_id.data(); }
-const int64_t* pio_col_time_us(void* h) { return H(h)->cols.time_us.data(); }
-const float* pio_col_rating(void* h) { return H(h)->cols.rating.data(); }
-const int64_t* pio_col_props(void* h) { return H(h)->cols.props.data(); }
-const int64_t* pio_col_span(void* h) { return H(h)->cols.span.data(); }
+
+// Native threads started by this library since it was loaded.
+int64_t pio_threads_started() { return g_threads_started.load(); }
+
+// Copy the ten columns into the caller's arrays (n, or 2n for props and
+// span), a piece a task: codes go through the piece's lut, the piece's
+// own columns are freed behind the copy.
+void pio_export_columns(void* handle, int32_t* event, int32_t* etype,
+                        int32_t* eid, int32_t* tetype, int32_t* teid,
+                        int32_t* event_id, int64_t* time_us, float* rating,
+                        int64_t* props, int64_t* span) {
+  Handle* h = H(handle);
+  parallel_for(static_cast<int>(h->pieces.size()), h->threads, [&](int p) {
+    Piece& piece = h->pieces[p];
+    Columns& c = piece.cols;
+    const size_t n = c.event.size();
+    const int64_t r0 = piece.row0;
+    Vec<int32_t>* codes[kNumTables] = {&c.event, &c.etype, &c.eid,
+                                               &c.tetype, &c.teid, &c.event_id};
+    int32_t* out[kNumTables] = {event, etype, eid, tetype, teid, event_id};
+    for (int w = 0; w < kNumTables; ++w) {
+      const int32_t* src = codes[w]->data();
+      int32_t* dst = out[w] + r0;
+      const auto& lut = piece.lut[w];
+      if (lut.empty()) {
+        if (n) memcpy(dst, src, n * sizeof(int32_t));
+      } else {
+        for (size_t i = 0; i < n; ++i) dst[i] = src[i] < 0 ? -1 : lut[src[i]];
+      }
+      Vec<int32_t>().swap(*codes[w]);
+    }
+    if (n) {
+      memcpy(time_us + r0, c.time_us.data(), n * sizeof(int64_t));
+      memcpy(rating + r0, c.rating.data(), n * sizeof(float));
+      memcpy(props + 2 * r0, c.props.data(), 2 * n * sizeof(int64_t));
+      memcpy(span + 2 * r0, c.span.data(), 2 * n * sizeof(int64_t));
+    }
+    Vec<int64_t>().swap(c.time_us);
+    Vec<float>().swap(c.rating);
+    Vec<int64_t>().swap(c.props);
+    Vec<int64_t>().swap(c.span);
+  });
+}
 
 int32_t pio_table_size(void* h, int32_t which) {
   if (which < 0 || which >= kNumTables) return -1;
-  return static_cast<int32_t>(H(h)->cols.tables[which].table.size());
-}
-
-const char* pio_table_get(void* h, int32_t which, int32_t idx,
-                          int32_t* len_out) {
-  if (which < 0 || which >= kNumTables) return nullptr;
-  auto& t = H(h)->cols.tables[which].table;
-  if (idx < 0 || static_cast<size_t>(idx) >= t.size()) return nullptr;
-  if (len_out) *len_out = static_cast<int32_t>(t[idx].size());
-  return t[idx].data();
+  return static_cast<int32_t>(H(h)->table(which).size());
 }
 
 // Bulk table export: concatenated UTF-8 strings + (size+1) end offsets.
 const char* pio_table_blob(void* h, int32_t which, int64_t* blob_len) {
   if (which < 0 || which >= kNumTables) return nullptr;
-  Handle* hh = H(h);
-  hh->pack(which);
-  if (blob_len) *blob_len = static_cast<int64_t>(hh->table_blob[which].size());
-  return hh->table_blob[which].data();
+  const Interner& t = H(h)->table(which);
+  if (blob_len) *blob_len = static_cast<int64_t>(t.blob.size());
+  return t.blob.data();
 }
 
 const int64_t* pio_table_offsets(void* h, int32_t which) {
   if (which < 0 || which >= kNumTables) return nullptr;
-  Handle* hh = H(h);
-  hh->pack(which);
-  return hh->table_offsets[which].data();
+  return H(h)->table(which).offs.data();
 }
 
 int64_t pio_tombstone_count(void* h) {
-  return static_cast<int64_t>(H(h)->cols.tombstones.size());
+  return static_cast<int64_t>(H(h)->tombstones.size());
 }
 
 const int64_t* pio_tombstone_pos(void* h) {
-  return H(h)->cols.tombstone_pos.data();
+  return H(h)->tombstone_pos.data();
 }
 
 const char* pio_tombstone_get(void* h, int64_t idx, int32_t* len_out) {
-  auto& t = H(h)->cols.tombstones;
+  auto& t = H(h)->tombstones;
   if (idx < 0 || static_cast<size_t>(idx) >= t.size()) return nullptr;
   if (len_out) *len_out = static_cast<int32_t>(t[idx].size());
   return t[idx].data();

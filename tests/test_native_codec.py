@@ -193,6 +193,191 @@ def _seed_app(s, ratings):
     return app_id
 
 
+# -- the split parse: pieces cut at newlines, parsed side by side, merged ------
+
+
+def _split_corpus() -> bytes:
+    """480 events that no cut leaves alone: ``u-everywhere`` is first seen
+    in the first piece and again in every later one, event ids e0..e299
+    come round again after 300 lines, a tombstone lies in each half,
+    ``targetEntityId`` is null, absent or an id with escapes and
+    non-ASCII, some lines are empty and the last has no newline."""
+    lines = []
+    for n in range(480):
+        e = {"event": "rate" if n % 3 else "buy", "entityType": "user",
+             "entityId": "u-everywhere" if n % 5 == 0 else "u%d" % (n % 37),
+             "eventTime": "2024-03-%02dT10:00:00.%03dZ" % (1 + n % 28, n),
+             "eventId": "e%d" % (n % 300)}
+        if n % 4 == 0:
+            e["targetEntityType"], e["targetEntityId"] = "item", None
+        elif n % 4 != 1:  # n % 4 == 1: no target at all
+            e["targetEntityType"] = "item"
+            e["targetEntityId"] = ('i%d' % (n % 11) if n % 8 < 4
+                                   else 'ü漢 "q" \\ %d \U0001f600' % (n % 6))
+        if n % 7 == 0:
+            e["properties"] = {"rating": 1 + n % 5, "s": 'esc"\\ é'}
+        lines.append(json.dumps(e, ensure_ascii=bool(n % 2)))
+        if n in (100, 350):
+            lines.append(json.dumps({"__tombstone__": "e%d" % (n - 50)}))
+        if n % 50 == 0:
+            lines.append("")
+    return "\n".join(lines).encode()
+
+
+def _same_scan(a, b):
+    _columns_equal(a, b)
+    assert np.array_equal(a.rating, b.rating, equal_nan=True)
+    assert a.raw == b.raw
+
+
+def _split_equals_one_pass(pieces):
+    def case(tmp_path, monkeypatch):
+        buf = _split_corpus()
+        one = native.parse_events_jsonl(buf, pieces=1)
+        assert one.parse_stats == {"mode": "whole", "pieces": 1,
+                                   "threads": 1, "merge_ms": 0.0}
+        assert len(one) == 480 and len(one.tombstones) == 2
+        got = native.parse_events_jsonl(buf, pieces=pieces)
+        _same_scan(got, one)
+        if pieces > 1:
+            assert got.parse_stats["mode"] == "split"
+            assert got.parse_stats["pieces"] == pieces
+            assert 2 <= got.parse_stats["threads"] <= pieces
+            # the first piece's table comes first, whole and in its order
+            assert one.table(2)[0] == "u-everywhere"
+    return case
+
+
+def _empty_buffer(tmp_path, monkeypatch):
+    for pieces in (None, 1, 16):
+        got = native.parse_events_jsonl(b"", pieces=pieces)
+        assert len(got) == 0 and got.tables == [[]] * 6
+        assert got.parse_stats["mode"] == "whole"
+
+
+def _falls_back(make_line):
+    """Every record holds a newline, so some cut of seven falls inside one:
+    that piece fails and the one pass gives the result."""
+    def case(tmp_path, monkeypatch):
+        buf = "".join(make_line(n) for n in range(120)).encode()
+        one = native.parse_events_jsonl(buf, pieces=1)
+        assert len(one) == 120
+        got = native.parse_events_jsonl(buf, pieces=7)
+        assert got.parse_stats["mode"] == "fallback"
+        assert got.parse_stats["pieces"] == 7
+        _same_scan(got, one)
+    return case
+
+
+def _two_line_record(n):
+    return ('{"event": "rate", "entityType": "user", "entityId": "u%d", '
+            '"eventId": "two-lines-%d",\n "targetEntityType": "item", '
+            '"targetEntityId": "i%d"}\n' % (n % 9, n, n % 4))
+
+
+def _raw_newline_in_a_string(n):
+    return ('{"event": "rate", "entityType": "user", "entityId": "u%d", '
+            '"eventId": "raw-newline-%d", "properties": {"rating": 3, '
+            '"note": "first\nsecond"}}\n' % (n % 9, n))
+
+
+def _malformed_in_the_third_of_five(tmp_path, monkeypatch):
+    lines = [json.dumps({"event": "rate", "entityType": "user",
+                         "entityId": "u%03d" % n, "eventId": "e%03d" % n})
+             for n in range(500)]
+    lines[250] = lines[250][:-1] + ' "oops"}'
+    buf = ("\n".join(lines) + "\n").encode()
+    assert 2 / 5 < buf.index(b"oops") / len(buf) < 3 / 5
+    texts = []
+    for pieces in (1, 5):
+        with pytest.raises(native.EventParseError) as err:
+            native.parse_events_jsonl(buf, pieces=pieces)
+        texts.append(str(err.value))
+    assert texts[0] == texts[1]
+    assert texts[0].endswith("at byte %d (record 250)" % buf.index(b'"oops"'))
+
+
+def _under_the_floor_starts_no_thread(tmp_path, monkeypatch):
+    lib = native._load()
+    started = lib.pio_threads_started()
+    got = native.parse_events_jsonl(_split_corpus())
+    assert got.parse_stats == {"mode": "whole", "pieces": 1, "threads": 1,
+                               "merge_ms": 0.0}
+    assert lib.pio_threads_started() == started
+    native.parse_events_jsonl(_split_corpus(), pieces=3)
+    assert lib.pio_threads_started() > started
+
+
+def _python_oracle_agrees(tmp_path, monkeypatch):
+    buf = _split_corpus()
+    oracle = native.parse_events_jsonl_py(buf)
+    assert oracle.parse_stats["mode"] == "whole"
+    _columns_equal(native.parse_events_jsonl(buf, pieces=7), oracle)
+
+
+def _find_ratings_over_a_split_log(tmp_path, monkeypatch):
+    import functools
+    import random
+
+    from incubator_predictionio_tpu.data.storage import jsonl
+
+    random.seed(11)
+    ratings = [("u%d" % random.randrange(40), "i%d" % random.randrange(15),
+                random.choice([None, 1.0, 2.0, 5.0])) for _ in range(300)]
+    s = _storage("jsonl", tmp_path)
+    _seed_app(s, ratings)
+    s.close()
+    seen = []
+
+    def split(buf):
+        cols = native.parse_events(buf, pieces=5)
+        seen.append(cols.parse_stats["mode"])
+        return cols
+
+    out = []
+    for parse in (functools.partial(native.parse_events, pieces=1), split):
+        monkeypatch.setattr(jsonl, "parse_events", parse)
+        s = _storage("jsonl", tmp_path)  # a store that has scanned nothing
+        s.get_meta_data_apps().insert(App(0, "fastpath", None))
+        u, i, r, users, items = PEventStore.find_ratings(
+            "fastpath", event_names=["rate", "buy"],
+            event_default_ratings={"buy": 4.0}, storage=s)
+        out.append((u, i, r, users.to_dict(), items.to_dict()))
+        s.close()
+    assert seen == ["split"]
+    assert len(out[0][0]) == 300
+    for a, b in zip(out[0][:3], out[1][:3]):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert out[0][3:] == out[1][3:]
+    assert list(out[0][3]) == list(out[1][3])  # the maps' order too
+
+
+_SPLIT_CASES = {
+    **{"equals-one-pass-%d-pieces" % n: _split_equals_one_pass(n)
+       for n in (1, 2, 3, 7, 16)},
+    "empty-buffer": _empty_buffer,
+    "record-over-two-lines-falls-back": _falls_back(_two_line_record),
+    "raw-newline-in-a-string-falls-back":
+        _falls_back(_raw_newline_in_a_string),
+    "malformed-in-third-of-five-gives-the-one-pass-error":
+        _malformed_in_the_third_of_five,
+    "under-the-floor-whole-and-no-thread": _under_the_floor_starts_no_thread,
+    "python-oracle-agrees": _python_oracle_agrees,
+    "find-ratings-over-a-split-log": _find_ratings_over_a_split_log,
+}
+
+
+@pytest.mark.parametrize("case", list(_SPLIT_CASES.values()),
+                         ids=list(_SPLIT_CASES))
+def test_split_parse(case, tmp_path, monkeypatch):
+    """A large buffer is cut at newlines and parsed as pieces on threads
+    (native/src/event_codec.cc ``parse_split``); whatever the cuts, the
+    result is the one pass's, field by field, or the one pass's error."""
+    if not native.available():
+        pytest.skip("no C++ toolchain")
+    case(tmp_path, monkeypatch)
+
+
 def test_find_ratings_fast_equals_slow(tmp_path):
     import random
 

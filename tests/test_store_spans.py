@@ -4,7 +4,9 @@ leaves ``store.scan`` (with ``store.parse`` inside), ``store.select`` (the
 masks, then the order) and ``store.index`` with their tags; a second read in
 the process says ``cached``; an appended tail is parsed alone; a compacted
 log loads from its snapshot; ``pio_store_scan_bytes_total`` and
-``pio_store_scan_events_total{source}`` move by the log's bytes and events;
+``pio_store_scan_events_total{source}`` move by the log's bytes and events,
+``pio_store_parse_total{mode}`` by one a parse (these logs are under the
+codec's floor: ``whole``, one piece, one thread);
 with metrics off nothing is recorded and the triple is the same."""
 
 import datetime
@@ -23,6 +25,8 @@ from incubator_predictionio_tpu.data.store.p_event_store import PEventStore
 
 APP = "SpanShop"
 T0 = datetime.datetime(2014, 7, 1, tzinfo=datetime.timezone.utc)
+#: how the codec says it ran on a read under its floor
+ONE_PASS = {"mode": "whole", "pieces": 1, "threads": 1, "merge_ms": 0.0}
 STORE_SPANS = ("store.scan", "store.parse", "store.select", "store.index")
 
 
@@ -80,6 +84,9 @@ def counters() -> dict:
     for source in ("parse", "snapshot", "cached"):
         out[source] = fams["pio_store_scan_events_total"].labels(
             source).value()
+    for mode in ("whole", "split", "fallback"):
+        out["parses:" + mode] = fams["pio_store_parse_total"].labels(
+            mode).value()
     return out
 
 
@@ -102,7 +109,8 @@ def test_a_cold_read_leaves_each_span_once_with_its_tags(shop):
     by = {(s.name, (s.tags or {}).get("step")): s for s in spans}
     scan, parse = by["store.scan", None], by["store.parse", None]
     assert scan.tags == {"source": "parse", "bytes": size, "events": 40}
-    assert parse.parent_id == scan.span_id and parse.tags == {"bytes": size}
+    assert parse.parent_id == scan.span_id
+    assert parse.tags == {"bytes": size, **ONE_PASS}
     assert scan.t0_ns <= parse.t0_ns and parse.t1_ns <= scan.t1_ns
     mask, order = by["store.select", "mask"], by["store.select", "order"]
     assert mask.tags == {"step": "mask", "events": 40, "selected": 40}
@@ -113,7 +121,7 @@ def test_a_cold_read_leaves_each_span_once_with_its_tags(shop):
     # in the order of the work, none overlapping the next
     assert scan.t1_ns <= mask.t0_ns <= mask.t1_ns <= order.t0_ns
     assert order.t1_ns <= index.t0_ns <= index.t1_ns <= root.t1_ns
-    assert moved(before) == {"bytes": size, "parse": 40}
+    assert moved(before) == {"bytes": size, "parse": 40, "parses:whole": 1}
 
 
 def test_a_second_read_in_the_process_says_cached(shop):
@@ -144,8 +152,8 @@ def test_an_appended_tail_is_parsed_alone(shop):
     grown = os.path.getsize(path) - size
     assert scan.tags == {"source": "parse", "bytes": grown, "events": 12}
     parse = next(s for s in spans if s.name == "store.parse")
-    assert parse.tags == {"bytes": grown}
-    assert moved(before) == {"bytes": grown, "parse": 12}
+    assert parse.tags == {"bytes": grown, **ONE_PASS}
+    assert moved(before) == {"bytes": grown, "parse": 12, "parses:whole": 1}
 
 
 def test_a_compacted_log_loads_from_its_snapshot(shop, tmp_path):
@@ -165,7 +173,7 @@ def test_a_compacted_log_loads_from_its_snapshot(shop, tmp_path):
     # the tail past the snapshot is the only JSON parsed
     parse = [s for s in spans if s.name == "store.parse"]
     assert len(parse) == 1 and 0 < parse[0].tags["bytes"] < size
-    assert moved(before) == {"bytes": size, "snapshot": 44}
+    assert moved(before) == {"bytes": size, "snapshot": 44, "parses:whole": 1}
     assert (u[:40] == want[0]).all() and (i[:40] == want[1]).all()
 
 
