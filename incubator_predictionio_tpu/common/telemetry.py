@@ -34,6 +34,14 @@ This module is the one measurement substrate every layer records into:
   holds a ``jax.profiler.TraceAnnotation("pio:<name>")`` once
   :func:`install_xla_hooks` was handed jax (this module never imports
   it), so a profiler session shows host spans beside device ops.
+- **The runtime beneath the spans** — what stands still when a whole
+  process stalls is under every span: the thread that would close it
+  is not running. A ``gc.callbacks`` hook counts every collection and
+  its pause (``py.gc`` spans for the long ones), and
+  :func:`loop_monitor` has an aiohttp application time its own event
+  loop's wake-ups (``loop.stall``, ``loop.beat``). These are the
+  process's spans, not a request's: roots of the one trace
+  :data:`PROCESS_TRACE`, which no tree of a request or a train holds.
 - **Sampled export** — ``PIO_TRACE`` sets a sample rate; a sampled
   request gets a trace id (honoring an incoming ``X-Pio-Trace-Id``,
   which — whenever tracing is enabled at all — bypasses the
@@ -48,12 +56,16 @@ a scraper expects.
 
 from __future__ import annotations
 
+import asyncio
 import collections
+import contextlib
 import contextvars
+import gc
 import itertools
 import json
 import os
 import random
+import resource
 import sys
 import threading
 import time
@@ -65,6 +77,7 @@ from . import envknobs
 __all__ = [
     "CounterFamily", "GaugeFamily", "HistogramFamily", "Registry",
     "Span", "span", "add_span", "spans_snapshot", "install_xla_hooks",
+    "PROCESS_TRACE", "gc_totals", "gc_share", "loop_monitor",
     "RING_SIZE", "Trace", "TraceRecorder", "TRACE_HEADER",
     "current_trace", "activate_trace", "deactivate_trace",
     "metrics_enabled", "set_metrics_enabled", "timer_start",
@@ -101,8 +114,11 @@ def metrics_enabled() -> bool:
 
 
 def set_metrics_enabled(on: bool) -> None:
-    """Flip metric recording at runtime (bench A/B, tests)."""
+    """Flip metric recording at runtime (bench A/B, tests). The
+    collector's hook goes in and out with it; a loop monitor that is
+    running ticks on and records nothing."""
     _STATE.metrics_on = bool(on)
+    _watch_gc(_STATE.metrics_on)
 
 
 def timer_start() -> int:
@@ -606,15 +622,22 @@ def span(name: str, *, trace_id=None, **tags):
     return _OpenSpan(name, trace_id, tags or None)
 
 
-def add_span(name: str, t0_ns: int, t1_ns: int, **tags) -> None:
+def add_span(name: str, t0_ns: int, t1_ns: int, *, trace_id=None,
+             **tags) -> None:
     """A span whose start and end (``perf_counter_ns``) are only known
     afterwards: a compile reported by its listener, the wait of an
     admitted query for a worker. A child of the span open in this
     context; not in the profiler's trace (an annotation cannot be
-    backdated)."""
+    backdated). With ``trace_id`` it is a root of that trace instead,
+    whatever is open here: the process's own spans (a collection that
+    ran inside some request's handler is not that request's work) go
+    under :data:`PROCESS_TRACE` so."""
     if not _STATE.metrics_on:
         return
-    trace, trace_id, parent_id = _lineage()
+    if trace_id is None:
+        trace, trace_id, parent_id = _lineage()
+    else:
+        trace = parent_id = None
     _finish(Span(trace_id, next(_IDS), parent_id, name, t0_ns, t1_ns,
                  tags or None), trace)
 
@@ -661,6 +684,205 @@ def install_xla_hooks(jax) -> None:
 
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     jax.monitoring.register_event_listener(on_event)
+
+
+# ---------------------------------------------------------------------------
+# the runtime beneath the spans: the collector's pauses, the loop's lag
+# ---------------------------------------------------------------------------
+
+#: trace id of the process's own spans (``py.gc``, ``loop.stall``,
+#: ``loop.beat``): roots that belong to no request and no train
+PROCESS_TRACE = "pio.process"
+
+#: a collection that paused for this long leaves a ``py.gc`` span, and so
+#: does every collection of the oldest generation
+GC_SPAN_NS = 1_000_000
+_GC_OLDEST = 2
+
+#: the loop monitor asks to be woken this often, calls a wake-up that is
+#: this late a stall, and sums up once in this much loop time
+LOOP_TICK_NS = 50_000_000
+LOOP_STALL_NS = 5_000_000
+LOOP_BEAT_NS = 1_000_000_000
+
+# What the hook writes: plain cells, because it runs inside whatever code
+# triggered the collection, which may hold a Counter's shard lock.
+# Collections do not nest (the interpreter's ``collecting`` flag spans
+# both callbacks), so one start cell is enough.
+_GC_COUNT = [0, 0, 0]
+_GC_PAUSE_NS = [0, 0, 0]
+_GC_OPEN = [0, None]  # start (perf_counter_ns), the annotation held
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` hook. Takes no lock; a collection that leaves
+    no span allocates no container."""
+    if phase == "start":
+        ann = _STATE.annotation
+        if ann is not None and info["generation"] == _GC_OLDEST:
+            _GC_OPEN[1] = ann = ann("pio:py.gc")
+            ann.__enter__()
+        _GC_OPEN[0] = time.perf_counter_ns()
+        return
+    t1 = time.perf_counter_ns()
+    t0, ann = _GC_OPEN
+    if ann is not None:
+        _GC_OPEN[1] = None
+        ann.__exit__(None, None, None)
+    if not t0:  # installed between a collection's two calls
+        return
+    _GC_OPEN[0] = 0  # before the booking: a reader between them reads low
+    gen = info["generation"]
+    _GC_COUNT[gen] += 1
+    _GC_PAUSE_NS[gen] += t1 - t0
+    if t1 - t0 >= GC_SPAN_NS or gen == _GC_OLDEST:
+        add_span("py.gc", t0, t1, trace_id=PROCESS_TRACE, generation=gen,
+                 collected=info["collected"],
+                 uncollectable=info["uncollectable"],
+                 thread=threading.get_ident())
+
+
+def _collect_gc() -> list[_Family]:
+    count = CounterFamily(
+        "pio_gc_collections_total",
+        "Collections of the cyclic garbage collector, by generation.",
+        ("generation",))
+    pause = CounterFamily(
+        "pio_gc_pause_seconds_total",
+        "Seconds the collector ran, by generation: every thread of the "
+        "process waits, the interpreter lock is held.", ("generation",))
+    for gen in range(len(_GC_COUNT)):
+        count.labels(gen).inc(_GC_COUNT[gen])
+        pause.labels(gen).inc(_GC_PAUSE_NS[gen] * 1e-9)
+    return [count, pause]
+
+
+def _watch_gc(on: bool) -> None:
+    """Put the hook into ``gc.callbacks`` and its two families into the
+    exposition, or take both out."""
+    if on == (_on_gc in gc.callbacks):
+        return
+    if on:
+        gc.callbacks.append(_on_gc)
+        _REGISTRY.register_collector("runtime.gc", _collect_gc)
+    else:
+        gc.callbacks.remove(_on_gc)
+        _REGISTRY.unregister_collector("runtime.gc")
+
+
+_watch_gc(_STATE.metrics_on)
+
+
+def gc_totals() -> tuple[int, int]:
+    """(collections, ns paused) since the hook went in, every generation:
+    what :func:`gc_share` takes the difference of."""
+    return sum(_GC_COUNT), sum(_GC_PAUSE_NS)
+
+
+@contextlib.contextmanager
+def gc_share(open_span):
+    """Tag ``open_span`` at close with the collections that ended while
+    this was open (``gc_collections``) and their pauses (``gc_ms``), in
+    whatever thread: two reads, exact, the short pauses included."""
+    n0, p0 = gc_totals()
+    try:
+        yield
+    finally:
+        n1, p1 = gc_totals()
+        open_span.tag(gc_ms=(p1 - p0) * 1e-6, gc_collections=n1 - n0)
+
+
+class _LoopReading:
+    """What a tick of the loop monitor reads besides the clock."""
+
+    __slots__ = ("cpu_ns", "loop_cpu_ns", "gc_ns", "nivcsw", "majflt")
+
+    def __init__(self, now_ns: int, gc_floor_ns: int = 0):
+        self.cpu_ns = time.process_time_ns()
+        self.loop_cpu_ns = time.thread_time_ns()
+        # The collector's time so far, a collection that is open included:
+        # a collecting thread gives the interpreter lock up as it enters
+        # the hook's ``stop`` call, so the loop wakes from a long pause
+        # BEFORE that pause is booked. Booked later from the hook's own
+        # clock it may come out microseconds shorter: never step back.
+        booked, open_t0 = sum(_GC_PAUSE_NS), _GC_OPEN[0]
+        self.gc_ns = max(gc_floor_ns,
+                         booked + (now_ns - open_t0 if open_t0 else 0))
+        usage = resource.getrusage(resource.RUSAGE_SELF)
+        self.nivcsw, self.majflt = usage.ru_nivcsw, usage.ru_majflt
+
+
+async def _monitor_loop(loop_name: str) -> None:
+    """Ask to be woken every LOOP_TICK_NS and note how late the wake-up
+    came. A tick reads the clocks once; every difference a span carries
+    is taken between two ticks, so a stall's readings hold the tick
+    before it was due as well (a quiet second's beat says what that
+    costs)."""
+    reg = registry()
+    lag_hist = reg.histogram(
+        "pio_event_loop_lag_seconds",
+        "How late the server's event loop woke its monitor, asked for "
+        "every 50 ms: what a timer or a hop through the loop waits.",
+        ("loop",)).labels(loop_name)
+    stalled = reg.counter(
+        "pio_event_loop_stall_seconds_total",
+        "Seconds of wake-ups that came 5 ms or more late: time in which "
+        "the event loop could not run.", ("loop",)).labels(loop_name)
+    lags: list[int] = []
+    beat_t0 = time.perf_counter_ns()
+    last = beat = _LoopReading(beat_t0)
+    while True:
+        due = time.perf_counter_ns() + LOOP_TICK_NS
+        await asyncio.sleep(LOOP_TICK_NS * 1e-9)
+        now = time.perf_counter_ns()
+        lag = max(0, now - due)
+        read = _LoopReading(now, last.gc_ns)
+        lag_hist.observe_raw(lag)
+        lags.append(lag)
+        if lag >= LOOP_STALL_NS:
+            stalled.inc(lag * 1e-9)
+            add_span("loop.stall", due, now, trace_id=PROCESS_TRACE,
+                     loop=loop_name, lag_ms=lag * 1e-6,
+                     loop_cpu_ms=(read.loop_cpu_ns - last.loop_cpu_ns) * 1e-6,
+                     cpu_ms=(read.cpu_ns - last.cpu_ns) * 1e-6,
+                     gc_ms=(read.gc_ns - last.gc_ns) * 1e-6,
+                     nivcsw=read.nivcsw - last.nivcsw,
+                     majflt=read.majflt - last.majflt)
+        last = read
+        if now - beat_t0 >= LOOP_BEAT_NS:
+            lags.sort()
+            add_span("loop.beat", beat_t0, now, trace_id=PROCESS_TRACE,
+                     loop=loop_name, ticks=len(lags),
+                     lag_med_ms=lags[len(lags) // 2] * 1e-6,
+                     lag_max_ms=lags[-1] * 1e-6,
+                     cpu_ms=(read.cpu_ns - beat.cpu_ns) * 1e-6,
+                     loop_cpu_ms=(read.loop_cpu_ns - beat.loop_cpu_ns) * 1e-6,
+                     gc_ms=(read.gc_ns - beat.gc_ns) * 1e-6)
+            lags.clear()
+            beat, beat_t0 = read, now
+
+
+def loop_monitor(loop_name: str):
+    """An aiohttp ``cleanup_ctx`` entry: the application's event loop
+    times its own wake-ups from start-up to clean-up (``loop.stall`` and
+    ``loop.beat`` spans, ``pio_event_loop_*``). Servers append it where
+    they append :func:`trace_middleware`; with ``PIO_METRICS=0`` no task
+    is started."""
+
+    async def _ctx(app):
+        if not _STATE.metrics_on:
+            yield
+            return
+        task = asyncio.get_running_loop().create_task(
+            _monitor_loop(loop_name), name=f"pio-loop-monitor-{loop_name}")
+        try:
+            yield
+        finally:
+            task.cancel()
+            with contextlib.suppress(asyncio.CancelledError):
+                await task
+
+    return _ctx
 
 
 # ---------------------------------------------------------------------------

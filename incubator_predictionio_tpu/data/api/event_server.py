@@ -146,6 +146,7 @@ class EventServer:
             client_max_size=16 * 1024 * 1024,
             middlewares=[self._shed_middleware,
                          telemetry.trace_middleware()])
+        self.app.cleanup_ctx.append(telemetry.loop_monitor("event"))
         self.app.on_startup.append(self._start_background)
         self.app.on_shutdown.append(self._drain_ingest)
         self.app.add_routes(

@@ -369,10 +369,12 @@ def run_train(
 
     try:
         # the train's root span: every dase.* / als.* / xla.compile span
-        # beneath it shares its trace id, the engine-instance id
+        # beneath it shares its trace id, the engine-instance id; at close
+        # it says how long the collector held the train up
         with telemetry.span("train.run", trace_id=instance_id,
                             instance=instance_id,
-                            factory=engine_factory_name):
+                            factory=engine_factory_name) as root, \
+                telemetry.gc_share(root):
             models = _train_with_stale_checkpoint_fallback(
                 engine, engine_params, ctx, wp, cm=_profile_cm)
             gang.beat()

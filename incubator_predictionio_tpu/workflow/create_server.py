@@ -353,6 +353,7 @@ class EngineServer:
 
         self.app = web.Application(
             middlewares=[telemetry.trace_middleware()])
+        self.app.cleanup_ctx.append(telemetry.loop_monitor("engine"))
         self.app.add_routes(
             [
                 web.get("/", self.handle_status),

@@ -227,6 +227,17 @@ def test_event_server_metrics_e2e():
     assert "# TYPE pio_storage_breaker_state gauge" in body
     # histograms expose cumulative buckets ending in +Inf
     assert 'pio_ingest_group_size_bucket{le="+Inf"}' in body
+    _assert_runtime_families(body, "event")
+
+
+def _assert_runtime_families(body, loop):
+    """The four families of the runtime beneath a running server."""
+    assert "# TYPE pio_gc_collections_total counter" in body
+    assert 'pio_gc_pause_seconds_total{generation="2"}' in body
+    assert "# TYPE pio_event_loop_lag_seconds histogram" in body
+    assert 'pio_event_loop_lag_seconds_bucket{loop="%s",le="+Inf"}' % loop \
+        in body
+    assert 'pio_event_loop_stall_seconds_total{loop="%s"}' % loop in body
 
 
 def _trained_engine_server(memory_storage):
@@ -266,6 +277,7 @@ def test_engine_server_metrics_e2e(memory_storage):
     assert "# TYPE pio_engine_compile_seconds gauge" in body
     assert 'pio_engine_compile_count{algorithm=' in body
     assert "pio_engine_query_count" in samples
+    _assert_runtime_families(body, "engine")
 
 
 def test_dashboard_metrics_pages():
@@ -503,7 +515,9 @@ def test_run_train_leaves_its_spans_under_one_root(memory_storage):
     assert len(roots) == 1
     root = roots[0]
     assert root.trace_id == instance_id and root.parent_id is None
-    assert root.tags == {"instance": instance_id, "factory": "rec"}
+    assert root.tags["instance"] == instance_id
+    assert set(root.tags) == {"instance", "factory", "gc_ms",
+                              "gc_collections"}
     mine = [s for s in spans if s.trace_id == instance_id]
     by_name = {s.name: s for s in mine}
     assert {"dase.read", "dase.prepare", "dase.algo_train", "als.layout",
@@ -637,8 +651,9 @@ def test_disabled_path_no_allocations():
     # and the enabled path actually records
     hot_request()
     assert c.value() == 1
-    assert [x.name for x in telemetry.spans_snapshot()[-2:]] == [
-        "t.noalloc.child", "t.noalloc"]
+    mine = [x.name for x in telemetry.spans_snapshot()
+            if x.trace_id != telemetry.PROCESS_TRACE]
+    assert mine[-2:] == ["t.noalloc.child", "t.noalloc"]
 
 
 def test_disabled_metrics_skip_recording():
