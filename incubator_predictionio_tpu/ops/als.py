@@ -708,6 +708,34 @@ def _cached_packed_train_fn(mesh: Mesh, params: ALSParams,
 _INIT_SCRATCH_ROWS = 8192
 
 
+def _init_stream(params: ALSParams, blocks, stop=None) -> bool:
+    """The init's one stream: ``default_rng(seed)`` drawn block after block,
+    each ``(n_rows, out, slots)`` of ``blocks`` as ``n_rows x k`` float64
+    standard normals through one reused scratch, chunk by chunk (NumPy fills
+    in order, so chunks give the stream of one whole draw, bit for bit).
+    With ``out`` a chunk is divided by ``sqrt(k)`` in float64 and cast into
+    ``out[slots[rows]]``, or ``out[rows]`` where ``slots`` is None; without
+    ``out`` the samples are drawn and dropped: no divide, no cast, no array.
+
+    ``stop`` (a ``threading.Event``) is read once a chunk: once it is set
+    the draw ends and the answer is False, with ``out`` left half filled."""
+    k = params.rank
+    scale = np.sqrt(k)
+    rng = np.random.default_rng(params.seed)
+    scratch = np.empty((_INIT_SCRATCH_ROWS, k))
+    for n_rows, out, slots in blocks:
+        for lo in range(0, n_rows, _INIT_SCRATCH_ROWS):
+            if stop is not None and stop.is_set():
+                return False
+            chunk = scratch[:n_rows - lo]
+            rng.standard_normal(out=chunk)
+            if out is not None:
+                chunk /= scale
+                hi = lo + len(chunk)
+                out[slice(lo, hi) if slots is None else slots[lo:hi]] = chunk
+    return True
+
+
 def _fresh_init(params: ALSParams, plan_u: LayoutPlan, plan_i: LayoutPlan,
                 n_users: int, n_items: int, keep_users: bool = True):
     """MLlib-style init (scaled standard normal), drawn in GLOBAL row
@@ -715,37 +743,90 @@ def _fresh_init(params: ALSParams, plan_u: LayoutPlan, plan_i: LayoutPlan,
     mesh shape or layout, and filler slots start at exactly 0 (so the
     implicit-mode YᵀY term never sees garbage rows).
 
-    The stream is fixed: ``default_rng(seed)`` gives the ``(n_users, k)``
-    normals first, then the ``(n_items, k)``, each ``/ sqrt(k)`` in
-    float64 and cast to float32. Both blocks go through one reused scratch,
-    chunk by chunk (NumPy fills in order, so chunks give the stream of one
-    whole draw, bit for bit).
+    The stream is fixed (``_init_stream``): ``default_rng(seed)`` gives the
+    ``(n_users, k)`` normals first, then the ``(n_items, k)``, each
+    ``/ sqrt(k)`` in float64 and cast to float32.
 
     A sweep starts with ``x = one_side(y, ...)``, so a train of one
     iteration or more never reads ``x0``: its caller passes
     ``keep_users=False`` and gets ``(None, y0)``. The user block is then
     still DRAWN, sample for sample, because ``y0`` has to come from where
-    the stream stands after it, and dropped: no divide, no cast, no
-    scatter and no ``[n_users, k]`` array on the host. A train of no
-    iteration, whose result IS the init, keeps it."""
+    the stream stands after it, and dropped: no ``[n_users, k]`` array on
+    the host. A train of no iteration, whose result IS the init, keeps
+    it."""
     k = params.rank
-    scale = np.sqrt(k)
-    rng = np.random.default_rng(params.seed)
-    scratch = np.empty((_INIT_SCRATCH_ROWS, k))
-
-    def block(n_rows: int, plan: LayoutPlan, keep: bool):
-        out = np.zeros((plan.total_slots, k), np.float32) if keep else None
-        for lo in range(0, n_rows, _INIT_SCRATCH_ROWS):
-            chunk = scratch[:n_rows - lo]
-            rng.standard_normal(out=chunk)
-            if keep:
-                chunk /= scale
-                out[plan.slot_of_row[lo:lo + len(chunk)]] = chunk
-        return out
-
-    x0 = block(n_users, plan_u, keep_users)
-    y0 = block(n_items, plan_i, True)
+    x0 = np.zeros((plan_u.total_slots, k), np.float32) if keep_users else None
+    y0 = np.zeros((plan_i.total_slots, k), np.float32)
+    _init_stream(params, ((n_users, x0, plan_u.slot_of_row),
+                          (n_items, y0, plan_i.slot_of_row)))
     return x0, y0
+
+
+class _InitAhead:
+    """The init of a fresh train of one sweep or more, begun before the
+    layout on ONE worker thread (``pio-init``) and joined where ``y0`` is
+    needed. What it draws needs no plan: the user block, drawn and dropped,
+    then the item block as float32 rows in GLOBAL order (``_init_stream``:
+    the stream, the chunks and the arithmetic of ``_fresh_init``); the
+    calling thread places the rows into ``plan_i``'s slots once it has
+    them, so ``y0`` is ``_fresh_init``'s bit for bit. ``rng.standard_normal``
+    releases the GIL, so the draw runs beside the layout's native fills,
+    the pack and whatever else the calling thread does.
+
+    Spans: the worker's ``als.init`` (tags ``users=dropped``,
+    ``overlap=layout``) opens in a copy of the caller's context, so it stays
+    in the train's tree; ``als.init_wait`` on the calling thread covers the
+    join and the placing: what of the init is still on the critical path.
+
+    A context manager: leaving it, by an exception of the calling thread
+    too, tells the worker to stop (read once a chunk) and joins it, so no
+    thread outlives ``train_als``. The worker's own exception is re-raised
+    by ``y0``."""
+
+    def __init__(self, params: ALSParams, n_users: int, n_items: int):
+        import contextvars
+        import threading
+        from concurrent.futures import ThreadPoolExecutor
+
+        self._stop = threading.Event()
+        self._pool = ThreadPoolExecutor(max_workers=1,
+                                        thread_name_prefix="pio-init")
+        self._rows = self._pool.submit(
+            contextvars.copy_context().run, self._draw, params, n_users,
+            n_items)
+
+    def _draw(self, params: ALSParams, n_users: int, n_items: int):
+        with telemetry.span("als.init", users="dropped", overlap="layout"):
+            rows = np.empty((n_items, params.rank), np.float32)
+            _init_stream(params, ((n_users, None, None),
+                                  (n_items, rows, None)), self._stop)
+            return rows
+
+    def y0(self, plan_i: LayoutPlan) -> np.ndarray:
+        with telemetry.span("als.init_wait"):
+            rows = self._rows.result()
+            self._rows = None    # the rows die here, not with the train
+            y0 = np.zeros((plan_i.total_slots, rows.shape[1]), np.float32)
+            y0[plan_i.slot_of_row] = rows
+        return y0
+
+    def __enter__(self) -> "_InitAhead":
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self._stop.set()
+        self._pool.shutdown(wait=True)
+        return False
+
+
+def _usable_cpus() -> int:
+    """CPUs this process may run on (its affinity mask where the platform
+    has one): a worker thread beside the caller needs a second."""
+    import os
+
+    if hasattr(os, "sched_getaffinity"):
+        return len(os.sched_getaffinity(0))
+    return os.cpu_count() or 1
 
 
 def _zeros_on_device(shape, sharding):
@@ -787,8 +868,11 @@ def train_als(
     bitwise-identical math to the single fori_loop. The reference cannot do
     this at all — a failed Spark ALS job restarts from zero (SURVEY.md §5.4).
 
-    Phases are spans (common/telemetry.py): ``als.layout``, ``als.init``,
-    ``als.pack``, ``als.upload`` (the host's part of the transfer; no
+    Phases are spans (common/telemetry.py): ``als.layout``, ``als.init``
+    (tag ``overlap``: on a worker thread beside the layout and the pack,
+    or in turn after the layout), ``als.pack``, ``als.init_wait`` (what
+    the calling thread still waits for the worker's init),
+    ``als.upload`` (the host's part of the transfer; no
     barrier, so what the transfer still owes lies in the loop),
     ``als.loop`` (dispatch until the factors are ready; one per dispatch
     in the ``nan_guard`` and checkpoint-chunked branches, a compile its
@@ -811,45 +895,77 @@ def train_als(
         from ..workflow.input_pipeline import PipelineConfig
 
         pipeline = PipelineConfig.from_env()
-    with telemetry.span("als.layout"):
-        plan_u, plan_i, arrs_u, arrs_i = plan_and_fill_both(
-            user_idx, item_idx, rating, n_users, n_items, d_size,
-            m_div=m_size, fill_vals=not params.binary_ratings,
-            parallel=pipeline.mode != "off")
 
-    k = params.rank
-    x_shape = (plan_u.total_slots, k)
-    y_shape = (plan_i.total_slots, k)
-
-    # Fingerprint of the exact COO triple: resume is only sound against the
-    # identical rating data (shape equality alone misses in-place rating
-    # updates that keep n_users/n_items fixed). Only computed when a hook
-    # is active — it's O(nnz) hashing that plain trains shouldn't pay.
-    fingerprint = None
-    if checkpoint_hook is not None:
-        import zlib
-
-        # Seeded with _LAYOUT_TAG (layout generation) and the slot
-        # permutations (mesh-dependent): factors are stored in slot
-        # order, so a snapshot is only resumable by a run with the
-        # IDENTICAL plan — same data AND same (d, m) mesh shape.
-        layout_fp = zlib.crc32(
-            plan_i.slot_of_row.tobytes(),
-            zlib.crc32(plan_u.slot_of_row.tobytes(), _LAYOUT_TAG))
-        fingerprint = zlib.crc32(
-            np.asarray(rating, np.float32).tobytes(),
-            zlib.crc32(np.asarray(item_idx).tobytes(),
-                       zlib.crc32(np.asarray(user_idx).tobytes(),
-                                  layout_fp)))
-
-    start_iter = 0
-    x0 = y0 = None
+    # Fresh start or resume is known before the layout: it hangs on the
+    # hook's latest snapshot, not on the plan (the shape and fingerprint
+    # checks of a restored snapshot wait below, where the plan exists).
+    resume_step = None
     if checkpoint_hook is not None and resume:
         from ..workflow.checkpoint import CheckpointIncompatibleError
 
-        step = checkpoint_hook.latest_step()
-        if step is not None and step < params.num_iterations:
-            start_iter, tree = checkpoint_hook.restore(step)
+        resume_step = checkpoint_hook.latest_step()
+        if resume_step is not None and resume_step >= params.num_iterations:
+            # Snapshots are never written at the final iteration, so a
+            # checkpoint at step >= num_iterations means the params changed
+            # (num_iterations lowered) since the interrupted run.
+            raise CheckpointIncompatibleError(
+                f"latest checkpoint is at iteration {resume_step} but only "
+                f"{params.num_iterations} iterations were requested; the "
+                "snapshot is from a run with more iterations — retrain from "
+                "scratch or raise num_iterations"
+            )
+    # a fresh start (start_iter 0) of one sweep or more overwrites x0
+    # before it reads it: the user block is drawn and dropped, so nothing
+    # of the init needs plan_u and it runs beside the layout and the pack
+    # (_InitAhead). A train of no iteration keeps x0, in turn; a resumed
+    # train draws nothing.
+    # The overlap follows what already decides that plan_and_fill_both
+    # uses threads (PIO_PIPELINE=off runs every host stage in turn), and
+    # needs a second CPU for the worker to run on.
+    import contextlib
+
+    keep_users = params.num_iterations < 1
+    parallel = pipeline.mode != "off"
+    ahead = (resume_step is None and not keep_users and parallel
+             and _usable_cpus() >= 2)
+    with (_InitAhead(params, n_users, n_items) if ahead
+          else contextlib.nullcontext()) as init:
+        with telemetry.span("als.layout"):
+            plan_u, plan_i, arrs_u, arrs_i = plan_and_fill_both(
+                user_idx, item_idx, rating, n_users, n_items, d_size,
+                m_div=m_size, fill_vals=not params.binary_ratings,
+                parallel=parallel)
+
+        k = params.rank
+        x_shape = (plan_u.total_slots, k)
+        y_shape = (plan_i.total_slots, k)
+
+        # Fingerprint of the exact COO triple: resume is only sound against
+        # the identical rating data (shape equality alone misses in-place
+        # rating updates that keep n_users/n_items fixed). Only computed
+        # when a hook is active — it's O(nnz) hashing that plain trains
+        # shouldn't pay.
+        fingerprint = None
+        if checkpoint_hook is not None:
+            import zlib
+
+            # Seeded with _LAYOUT_TAG (layout generation) and the slot
+            # permutations (mesh-dependent): factors are stored in slot
+            # order, so a snapshot is only resumable by a run with the
+            # IDENTICAL plan — same data AND same (d, m) mesh shape.
+            layout_fp = zlib.crc32(
+                plan_i.slot_of_row.tobytes(),
+                zlib.crc32(plan_u.slot_of_row.tobytes(), _LAYOUT_TAG))
+            fingerprint = zlib.crc32(
+                np.asarray(rating, np.float32).tobytes(),
+                zlib.crc32(np.asarray(item_idx).tobytes(),
+                           zlib.crc32(np.asarray(user_idx).tobytes(),
+                                      layout_fp)))
+
+        start_iter = 0
+        x0 = y0 = None
+        if resume_step is not None:
+            start_iter, tree = checkpoint_hook.restore(resume_step)
             rx, ry = np.asarray(tree["user_factors"]), np.asarray(tree["item_factors"])
             if rx.shape != x_shape or ry.shape != y_shape:
                 raise CheckpointIncompatibleError(
@@ -865,39 +981,28 @@ def train_als(
                     "the interrupted run — retrain from scratch"
                 )
             x0, y0 = rx, ry
-        elif step is not None:
-            # Snapshots are never written at the final iteration, so a
-            # checkpoint at step >= num_iterations means the params changed
-            # (num_iterations lowered) since the interrupted run.
-            raise CheckpointIncompatibleError(
-                f"latest checkpoint is at iteration {step} but only "
-                f"{params.num_iterations} iterations were requested; the "
-                "snapshot is from a run with more iterations — retrain from "
-                "scratch or raise num_iterations"
-            )
-
-    if y0 is None:
-        # a fresh start (start_iter 0) of one sweep or more overwrites x0
-        # before it reads it: the user block is drawn and dropped
-        keep_users = params.num_iterations < 1
-        with telemetry.span("als.init",
-                            users="kept" if keep_users else "dropped"):
-            x0, y0 = _fresh_init(params, plan_u, plan_i, n_users, n_items,
-                                 keep_users=keep_users)
-    fn, in_shardings = _cached_train_fn(mesh, params, plan_u, plan_i)
-    binary = bool(params.binary_ratings)
-    # Single-device runs pack the slabs: 2-3 large transfers instead of
-    # ~70 small ones (see _pack_flat). run_fn/run_args abstract over
-    # packed vs per-slab.
-    packed = jax.process_count() == 1 and mesh.devices.size == 1
-    with telemetry.span("als.pack"):
-        flat = tuple(
-            _side_flat(arrs_u, plan_u, _host_lam(plan_u, params), binary,
-                       col_sentinel=plan_i.total_slots)
-            + _side_flat(arrs_i, plan_i, _host_lam(plan_i, params), binary,
-                         col_sentinel=plan_u.total_slots))
-        if packed:
-            bufs, pack_key = _pack_flat(flat)
+        elif init is None:
+            with telemetry.span("als.init",
+                                users="kept" if keep_users else "dropped",
+                                overlap="none"):
+                x0, y0 = _fresh_init(params, plan_u, plan_i, n_users,
+                                     n_items, keep_users=keep_users)
+        fn, in_shardings = _cached_train_fn(mesh, params, plan_u, plan_i)
+        binary = bool(params.binary_ratings)
+        # Single-device runs pack the slabs: 2-3 large transfers instead of
+        # ~70 small ones (see _pack_flat). run_fn/run_args abstract over
+        # packed vs per-slab.
+        packed = jax.process_count() == 1 and mesh.devices.size == 1
+        with telemetry.span("als.pack"):
+            flat = tuple(
+                _side_flat(arrs_u, plan_u, _host_lam(plan_u, params), binary,
+                           col_sentinel=plan_i.total_slots)
+                + _side_flat(arrs_i, plan_i, _host_lam(plan_i, params),
+                             binary, col_sentinel=plan_u.total_slots))
+            if packed:
+                bufs, pack_key = _pack_flat(flat)
+        if init is not None:
+            y0 = init.y0(plan_i)
     run_fn = (_cached_packed_train_fn(mesh, params, plan_u, plan_i, pack_key)
               if packed else fn)
     # No barrier after the puts: what the transfer still owes when they

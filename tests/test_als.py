@@ -446,7 +446,7 @@ def test_train_never_reads_x0(monkeypatch, n_iters, implicit):
                        implicit_prefs=implicit, alpha=10.0)
     since = time.perf_counter_ns()
     zeros = train_als(u, i, r, 50, 30, params)
-    assert _init_spans(since) == [{"users": "dropped"}]
+    assert _init_spans(since) == [{"users": "dropped", "overlap": "layout"}]
 
     handed = []
 
@@ -455,7 +455,9 @@ def test_train_never_reads_x0(monkeypatch, n_iters, implicit):
         handed.append(x0)
         return x0, y0
 
+    # in turn, the init is _fresh_init's call (the worker has its own)
     monkeypatch.setattr(als, "_fresh_init", legacy)
+    monkeypatch.setenv("PIO_PIPELINE", "off")
     random = train_als(u, i, r, 50, 30, params)
     assert len(handed) == 1 and handed[0].any()
     assert np.array_equal(zeros.user_factors, random.user_factors)
@@ -474,9 +476,126 @@ def test_train_of_no_iteration_returns_the_legacy_init(implicit):
                        implicit_prefs=implicit)
     since = time.perf_counter_ns()
     out = train_als(u, i, r, 50, 30, params)
-    assert _init_spans(since) == [{"users": "kept"}]
+    assert _init_spans(since) == [{"users": "kept", "overlap": "none"}]
     rng = np.random.default_rng(5)
     x = (rng.standard_normal((50, 8)) / np.sqrt(8)).astype(np.float32)
     y = (rng.standard_normal((30, 8)) / np.sqrt(8)).astype(np.float32)
     assert np.array_equal(out.user_factors, x)
     assert np.array_equal(out.item_factors, y)
+
+
+# --- the init beside the layout: same stream, same factors ------------------
+
+@pytest.mark.parametrize("nan_guard", [False, True], ids=["fused", "guarded"])
+@pytest.mark.parametrize("n_dev", [1, 2], ids=["one-device", "mesh2"])
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+def test_overlapped_init_gives_the_factors_of_the_init_in_turn(
+        monkeypatch, implicit, n_dev, nan_guard):
+    """The worker's draw beside the layout and PIO_PIPELINE=off's init
+    after it: the same factors, bit for bit, on one device and a mesh,
+    through the fused loop and the guarded one."""
+    import time
+
+    import jax
+
+    u, i, r = _toy_ratings(n_users=70, n_items=45, density=0.3, seed=8)
+    if implicit:
+        r = np.ones_like(r)
+    params = ALSParams(rank=8, num_iterations=2, reg=0.05, seed=9,
+                       implicit_prefs=implicit, alpha=10.0)
+    mesh = mesh_from_devices(devices=jax.devices()[:n_dev])
+    monkeypatch.delenv("PIO_PIPELINE", raising=False)
+    since = time.perf_counter_ns()
+    beside = train_als(u, i, r, 70, 45, params, mesh=mesh,
+                       nan_guard=nan_guard)
+    assert _init_spans(since) == [{"users": "dropped", "overlap": "layout"}]
+    monkeypatch.setenv("PIO_PIPELINE", "off")
+    since = time.perf_counter_ns()
+    in_turn = train_als(u, i, r, 70, 45, params, mesh=mesh,
+                        nan_guard=nan_guard)
+    assert _init_spans(since) == [{"users": "dropped", "overlap": "none"}]
+    assert np.array_equal(beside.user_factors, in_turn.user_factors)
+    assert np.array_equal(beside.item_factors, in_turn.item_factors)
+    assert beside.item_factors.any()
+
+
+@pytest.mark.parametrize("k", [4, 128])
+def test_the_workers_rows_are_fresh_inits_y0(k):
+    """What the worker draws with no plan, placed into the plan's slots,
+    is _fresh_init's y0 bit for bit, at more rows a side than the scratch
+    holds (so both blocks cross a chunk boundary)."""
+    from incubator_predictionio_tpu.ops.als import _InitAhead
+
+    n_users = 2 * _INIT_SCRATCH_ROWS + 1234
+    n_items = _INIT_SCRATCH_ROWS + 777
+    rng = np.random.default_rng(k)
+    plan_u = plan_layout(rng.integers(0, 5, n_users), 3)
+    plan_i = plan_layout(rng.integers(1, 9, n_items), 3)
+    params = ALSParams(rank=k, seed=11)
+    _, want = _fresh_init(params, plan_u, plan_i, n_users, n_items,
+                          keep_users=False)
+    _, legacy = _legacy_init(params, plan_u, plan_i, n_users, n_items)
+    with _InitAhead(params, n_users, n_items) as init:
+        got = init.y0(plan_i)
+    assert got.dtype == np.float32 and np.array_equal(got, want)
+    assert np.array_equal(got, legacy)
+
+
+def _pio_threads():
+    import threading
+
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith("pio-")]
+
+
+def test_a_failed_layout_stops_and_joins_the_worker(monkeypatch):
+    """plan_and_fill_both raises while the worker is still in the users'
+    block: the flag ends the draw at its next chunk, the thread is joined
+    before the exception leaves train_als, and the exception is the
+    layout's own."""
+    import time
+
+    from incubator_predictionio_tpu.ops import als
+
+    drawn = []
+    real = als._init_stream
+
+    def counting(params, blocks, stop=None):
+        done = real(params, blocks, stop)
+        drawn.append(done)
+        return done
+
+    def broken(*a, **kw):
+        time.sleep(0.05)     # the worker is well into the users' block
+        raise RuntimeError("layout broke")
+
+    monkeypatch.setattr(als, "_init_stream", counting)
+    monkeypatch.setattr(als, "plan_and_fill_both", broken)
+    monkeypatch.delenv("PIO_PIPELINE", raising=False)
+    u, i, r = _toy_ratings(n_users=50, n_items=30, density=0.3, seed=4)
+    # 400 chunks of users: seconds of drawing if nothing stopped it
+    n_users = 400 * _INIT_SCRATCH_ROWS
+    t0 = time.perf_counter()
+    with pytest.raises(RuntimeError, match="layout broke"):
+        train_als(u, i, r, n_users, 30,
+                  ALSParams(rank=128, num_iterations=2, seed=5))
+    assert _pio_threads() == []
+    assert drawn == [False]              # stopped, not run to its end
+    assert time.perf_counter() - t0 < 5.0
+
+
+def test_the_workers_own_exception_leaves_train_als(monkeypatch):
+    """An exception inside the worker's draw is re-raised at the join, as
+    itself, and no thread is left."""
+    from incubator_predictionio_tpu.ops import als
+
+    def broken(params, blocks, stop=None):
+        raise MemoryError("no room for the rows")
+
+    monkeypatch.setattr(als, "_init_stream", broken)
+    monkeypatch.delenv("PIO_PIPELINE", raising=False)
+    u, i, r = _toy_ratings(n_users=50, n_items=30, density=0.3, seed=4)
+    with pytest.raises(MemoryError, match="no room for the rows"):
+        train_als(u, i, r, 50, 30, ALSParams(rank=8, num_iterations=2))
+    assert _pio_threads() == []

@@ -502,6 +502,12 @@ def test_run_train_leaves_its_spans_under_one_root(memory_storage):
     _seed_ratings(memory_storage)
     engine = RecommendationEngine()()
     ctx = WorkflowContext(app_name="testapp", storage=memory_storage)
+    # what earlier tests of this process left in the bounded cache of jitted
+    # loops is dropped here, not inside the train (freeing nine executables
+    # is 50 ms between two spans of a 400 ms train)
+    from incubator_predictionio_tpu.ops import als
+
+    als._train_fn_cache.clear()
     # a rank no other test trains: the step compiles here, as in a first
     # `pio train`, so the train is a second long and not 10 ms of glue
     params = EngineParams.from_json({
@@ -532,12 +538,59 @@ def test_run_train_leaves_its_spans_under_one_root(memory_storage):
     assert by_name["als.loop"].parent_id == algo.span_id
     assert by_name["xla.compile"].parent_id == by_name["als.loop"].span_id
     assert by_name["dase.persist"].tags["bytes"] > 0
-    order = ["dase.read", "dase.prepare", "als.layout", "als.init",
-             "als.pack", "als.upload", "als.loop", "als.readback",
+    order = ["dase.read", "dase.prepare", "als.layout", "als.pack",
+             "als.init_wait", "als.upload", "als.loop", "als.readback",
              "dase.serialize", "dase.persist"]
     starts = [by_name[n].t0_ns for n in order]
     assert starts == sorted(starts)
+    # the init runs on a worker thread beside the layout and the pack: in
+    # the train's tree all the same, begun before the layout has ended, and
+    # what the calling thread waits for it ends no earlier than it does
+    init, wait = by_name["als.init"], by_name["als.init_wait"]
+    assert init.parent_id == wait.parent_id == algo.span_id
+    assert init.tags == {"users": "dropped", "overlap": "layout"}
+    assert algo.t0_ns <= init.t0_ns <= by_name["als.layout"].t1_ns
+    assert init.t1_ns <= wait.t1_ns <= by_name["als.upload"].t0_ns
     assert _covered_share(root, mine) >= 0.95
+
+
+def test_train_als_in_turn_leaves_no_init_wait(monkeypatch):
+    """PIO_PIPELINE=off: the parent's order of stages, the init after the
+    layout on the calling thread (tag overlap=none), and no als.init_wait;
+    with the overlap the wait is there even where it waits 0 ms."""
+    import time
+
+    import numpy as np
+
+    from incubator_predictionio_tpu.ops.als import ALSParams, train_als
+
+    rng = np.random.default_rng(3)
+    u = rng.integers(0, 40, 500).astype(np.int32)
+    i = rng.integers(0, 25, 500).astype(np.int32)
+    r = rng.random(500).astype(np.float32)
+    params = ALSParams(rank=6, num_iterations=2)
+
+    def stages():
+        t0 = time.perf_counter_ns()
+        train_als(u, i, r, 40, 25, params)
+        spans = sorted((s for s in _since(t0) if s.name.startswith("als.")),
+                       key=lambda s: s.t0_ns)
+        return [s.name for s in spans], {s.name: s for s in spans}
+
+    monkeypatch.setenv("PIO_PIPELINE", "off")
+    names, by_name = stages()
+    assert names == ["als.layout", "als.init", "als.pack", "als.upload",
+                     "als.loop", "als.readback"]
+    assert by_name["als.init"].tags == {"users": "dropped",
+                                        "overlap": "none"}
+    assert by_name["als.layout"].t1_ns <= by_name["als.init"].t0_ns
+    monkeypatch.delenv("PIO_PIPELINE")
+    names, by_name = stages()
+    assert sorted(names) == sorted(
+        ["als.layout", "als.init", "als.pack", "als.init_wait",
+         "als.upload", "als.loop", "als.readback"])
+    assert by_name["als.init"].tags["overlap"] == "layout"
+    assert by_name["als.init"].t1_ns <= by_name["als.init_wait"].t1_ns
 
 
 def test_served_query_leaves_wait_and_topk_spans_under_its_root(
