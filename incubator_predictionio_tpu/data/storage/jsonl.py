@@ -48,6 +48,10 @@ _M_SCAN_EVENTS = telemetry.registry().counter(
     "Events a log scan supplied, by where they came from: decoded from "
     "JSON (parse), a committed columnar snapshot (+ its tail), or the "
     "scan this process had cached", ("source",))
+_M_AGGREGATE_ENTITIES = telemetry.registry().counter(
+    "pio_store_aggregate_entities_total",
+    "Entities that a $set/$unset/$delete replay over the columnar scan "
+    "left with properties (one count a store.aggregate span)")
 
 
 _M_PARSE = telemetry.registry().counter(
@@ -106,6 +110,10 @@ def shard_paths(dirpath: str, app_id: int,
 
 class _LogScan:
     """Cached columnar scan of one log file, extended incrementally."""
+
+    #: where the last ``refresh`` got its events (``store.scan``'s tag);
+    #: a merged or windowed view, which no ``refresh`` fills, says so
+    source = "view"
 
     def __init__(self) -> None:
         self.size = 0
@@ -173,6 +181,7 @@ class _LogScan:
             n_events = len(self.cols) - (
                 0 if source == "cached" else had_events)
             sp.tag(source=source, bytes=n_bytes, events=n_events)
+        self.source = source
         _M_SCAN_BYTES.labels().inc(n_bytes)
         _M_SCAN_EVENTS.labels(source).inc(n_events)
 
@@ -1159,6 +1168,13 @@ class JSONLEvents(base.LEvents):
         layer, where a cold read skips whole out-of-window generations
         by manifest bounds (zero decode); the row filter below then
         makes the result bit-identical to filtering the full view."""
+        _scan, cols, rows = self._select_columnar(
+            app_id, channel_id, event_names, start_time, until_time)
+        return cols, rows
+
+    def _select_columnar(self, app_id, channel_id, event_names,
+                         start_time, until_time):
+        """``scan_columnar`` with the scan it read from in front."""
         s_us, u_us = _to_us(start_time), _to_us(until_time)
         window = ((s_us, u_us)
                   if s_us is not None or u_us is not None else None)
@@ -1166,7 +1182,7 @@ class JSONLEvents(base.LEvents):
         cols = scan.cols
         if cols is None:
             empty = parse_events(b"")
-            return empty, np.empty(0, np.int64)
+            return scan, empty, np.empty(0, np.int64)
         with telemetry.span("store.select", step="mask") as sp:
             mask = scan.live_mask()
             if event_names is not None:
@@ -1182,7 +1198,7 @@ class JSONLEvents(base.LEvents):
                         & (cols.time_us < u_us))
             rows = np.nonzero(mask)[0]
             sp.tag(events=len(cols), selected=int(rows.size))
-        return cols, rows
+        return scan, cols, rows
 
     def aggregate_properties(self, app_id, entity_type, channel_id=None,
                              start_time=None, until_time=None,
@@ -1214,27 +1230,38 @@ class JSONLEvents(base.LEvents):
         default-to-now: they sort after every timestamped event (file
         order among themselves) and report the scan time as their
         update time.
+
+        Span ``store.aggregate``: tags ``events`` (the $set, $unset and
+        $delete events replayed, of every entity type), ``entities`` (what
+        the replay left, as ``pio_store_aggregate_entities_total``) and
+        ``source`` (the ``store.scan`` inside it: a train that has read
+        its ratings first says ``cached``).
         """
-        cols, rows = self.scan_columnar(
-            app_id, channel_id, ["$set", "$unset", "$delete"],
-            start_time, until_time)
-        state = aggregate_replay(cols, rows, entity_type)
+        with telemetry.span("store.aggregate") as sp:
+            scan, cols, rows = self._select_columnar(
+                app_id, channel_id, ["$set", "$unset", "$delete"],
+                start_time, until_time)
+            state = aggregate_replay(cols, rows, entity_type)
+            sp.tag(events=int(rows.size), entities=len(state),
+                   source=scan.source)
+            _M_AGGREGATE_ENTITIES.labels().inc(len(state))
 
-        now = _dt.datetime.now(_dt.timezone.utc)
+            now = _dt.datetime.now(_dt.timezone.utc)
 
-        def us_dt(us: int) -> _dt.datetime:
-            if us == _TIME_ABSENT:
-                return now
-            return _EPOCH + _dt.timedelta(microseconds=us)
+            def us_dt(us: int) -> _dt.datetime:
+                if us == _TIME_ABSENT:
+                    return now
+                return _EPOCH + _dt.timedelta(microseconds=us)
 
-        out = {
-            eid: PropertyMap(props, us_dt(first), us_dt(last))
-            for eid, (props, first, last) in state.items()
-        }
-        if required:
-            req = set(required)
-            out = {k: v for k, v in out.items() if req.issubset(v.keyset())}
-        return out
+            out = {
+                eid: PropertyMap(props, us_dt(first), us_dt(last))
+                for eid, (props, first, last) in state.items()
+            }
+            if required:
+                req = set(required)
+                out = {k: v for k, v in out.items()
+                       if req.issubset(v.keyset())}
+            return out
 
     def compact(self, app_id: int, channel_id: Optional[int] = None) -> int:
         """Rewrite the log without tombstoned records; returns live count

@@ -21,6 +21,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from ..common import telemetry
 from ..controller import Algorithm, DataSource, Engine, EngineFactory, Params, SanityCheck
 from ..data.storage.bimap import BiMap
 from ..data.store.p_event_store import PEventStore
@@ -60,6 +61,39 @@ class TrainingData(SanityCheck):
 PreparedData = TrainingData
 
 
+def count_pairs(user_idx: np.ndarray, item_idx: np.ndarray,
+                rating: np.ndarray, n_items: int):
+    """The events of each (user, item) pair summed into ONE entry: its
+    value is the sum of the events' ratings (1.0 an event: the number of
+    views). Reference: the template's ``ALSAlgorithm`` maps a view to
+    ``((user, item), 1)`` and ``reduceByKey(_ + _)`` before
+    ``ALS.trainImplicit``, so a pair viewed r times has confidence
+    ``1 + alpha r`` and right-hand side ``(1 + alpha r) y``; r entries of
+    1.0 would give the same gram and ``r (1 + alpha) y``.
+
+    One stable sort of int64 keys; the pairs stay in the order in which
+    each was FIRST seen, and rows are not renumbered, so the ``BiMap``s
+    stand. Events without a repeated pair come back as they are (all-ones
+    data still trains through ``binary_ratings``). Span
+    ``prep.pair_counts`` (tags ``events``, ``pairs``)."""
+    with telemetry.span("prep.pair_counts", events=len(user_idx)) as sp:
+        key = user_idx.astype(np.int64) * np.int64(n_items) + item_idx
+        order = np.argsort(key, kind="stable")
+        key = key[order]
+        starts = np.ones(len(key), bool)
+        starts[1:] = key[1:] != key[:-1]
+        at = np.nonzero(starts)[0]
+        sp.tag(pairs=len(at))
+        if len(at) == len(key):
+            return user_idx, item_idx, rating
+        sums = np.add.reduceat(rating[order], at).astype(np.float32)
+        # the stable sort puts a pair's first event first in its group
+        first = order[at]
+        by_first = np.argsort(first)
+        first = first[by_first]
+        return user_idx[first], item_idx[first], sums[by_first]
+
+
 @dataclasses.dataclass(frozen=True)
 class DataSourceParams(Params):
     app_name: str = ""
@@ -88,6 +122,9 @@ class SimilarProductDataSource(DataSource):
                 app_name, event_names=list(p.event_names),
                 rating_from_props=False, storage=storage,
                 channel_name=ctx.channel_name, feed_ctx=feed_ctx)
+            # this worker's partitions only: a pair whose events lie with
+            # two workers stays two entries (ROADMAP R-M14)
+            u, i, r = count_pairs(u, i, r, len(items))
             cats = {
                 item_id: set(c)
                 for item_id, props in train_feed.partition_properties(
@@ -104,6 +141,7 @@ class SimilarProductDataSource(DataSource):
             storage=storage,
             channel_name=ctx.channel_name,
         )
+        u, i, r = count_pairs(u, i, r, len(items))
         cats: dict[str, set[str]] = {}
         for item_id, pm in PEventStore.aggregate_properties(
             app_name, p.item_entity_type, storage=storage

@@ -1040,6 +1040,9 @@ def train_als(
                     fast_put(np.asarray(b), sh)
                     for b, sh in zip(flat, in_shardings[3:]))
     chunk = checkpoint_hook.every_n if checkpoint_hook is not None and checkpoint_hook.enabled else 0
+    # which device path the dispatches run: Hu-Koren-Volinsky or explicit,
+    # with the value slabs or without them
+    loop_tags = {"implicit": bool(params.implicit_prefs), "binary": binary}
     if nan_guard:
         # Sanitizer tier: one dispatch per iteration + a device-side
         # finite reduction (ONE scalar fetched per iteration, not the
@@ -1052,7 +1055,7 @@ def train_als(
         x, y = x0, y0
         for it in range(start_iter, params.num_iterations):
             fault_point("train.sweep")
-            with telemetry.span("als.loop"):
+            with telemetry.span("als.loop", **loop_tags):
                 x, y = run_fn(np.int32(1), x, y, *run_args)
                 # Beat AFTER the dispatch: the first sweep includes the
                 # XLA compile, and the supervisor's stall detector only
@@ -1091,7 +1094,7 @@ def train_als(
         while it < params.num_iterations:
             fault_point("train.sweep")
             n = min(chunk, params.num_iterations - it)
-            with telemetry.span("als.loop"):
+            with telemetry.span("als.loop", **loop_tags):
                 x, y = run_fn(n, x, y, *run_args)
                 gang.beat()  # after the dispatch: sweep 1 includes compile
                 jax.block_until_ready((x, y))  # the save would wait anyway
@@ -1105,7 +1108,7 @@ def train_als(
                 if gang.drain_requested_global():
                     raise gang.GangDrainRequested(it)
     else:
-        with telemetry.span("als.loop"):
+        with telemetry.span("als.loop", **loop_tags):
             x, y = run_fn(params.num_iterations - start_iter, x0, y0,
                           *run_args)
             gang.beat()

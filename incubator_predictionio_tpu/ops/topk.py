@@ -303,9 +303,20 @@ def normalize_rows(x) -> np.ndarray:
     deploy/warm-up time: per-query catalog normalization was O(N·rank)
     wasted work, and device-side norm reductions vary bitwise with the
     row count at small shapes, which would break the sharded-catalog
-    bit-identity guarantee (ops/sharded_topk.py)."""
+    bit-identity guarantee (ops/sharded_topk.py).
+
+    Each row over its own norm, taken in float64; a zero row stays zero
+    (the reference's cosine answers 0 for a zero vector). No additive
+    epsilon: implicit ALS shrinks the rows of an item that only
+    one-item users view by orders of magnitude a sweep (norms of 1e-9
+    after five), and ``x / (norm + 1e-9)`` scored such an item at a
+    fraction of its cosine."""
     x = np.asarray(x, np.float32)
-    return x / (np.linalg.norm(x, axis=1, keepdims=True) + 1e-9)
+    norm = np.sqrt(np.einsum("ij,ij->i", x, x, dtype=np.float64))
+    # a reciprocal that float32 cannot hold is a zero row's
+    with np.errstate(divide="ignore"):
+        inv = np.where(norm > np.finfo(np.float32).tiny, 1.0 / norm, 0.0)
+    return x * inv.astype(np.float32)[:, None]
 
 
 def similar_items(query_vecs, item_factors_normed, k: int, exclude=None):

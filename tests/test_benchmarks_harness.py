@@ -48,6 +48,18 @@ RED = {
         "appended after them, and a model_config PR may edit no file the "
         "benchmark has: the next benchmark PR turns the positions into "
         "membership",
+    ("test_eventlog_deployment",
+     "test_the_cell_went_in_by_files_and_appended_entries"):
+        "pins PR 36's four event_store metrics to ONE cell (== [CELL]); "
+        "ISSUE 40 has its cell appended to every metric that lists "
+        "retrain-electronics-eventlog (dase.read_s must read in the "
+        "Similar-Product cell), and a model_config PR may edit no file the "
+        "benchmark has: the next benchmark PR turns == [CELL] into `in`",
+    ("test_init_wait", "test_the_manifest_names_the_two_als_cells"):
+        "pins als.init_wait_s to the two ALS cells of PR 39 (== CELLS); "
+        "ISSUE 40's cell runs the same train_als front and is appended, "
+        "and a model_config PR may edit no file the benchmark has: the "
+        "next benchmark PR turns the equality into a subset",
 }
 
 
