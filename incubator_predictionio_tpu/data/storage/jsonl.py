@@ -327,7 +327,8 @@ class _LogScan:
         mask = np.ones(n, bool)
         ids = cols.event_id
         n_with_id = int((ids >= 0).sum())
-        if n and len(cols.table(ColumnarEvents.TABLE_EVENT_ID)) < n_with_id:
+        n_codes = cols.table_size(ColumnarEvents.TABLE_EVENT_ID)
+        if n and n_codes < n_with_id:
             # duplicates exist: keep last occurrence of each code
             rev_ids = ids[::-1]
             _, first_in_rev = np.unique(rev_ids, return_index=True)
@@ -337,7 +338,6 @@ class _LogScan:
             mask &= keep
         if self.tombstones or self.skip_kills:
             index = self.eid_index()
-            n_codes = len(cols.table(ColumnarEvents.TABLE_EVENT_ID))
             last_ts = np.full(n_codes + 1, -1, np.int64)
             # Snapshot: a concurrent delete_batch may grow the dict.
             # skip_kills replay keep-last dedup against records that
@@ -524,8 +524,9 @@ def aggregate_replay(
         else:  # $delete
             state.pop(c, None)
 
-    eid_table = cols.table(ColumnarEvents.TABLE_EID)
-    return {eid_table[c]: v for c, v in state.items()}
+    # the survivors' ids alone are made strings, not the whole table
+    return dict(zip(cols.strings(ColumnarEvents.TABLE_EID, list(state)),
+                    state.values()))
 
 
 def _fsync_enabled() -> bool:

@@ -15,6 +15,7 @@ from typing import Iterator, Optional, Sequence
 import numpy as np
 
 from ...common import telemetry
+from ...native import IdTable
 from ..storage.bimap import BiMap
 from ..storage.datamap import PropertyMap
 from ..storage.event import Event
@@ -237,27 +238,38 @@ class PEventStore:
                 else:
                     r = np.full(keep.shape, default_rating, np.float32)
 
-            def densify(codes: np.ndarray, table: list[str]):
-                uniq, first_pos, inv = np.unique(
-                    codes, return_index=True, return_inverse=True
-                )
-                order = np.argsort(first_pos, kind="stable")
-                rank = np.empty(order.shape, np.int64)
-                rank[order] = np.arange(order.shape[0])
-                bimap = BiMap({table[c]: int(k)
-                               for k, c in enumerate(uniq[order])})
-                return rank[inv], bimap
+            def densify(codes: np.ndarray, which: int):
+                """Codes to dense rows in first-seen order, and the
+                map of the rows' ids. A code is bounded by its table's
+                size, so no sort of the events: the reversed scatter
+                leaves every code its FIRST position (of a repeated
+                index the last assignment stays), and only the codes
+                present are sorted, by that position."""
+                first = np.full(cols.table_size(which), -1, np.int64)
+                first[codes[::-1]] = np.arange(len(codes) - 1, -1, -1)
+                present = np.nonzero(first >= 0)[0]
+                row_codes = present[np.argsort(first[present])]
+                rank = np.empty(first.shape, np.int32)
+                rank[row_codes] = np.arange(row_codes.shape[0],
+                                            dtype=np.int32)
+                ids = cols.take(which, row_codes)
+                arrays = isinstance(ids, IdTable)
+                return rank[codes], arrays, BiMap(
+                    ids if arrays else {s: k for k, s in enumerate(ids)})
 
             # span store.index: codes to dense rows in first-seen
-            # order and the string BiMaps of both sides
+            # order and the id maps of both sides; ``ids`` says what
+            # backs the maps: the codec's arrays, or strings (tables
+            # that were lists: a snapshot, an extended scan)
             with telemetry.span("store.index") as sp:
-                u_all, users = densify(cols.eid[rows],
-                                       cols.table(cols.TABLE_EID))
+                u_all, u_arrays, users = densify(
+                    cols.eid[rows], cols.TABLE_EID)
                 u = u_all[keep_mask]
-                i, items = densify(cols.teid[keep],
-                                   cols.table(cols.TABLE_TEID))
-                u, i = u.astype(np.int32), i.astype(np.int32)
-                sp.tag(users=len(users), items=len(items))
+                i, i_arrays, items = densify(
+                    cols.teid[keep], cols.TABLE_TEID)
+                sp.tag(users=len(users), items=len(items),
+                       ids="arrays" if u_arrays and i_arrays
+                       else "strings")
             return u, i, r, users, items
 
         batch = PEventStore.find_batch(

@@ -29,7 +29,9 @@ from typing import Optional
 
 import numpy as np
 
-_EXPECTED_VERSION = 19
+from ..common import telemetry
+
+_EXPECTED_VERSION = 20
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -171,6 +173,16 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.POINTER(ctypes.c_float),   # flat_vals
         ctypes.c_int64,                   # total
     ]
+    lib.pio_take_strings.restype = ctypes.c_int32
+    lib.pio_take_strings.argtypes = [
+        ctypes.c_char_p,                  # the table's blob
+        ctypes.POINTER(ctypes.c_int64),   # its offsets [size + 1]
+        ctypes.c_int64,                   # size
+        ctypes.POINTER(ctypes.c_int64),   # codes [n]
+        ctypes.c_int64,                   # n
+        ctypes.POINTER(ctypes.c_uint8),   # out blob
+        ctypes.POINTER(ctypes.c_int64),   # out offsets [n + 1]
+    ]
     lib.pio_tfidf_tf.restype = ctypes.c_int32
     lib.pio_tfidf_tf.argtypes = [
         ctypes.c_char_p,                  # concatenated utf-8 docs
@@ -281,6 +293,72 @@ def available() -> bool:
         return False
 
 
+_M_ID_STRINGS = telemetry.registry().counter(
+    "pio_store_id_strings_total",
+    "Python strings decoded out of the id tables of a log scan's columns, "
+    "by table (event, entityType, entityId, targetEntityType, "
+    "targetEntityId, eventId); a table that is a list already decodes "
+    "nothing", ("table",))
+
+_TABLE_NAMES = ("event", "entityType", "entityId", "targetEntityType",
+                "targetEntityId", "eventId")
+
+
+class IdTable:
+    """An id table as the codec hands it over: the strings' utf-8 bytes
+    end to end in ``blob`` and ``size + 1`` int64 end offsets in
+    ``offs``. A string is made when somebody asks for that id; the
+    object pickles as its two buffers."""
+
+    __slots__ = ("blob", "offs")
+
+    def __init__(self, blob: bytes, offs: np.ndarray) -> None:
+        self.blob = blob
+        self.offs = offs
+
+    def __reduce__(self):
+        return IdTable, (self.blob, self.offs)
+
+    def __len__(self) -> int:
+        return len(self.offs) - 1
+
+    def __getitem__(self, k: int) -> str:
+        return self.blob[self.offs[k]:self.offs[k + 1]].decode("utf-8")
+
+    def strings(self, codes) -> list[str]:
+        """The strings of the given codes, in their order."""
+        codes = np.asarray(codes, np.int64)
+        blob, offs = self.blob, self.offs
+        return [blob[s:e].decode("utf-8") for s, e in zip(
+            offs[codes].tolist(), offs[codes + 1].tolist())]
+
+    def tolist(self) -> list[str]:
+        """Every string, in code order."""
+        blob, ends = self.blob, self.offs.tolist()
+        text = blob.decode("utf-8")
+        if len(text) == len(blob):  # pure ASCII: str slicing == byte slicing
+            return [text[s:e] for s, e in zip(ends, ends[1:])]
+        return [blob[s:e].decode("utf-8") for s, e in zip(ends, ends[1:])]
+
+    def take(self, codes) -> "IdTable":
+        """The table of the given codes, in their order (a copy of byte
+        ranges in the codec, which made this table)."""
+        lib = _load()
+        codes = np.ascontiguousarray(codes, np.int64)
+        offs = np.zeros(len(codes) + 1, np.int64)
+        np.cumsum(self.offs[codes + 1] - self.offs[codes], out=offs[1:])
+        out = np.empty(int(offs[-1]), np.uint8)
+        p64 = ctypes.POINTER(ctypes.c_int64)
+        rc = lib.pio_take_strings(
+            self.blob, self.offs.ctypes.data_as(p64), len(self),
+            codes.ctypes.data_as(p64), len(codes),
+            out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+            offs.ctypes.data_as(p64))
+        if rc != 0:
+            raise ValueError(f"take: code outside the table (error {rc})")
+        return IdTable(out.tobytes(), offs)
+
+
 @dataclass
 class ColumnarEvents:
     """Interned columnar view of an event log scan.
@@ -293,9 +371,12 @@ class ColumnarEvents:
     the full JSON. ``tombstone_pos[i]`` = how many event records precede
     tombstone i (deletes are positional: later re-inserts are live).
 
-    String tables are materialized lazily per table via ``table(which)`` —
-    the eventId table of a big scan is as large as the scan itself, and the
-    training fast path never touches it.
+    A table is an :class:`IdTable` (the codec's) or a list of strings
+    (the Python parser's, a snapshot's, an extended scan's).
+    ``table_size`` and ``strings`` read either form and make no string
+    nobody asked for; ``table(which)`` turns the whole table into a
+    list once — the eventId table of a big scan is as large as the scan
+    itself, and the training fast path never touches it.
     """
 
     raw: bytes
@@ -309,8 +390,7 @@ class ColumnarEvents:
     rating: np.ndarray
     props: np.ndarray  # (n, 2) int64
     span: np.ndarray  # (n, 2) int64
-    # per table: (concatenated utf-8 blob, size+1 end-offsets) or the
-    # already-built list
+    # per table: an IdTable or the already-built list
     _tables: list
     tombstones: list[str]
     tombstone_pos: np.ndarray  # int64, record count before each tombstone
@@ -328,15 +408,31 @@ class ColumnarEvents:
         t = self._tables[which]
         if isinstance(t, list):
             return t
-        blob, offs = t
-        size = len(offs) - 1
-        text = blob.decode("utf-8")
-        if len(text) == len(blob):  # pure ASCII: str slicing == byte slicing
-            out = [text[offs[k]:offs[k + 1]] for k in range(size)]
-        else:
-            out = [blob[offs[k]:offs[k + 1]].decode("utf-8") for k in range(size)]
+        out = t.tolist()
+        _M_ID_STRINGS.labels(_TABLE_NAMES[which]).inc(len(out))
         self._tables[which] = out
         return out
+
+    def table_size(self, which: int) -> int:
+        return len(self._tables[which])
+
+    def strings(self, which: int, codes) -> list[str]:
+        """The strings of the given codes of one table, in their order."""
+        t = self._tables[which]
+        if isinstance(t, list):
+            return [t[c] for c in np.asarray(codes).tolist()]
+        out = t.strings(codes)
+        _M_ID_STRINGS.labels(_TABLE_NAMES[which]).inc(len(out))
+        return out
+
+    def take(self, which: int, codes):
+        """The ids of the given codes of one table, in their order, in
+        the table's own form: an :class:`IdTable` of a table that is
+        one, else a list of strings."""
+        t = self._tables[which]
+        if isinstance(t, IdTable):
+            return t.take(codes)
+        return self.strings(which, codes)
 
     @property
     def tables(self) -> list[list[str]]:
@@ -397,13 +493,13 @@ def parse_events_jsonl(buf: bytes,
         for which in range(6):
             size = lib.pio_table_size(handle, which)
             if size == 0:
-                tables.append([])
+                tables.append(IdTable(b"", np.zeros(1, np.int64)))
                 continue
             blob_len = ctypes.c_int64(0)
             blob_ptr = lib.pio_table_blob(handle, which, ctypes.byref(blob_len))
             blob = ctypes.string_at(blob_ptr, blob_len.value)
             offs = _np_copy(lib.pio_table_offsets(handle, which), size + 1, np.int64)
-            tables.append((blob, offs))
+            tables.append(IdTable(blob, offs))
         tombstones = []
         ln = ctypes.c_int32(0)
         n_tomb = lib.pio_tombstone_count(handle)

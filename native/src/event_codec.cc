@@ -905,7 +905,7 @@ extern "C" {
 
 // Bump when the ABI or semantics change — the Python wrapper rebuilds the
 // cached .so when this does not match its expected version.
-int32_t pio_codec_version() { return 19; }
+int32_t pio_codec_version() { return 20; }
 
 namespace {
 // FNV-1a over a byte range, continuing from a running state.
@@ -1083,6 +1083,26 @@ int32_t pio_fill_entries(
     if (dest < 0 || dest >= total) return -2;
     flat_cols[dest] = static_cast<int32_t>(col_slot_map[c]);
     if (flat_vals != nullptr) flat_vals[dest] = val[i];
+  }
+  return 0;
+}
+
+// Row order for an id table (native.IdTable.take): the strings of
+// `codes`, in that order, copied out of a table's blob. The caller has
+// summed the lengths into `out_offs` (n + 1 entries) and sized `out_blob`
+// by the last of them; this is the copy of byte ranges, which NumPy can
+// only do as a gather of single bytes. Returns 0 on success, -1 code
+// outside [0, size), -2 `out_offs` not the lengths' running sum.
+int32_t pio_take_strings(const char* blob, const int64_t* offs, int64_t size,
+                         const int64_t* codes, int64_t n, char* out_blob,
+                         const int64_t* out_offs) {
+  for (int64_t k = 0; k < n; ++k) {
+    const int64_t c = codes[k];
+    if (c < 0 || c >= size) return -1;
+    const int64_t len = offs[c + 1] - offs[c];
+    if (out_offs[k + 1] - out_offs[k] != len) return -2;
+    std::memcpy(out_blob + out_offs[k], blob + offs[c],
+                static_cast<size_t>(len));
   }
   return 0;
 }
