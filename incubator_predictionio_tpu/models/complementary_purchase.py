@@ -11,7 +11,9 @@ TPU-native redesign: baskets (user × time-window sessions) take the
 same MXU path the Universal Recommender uses), so mining runs as dense
 [basket-chunk, items]ᵀ×[basket-chunk, items] einsum stripes with
 LLR-thresholded top-k indicators per item, and serving scores a query
-basket on device (gather+dot + top_k, ops/llr.score_user).
+basket on device (the indicators resident as an index by correlator,
+the basket shipped as rows: ops/llr.score_rows, the universal
+recommender's kernel).
 
 DASE shape:
 - DataSource: "buy" events (entity=user, target=item).
@@ -32,7 +34,8 @@ import numpy as np
 from ..controller import Algorithm, Engine, EngineFactory, Params, SanityCheck
 from ..controller.datasource import DataSource
 from ..data.storage.bimap import BiMap
-from ..ops.llr import Indicators, cco_indicators, score_user
+from ..ops.llr import Indicators, cco_indicators, place_indicators, score_rows
+from ..ops.topk import RowExclude
 
 
 @dataclasses.dataclass
@@ -140,29 +143,31 @@ class AlgoParams(Params):
 class ComplementaryModel:
     indicators: Indicators
     items: BiMap
+    _resident: object = dataclasses.field(default=None, repr=False,
+                                          compare=False)
+
+    def resident(self):
+        """The indicators on the device, placed at first use and kept."""
+        if self._resident is None:
+            self._resident = place_indicators({"basket": self.indicators})
+        return self._resident
+
+    def warm_up(self, num: int = 4):
+        if len(self.items):
+            self.suggest([self.items.inverse(0)], num)
 
     def suggest(self, basket_items: Sequence[str], num: int
                 ) -> list[tuple[str, float]]:
-        ids = [self.items.get(x) for x in basket_items]
-        known = [x for x in ids if x is not None]
-        n_items = self.indicators.idx.shape[0]
-        if not known or n_items == 0:
+        known = [j for j in map(self.items.get, basket_items)
+                 if j is not None]
+        if not known or self.indicators.idx.shape[0] == 0:
             return []
-        membership = np.zeros(n_items, np.float32)
-        membership[known] = 1.0
-        exclude = np.zeros(n_items, bool)
-        exclude[known] = True
-        scores, idx = score_user(
-            [(self.indicators, membership, 1.0)],
-            k=min(num + len(known), n_items), exclude=exclude)
-        out = []
-        for s, j in zip(scores, idx):
-            if not np.isfinite(s) or s <= 0:
-                break
-            out.append((self.items.inverse(int(j)), float(s)))
-            if len(out) >= num:
-                break
-        return out
+        rows = np.asarray(known, np.int32)
+        scores, idx, _postings = score_rows(
+            self.resident(), {"basket": rows}, num,
+            exclude=RowExclude(base=None, deny=rows, allow=None))
+        return [(self.items.inverse(int(j)), float(s))
+                for s, j in zip(scores, idx) if np.isfinite(s) and s > 0]
 
 
 class ComplementaryAlgorithm(Algorithm):

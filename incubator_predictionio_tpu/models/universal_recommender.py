@@ -8,10 +8,13 @@ Elasticsearch and queries run as ES boolean similarity queries with
 business rules (category filters/boosts, blacklists, date rules).
 
 TPU-native redesign: ops/llr.py computes the indicators as dense chunked
-MXU matmuls + vectorized G²; the "index" is a static [I, K] correlator
-array on device, and a query is a gather+dot + top_k — no Elasticsearch
-in the serving path. Business-rule filters (categories, white/black
-lists, exclude-purchased) are applied as device masks.
+MXU matmuls + vectorized G²; the served "index" is what Elasticsearch
+held for upstream, an index BY CORRELATOR built at deploy time from the
+persisted [I, K] arrays and resident on the device
+(`ops/llr.place_indicators`). A query ships its history as catalog rows
+and one dispatch sums the postings that name them, applies the business
+rules (categories, blacklist, exclude-purchased: rows and resident
+category masks, `models/_filters.py`) and selects the top k.
 
 Wire format (UR parity, core subset):
   query  {"user": "u1", "num": 4, "fields": [{"name": "categories",
@@ -31,8 +34,11 @@ from ..controller import Algorithm, DataSource, Engine, EngineFactory, Params, S
 from ..data.storage.bimap import BiMap
 from ..data.store.l_event_store import LEventStore
 from ..data.store.p_event_store import PEventStore
-from ..ops.llr import Indicators, cco_indicators_multi, score_user
-from ._filters import CategoryIndex, build_exclude_mask
+from ..ops.llr import (
+    Indicators, ResidentIndicators, cco_indicators_multi, compile_ladders,
+    place_indicators, popular_rows, score_rows,
+)
+from ._filters import CategoryIndex, _rows, build_exclude, split_fields
 
 
 @dataclasses.dataclass
@@ -126,6 +132,7 @@ class URModel:
     _storage: object = dataclasses.field(default=None, repr=False, compare=False)
     _cat_index: object = dataclasses.field(default=None, repr=False, compare=False)
     _date_arrays: object = dataclasses.field(default=None, repr=False, compare=False)
+    _resident: object = dataclasses.field(default=None, repr=False, compare=False)
 
     def category_index(self) -> CategoryIndex:
         if self._cat_index is None:
@@ -165,32 +172,69 @@ class URModel:
             self._date_arrays = (avail, expire, date)
         return self._date_arrays
 
+    def resident(self) -> ResidentIndicators:
+        """The served state on the device: the event types' indicators as
+        one index by correlator and the popularity vector, placed at
+        first use (deploy: `warm_up`) and kept, as
+        `ShardedCatalogServing.catalog` keeps the ALS catalog. Without it
+        every query would upload the model."""
+        if self._resident is None:
+            # a ranking of zeros ranks nothing: no backfill then
+            popular = (self.popularity is not None
+                       and bool(np.any(self.popularity)))
+            self._resident = place_indicators(
+                {name: self.indicators[name] for name in self.event_names
+                 if name in self.indicators},
+                self.popularity if popular else None)
+        return self._resident
+
     def warm_up(self, num: int = 10):
+        """Deploy time: the state resident (indicators, popularity, every
+        category's mask), the ids' forward maps built, and every step of
+        the shape ladders compiled (`ops/llr.compile_ladders`; here the
+        small programs that compose masks and boosts): no query after this
+        builds, ships or compiles anything whose size grows with the
+        catalog."""
+        resident = self.resident()
+        if not resident.n_items:
+            return
+        cats = self.category_index()
+        some = cats.resident_all()[:2]
+        if some:  # the small programs that compose masks and boosts
+            two = [{"values": some, "bias": -1}, {"values": some[:1]}]
+            build_exclude(self.items, cats, fields=two, rows=True)
+            cats.device_boost([(some, 2.0), (some[:1], 2.0)])
+        compile_ladders(resident, num)
         if len(self.users):
             self.recommend(next(iter(self.users.keys())), num)
 
-    def _history(self, user: str) -> dict[str, np.ndarray]:
-        """Realtime user history per event type (reference: UR queries the
-        event store at serve time so new events influence results
-        immediately). One combined store query, bucketed by event name."""
-        n_items = len(self.items)
-        out = {name: np.zeros(n_items, np.float32) for name in self.event_names}
-        try:
-            events = LEventStore.find_by_entity(
-                self.app_name, "user", user,
-                event_names=list(self.event_names),
-                limit=500 * max(len(self.event_names), 1),
-                storage=self._storage,
-            )
-        except Exception:
-            events = []
+    def _history(self, user: str) -> dict[str, list[int]]:
+        """The user's history an event type, as catalog rows, read from
+        the event store NOW (reference: UR queries the event store at
+        serve time so new events influence results immediately; no cache
+        of the store's answers). One combined store query under span
+        ``query.store_read`` (``what=history``), bucketed by event name;
+        targets the catalog does not know are skipped, repeats kept
+        (`ops/llr.score_rows` counts a row once)."""
+        out: dict[str, list[int]] = {name: [] for name in self.event_names}
+        with telemetry.span("query.store_read", what="history") as sp:
+            try:
+                events = LEventStore.find_by_entity(
+                    self.app_name, "user", user,
+                    event_names=list(self.event_names),
+                    limit=500 * max(len(self.event_names), 1),
+                    storage=self._storage,
+                )
+            except Exception:
+                events = []
+            sp.tag(events=len(events))
         for e in events:
-            membership = out.get(e.event)
-            if membership is None or not e.target_entity_id:
+            rows = out.get(e.event)
+            if rows is None or not e.target_entity_id:
                 continue
             j = self.items.get(e.target_entity_id)
             if j is not None:
-                membership[j] = 1.0
+                rows.append(j)
         return out
 
     def _date_exclude(self, current_date: Optional[str],
@@ -233,68 +277,48 @@ class URModel:
         date_range: Optional[dict] = None,
     ):
         """UR query core: user-based, item-based ("similar to these
-        items"), or both (memberships union); cold/unknown users fall
+        items"), or both (the query items join the history's rows of every
+        event type); cold/unknown users fall
         back to the popularity ranking through the SAME filter pipeline
         (reference UR: popModel backfill; item-based and dateRange
         queries per the UR query spec)."""
-        n_items = len(self.items)
         history = (self._history(user) if user is not None
-                   else {n: np.zeros(n_items, np.float32)
-                         for n in self.event_names})
+                   else {n: [] for n in self.event_names})
         # Item-based query: the query items act as history for every
-        # indicator type — _score_history then reads each candidate's
-        # correlator weight against them (the item-similarity column).
-        query_idx = []
-        for q in items or []:
-            j = self.items.get(q)
-            if j is not None:
-                query_idx.append(j)
-        for j in query_idx:
-            for name in self.event_names:
-                history[name][j] = 1.0
+        # indicator type: each candidate's postings then name them with
+        # their correlator weight (the item-similarity column).
+        query_rows = _rows(self.items, items)
+        for rows in history.values():
+            rows.extend(query_rows)
 
-        exclude = build_exclude_mask(
-            self.items, black_list=blacklist_items,
-            extra_excluded_items=items,  # never return the query items
-        )
-        if exclude_primary_history:
-            primary = self.event_names[0]
-            exclude |= history[primary] > 0
-        if current_date or date_range or self.item_dates:
+        # The rules as rows and resident category masks: never return
+        # the query items, the blacklist or (by default) what the user
+        # already has under the primary event; a field with a bias under
+        # 0 filters by its categories, one with a bias of 0 or more
+        # multiplies (composed on the device from the resident masks).
+        # Nothing of the catalog's length is built on the host, except
+        # under item dates, whose rule is still a dense host mask.
+        dated = bool(current_date or date_range or self.item_dates)
+        exclude = build_exclude(
+            self.items, self.category_index(), black_list=blacklist_items,
+            extra_rows=query_rows + (
+                history[self.event_names[0]] if exclude_primary_history
+                else []),
+            fields=fields, rows=not dated)
+        if dated:
             exclude |= self._date_exclude(current_date, date_range)
-        # UR "fields" biz rules: bias<0 = hard filter, bias>0 = boost —
-        # category masks precomputed (CategoryIndex), no per-item loop.
-        boost_vec = np.ones(n_items, np.float32)
-        for f in fields or []:
-            match = self.category_index().any_of(f.get("values", []))
-            bias = float(f.get("bias", -1))
-            if bias < 0:
-                exclude |= ~match
-            else:
-                boost_vec = np.where(match, boost_vec * bias, boost_vec)
+        boost = self.category_index().device_boost(split_fields(fields)[1])
 
-        if not any(m.any() for m in history.values()):
+        resident = self.resident()
+        if not any(history.values()):
             # Cold/unknown user with no query items: popularity-ranked
-            # backfill through the same exclude/boost masks.
-            if self.popularity is None or not np.any(self.popularity):
+            # backfill through the same rules, on the device.
+            if resident.popularity is None:
                 return []
-            scores = np.where(exclude, -np.inf,
-                              self.popularity * boost_vec)
-            order = np.argsort(-scores)[:num]
-            return [
-                (self.items.inverse(int(j)), float(scores[j]))
-                for j in order
-                if np.isfinite(scores[j]) and scores[j] > 0
-            ]
-
-        entries = [
-            (self.indicators[name], history[name], 1.0)
-            for name in self.event_names
-            if name in self.indicators
-        ]
-        scores, idx = score_user(
-            entries, num, exclude=exclude, item_boost=boost_vec
-        )
+            scores, idx = popular_rows(resident, num, exclude, boost)
+        else:
+            scores, idx, _postings = score_rows(
+                resident, history, num, exclude, boost)
         return [
             (self.items.inverse(int(j)), float(s))
             for s, j in zip(scores, idx)

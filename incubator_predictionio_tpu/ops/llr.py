@@ -9,8 +9,8 @@ the MXU — user-interaction matrices are scattered into dense [U_chunk, I]
 slabs on device and C = Σ_chunks A_pᵀ A_s accumulates per primary/secondary
 pair; Dunning's G² LLR is evaluated vectorized over the full count matrix;
 top-k correlators per item are kept as static [I, K] index/score arrays
-(the "index" that replaces Elasticsearch — scoring is then a gather+dot,
-see models/universal_recommender.py).
+(what is persisted; served, they become an index by correlator on the
+device, what Elasticsearch held: the serving section at the end).
 
 Scale notes: events are pre-partitioned by user range on the host (sorted
 slabs, like ops/blocked.py), so each scan step scatters only its own
@@ -34,13 +34,17 @@ from __future__ import annotations
 import dataclasses
 import os
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..common import telemetry
+from .topk import (
+    ROW_LADDER, RowExclude, no_exclude_mask, pack_rows, put_rows,
+    select_block_len, select_topk, suppress_rows,
+)
 
 
 _M_PATH = telemetry.registry().counter(
@@ -841,40 +845,335 @@ def cco_indicators_multi(
         }
 
 
+# -- serving: the indicators resident on the device ---------------------------
+#
+# A query's score is  score_i = boost_i * sum_e sum_s score_e[i, s] *
+# [idx_e[i, s] in history_e].  The forward form of that sum gathers one
+# membership bit for every slot of every item (I x K gathers an event type:
+# 940M for 9.4M items, 50 correlators and two event types, 2.2 s of the
+# gather unit); an index BY CORRELATOR reads, for the rows of the history,
+# only the postings that name them. That index is built once, at deploy
+# time, from the persisted ``idx`` / ``score`` and stays on the device;
+# a query ships its history rows, its rule rows and a few scalars.
+
+_M_UR_QUERIES = telemetry.registry().counter(
+    "pio_ur_queries_total",
+    "Indicator-scored queries by path: history = postings of the history's "
+    "rows summed on the device; backfill = no history row and no query "
+    "item, the popularity ranking under the same rules.",
+    ("path",))
+_M_UR_ROWS = telemetry.registry().counter(
+    "pio_ur_history_rows_total",
+    "History rows (distinct catalog rows an event type, query items "
+    "counted in) that indicator-scored queries shipped to the device."
+    ).labels()
+_M_UR_POSTINGS = telemetry.registry().counter(
+    "pio_ur_postings_read_total",
+    "Postings (item, score) that indicator-scored queries read on the "
+    "device: the entries of the index that name a row of the history."
+    ).labels()
+
+#: history rows an event type a query is padded to, smallest first (the
+#: store's read hands back at most 500 events an event type; ``itemSet``
+#: adds its own): one executable a step, whatever the history's length.
+#: Longer histories take the next power of two.
+_HISTORY_LADDER = (16, 128, 1024)
+#: ``num`` is rounded up to a step of this ladder (then powers of two), so
+#: that clients varying ``num`` share executables.
+_NUM_LADDER = (8, 32)
+#: how a run of postings is read (my chip run, PR 42: a scalar gather
+#: from a 1.88 GB array costs 15-23 ns, a scatter-add 9-10 ns, a
+#: contiguous slice nothing). The first `_RUN_HEAD` postings of every run
+#: are gathered, all runs at once: most runs are shorter (a correlator is
+#: named by 50 rows on average, by 6 in the median). What a run holds
+#: beyond them is read as contiguous slices of `_RUN_CHUNK` postings, one a
+#: loop step.
+_RUN_HEAD = 128
+_RUN_CHUNK = 16384
+
+
+class ResidentIndicators(NamedTuple):
+    """The served state of a set of indicators, on the device: per event
+    type (``names``, in scoring order) ONE form, an index by correlator.
+    ``post_item[e]`` int32 / ``post_score[e]`` float32 hold the event
+    type's (item, score) postings sorted by correlator, then by item (the
+    slots of a padded row, ``idx < 0``, lie behind the last run and no run
+    reaches them); ``offsets[e]`` int32 ``[n_items + 2]`` holds where the
+    run of each correlator starts: run c is ``[offsets[c], offsets[c +
+    1])``, and the run of the row ``n_items``, which pads a history, is
+    empty. ``popularity`` is the float32 backfill ranking, or None."""
+    names: tuple
+    n_items: int
+    post_item: tuple
+    post_score: tuple
+    offsets: tuple
+    popularity: Optional[jax.Array]
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(a.nbytes) for a in (
+            *self.post_item, *self.post_score, *self.offsets,
+            self.popularity) if a is not None)
+
+
+@functools.partial(jax.jit, static_argnames=("n_items", "k"),
+                   donate_argnums=(0, 1))
+def _index_by_correlator(idx, score, n_items: int, k: int):
+    """One event type's forward slots (flat ``[n_items * k]``, donated)
+    as (items, scores, offsets) of `ResidentIndicators`: ONE device sort
+    by (correlator, item), and the runs' lengths counted."""
+    keys = jnp.where((idx >= 0) & (idx < n_items), idx, n_items)
+    counts = jnp.zeros((n_items + 1,), jnp.int32).at[keys].add(1)
+    keys, items, scores = jax.lax.sort(
+        (keys, jnp.arange(idx.shape[0], dtype=jnp.int32) // k, score),
+        num_keys=2)
+    del keys
+    starts = jnp.cumsum(counts) - counts
+    # run n_items (the history's padding row) is empty: it starts and ends
+    # where the padded slots begin
+    return items, scores, jnp.concatenate([starts, starts[-1:]])
+
+
+def place_indicators(indicators, popularity=None) -> ResidentIndicators:
+    """Put ``indicators`` ({event name: `Indicators`}, in scoring order) on
+    the device as an index by correlator, once (deploy / warm-up): an
+    event type's forward slots cross and are sorted there, so the host
+    sorts nothing. The sort holds three times its operands (11.3 GB for
+    9.4M items x 50), so each event type's index waits on the host while
+    the next is sorted and goes back at the end; the last one's never
+    leaves: the device never holds both forms of the model, nor a sort
+    beside an index. Span ``ur.place`` (tags ``events``, ``items``,
+    ``bytes``)."""
+    names = tuple(indicators)
+    n_items = int(next(iter(indicators.values())).idx.shape[0])
+    with telemetry.span("ur.place", events=len(names),
+                        items=n_items) as sp:
+        post_item, post_score, offsets = [], [], []
+        for at, ind in enumerate(indicators.values()):
+            rows, k = ind.idx.shape
+            if rows != n_items:
+                raise ValueError("indicators of one model index one catalog")
+            if rows * k >= 2 ** 31:
+                raise ValueError(f"{rows * k} indicator slots are over what "
+                                 f"an int32 posting offset addresses")
+            idx = np.asarray(ind.idx, np.int32).ravel()
+            score = np.asarray(ind.score, np.float32).ravel()
+            if idx.size < _RUN_CHUNK:
+                # the tail's slices are `_RUN_CHUNK` long whatever the
+                # index holds: a small model's arrays are filled up to one
+                fill = (0, _RUN_CHUNK - idx.size)
+                idx = np.pad(idx, fill, constant_values=-1)
+                score = np.pad(score, fill)
+            placed = _index_by_correlator(idx, score, n_items=n_items,
+                                          k=max(k, 1))
+            # either way the sort has ended before anything else is put
+            items, scores, off = (jax.block_until_ready(placed)
+                                  if at == len(names) - 1
+                                  else jax.device_get(placed))
+            post_item.append(items)
+            post_score.append(scores)
+            offsets.append(off)
+        resident = ResidentIndicators(names, n_items, *jax.device_put((
+            tuple(post_item), tuple(post_score), tuple(offsets),
+            None if popularity is None
+            else np.asarray(popularity, np.float32))))
+        jax.block_until_ready(resident.offsets)
+        sp.tag(bytes=resident.nbytes)
+    return resident
+
+
 @functools.partial(jax.jit, static_argnames=("k",))
-def _score_history(idx, score, membership, boost, k: int):
-    """score_i = Σ_slots score[i,s]·membership[idx[i,s]] (gather+dot) —
-    the ES similarity query replacement. membership: [I] 0/1 vector of the
-    user's history for this event type."""
-    m = jnp.where(idx >= 0, membership[jnp.maximum(idx, 0)], 0.0)
-    s = jnp.einsum("ik,ik->i", score, m) * boost
-    return s
+def _ur_rank(scores, boost, base, rows, k: int):
+    """The query's rules over a score row and the k best of what is left:
+    ``boost`` multiplies, ``base`` (True = suppressed) and the packed
+    ``rows`` of `ops/topk.suppress_rows` exclude, and only scores over 0
+    are answers (the others come back as -inf). One executable a (``k``,
+    rule-row step), whatever scored the row: the history's postings or
+    the popularity."""
+    scores = jnp.where(base, -jnp.inf, scores * boost)
+    scores = suppress_rows(scores, rows)
+    scores = jnp.where(scores > 0, scores, -jnp.inf)
+    block_len = select_block_len(scores.shape[0], k)
+    if block_len:
+        return select_topk(scores, k, block_len)
+    return jax.lax.top_k(scores, k)
 
 
-def score_user(
-    indicator_list: list[tuple[Indicators, np.ndarray, float]],
-    k: int,
-    exclude: Optional[np.ndarray] = None,
-    item_boost: Optional[np.ndarray] = None,
-):
-    """Combine per-event-type indicator scores for one user's history.
+def _add_runs(acc, post_item, post_score, offsets, history):
+    """``acc`` plus the postings of the runs of ``history`` (int32 ``[H]``
+    rows of one event type, padded with the row ``n_items``), and how
+    many they were. The first `_RUN_HEAD` postings of every run in ONE
+    gather and scatter-add; the rest of the long runs slice by slice,
+    `_RUN_CHUNK` postings a loop step, which a step's place among the
+    runs' summed slice counts names (no run of no further posting takes
+    a step)."""
+    starts = offsets[history]
+    lens = offsets[history + 1] - starts
+    j = jnp.arange(_RUN_HEAD, dtype=jnp.int32)[None, :]
+    live = j < lens[:, None]
+    source = jnp.where(live, starts[:, None] + j, 0).ravel()
+    acc = acc.at[post_item[source]].add(
+        jnp.where(live.ravel(), post_score[source], 0.0))
 
-    indicator_list: [(indicators, membership [I] f32, boost)] per event
-    type. ``item_boost`` [I] multiplies scores BEFORE top-k so boosted
-    items can enter the result set. Returns (scores[k], idx[k]) host
-    arrays.
-    """
-    total = None
-    for ind, membership, boost in indicator_list:
-        s = _score_history(
-            jnp.asarray(ind.idx), jnp.asarray(ind.score),
-            jnp.asarray(membership), jnp.float32(boost), ind.idx.shape[1],
-        )
-        total = s if total is None else total + s
-    if item_boost is not None:
-        total = total * jnp.asarray(item_boost, total.dtype)
-    if exclude is not None:
-        total = jnp.where(jnp.asarray(exclude), -jnp.inf, total)
-    kk = min(k, total.shape[0])
-    out = jax.lax.top_k(total, kk)
-    return jax.device_get(out)
+    steps = (jnp.maximum(lens - _RUN_HEAD, 0) + _RUN_CHUNK - 1) // _RUN_CHUNK
+    ends = jnp.cumsum(steps)
+    lane = jnp.arange(_RUN_CHUNK, dtype=jnp.int32)
+    last = post_item.shape[0] - _RUN_CHUNK
+
+    def step(t, acc):
+        run = jnp.sum(ends <= t)
+        done = _RUN_HEAD + (t - (ends[run] - steps[run])) * _RUN_CHUNK
+        lo = starts[run] + done
+        at = jnp.minimum(lo, last)       # a slice never passes the end
+        live = (lane >= lo - at) & (lane < lo - at + lens[run] - done)
+        items = jax.lax.dynamic_slice(post_item, (at,), (_RUN_CHUNK,))
+        scores = jax.lax.dynamic_slice(post_score, (at,), (_RUN_CHUNK,))
+        return acc.at[jnp.where(live, items, 0)].add(
+            jnp.where(live, scores, 0.0))
+
+    return jax.lax.fori_loop(0, ends[-1], step, acc), jnp.sum(lens)
+
+
+@functools.partial(jax.jit, static_argnames=("n_items",))
+def _ur_score(post_item, post_score, offsets, history, n_items: int):
+    """``history`` int32 ``[event types, H]`` (padded with the row
+    ``n_items``): the postings that name a row of an event type's history
+    summed into one float32 score an item (`_add_runs`, an event type
+    after the other). Returns (scores[n_items], postings read). One
+    executable a step of `_HISTORY_LADDER`."""
+    acc = jnp.zeros((n_items,), jnp.float32)
+    total = jnp.int32(0)
+    for e in range(history.shape[0]):
+        acc, n = _add_runs(acc, post_item[e], post_score[e], offsets[e],
+                           history[e])
+        total += n
+    return acc, total
+
+
+@functools.lru_cache(maxsize=None)
+def _no_boost(n_items: int):
+    """Device-resident all-ones boost, one per catalog size."""
+    return jax.device_put(np.ones((n_items,), np.float32))
+
+
+@functools.lru_cache(maxsize=None)
+def _no_rows(n_items: int):
+    """Device-resident packed row lists that suppress nothing."""
+    return jax.device_put(pack_rows((), None, ROW_LADDER[0], n_items))
+
+
+def _step_of(ladder: tuple, n: int) -> int:
+    """The first step of ``ladder`` that holds ``n``, or the power of two
+    that does where ``n`` is over the ladder's top."""
+    return next((s for s in ladder if n <= s),
+                1 << max(int(n) - 1, 0).bit_length())
+
+
+def _rules_on_device(exclude, boost, n_items: int):
+    """(boost, base, rows) as the kernels take them. ``exclude`` is what
+    `models/_filters.build_exclude` makes: None, a `RowExclude` (its rows
+    are packed and put under ``topk.mask_put``, as for the ALS scan), or
+    a dense ``bool[n_items]`` host mask (lists over the row ladder's top,
+    item dates), put whole under the same span."""
+    base, rows = None, _no_rows(n_items)
+    if isinstance(exclude, RowExclude):
+        base = exclude.base
+        if len(exclude.deny) or exclude.allow is not None:
+            rows = put_rows(exclude, n_items)
+    elif exclude is not None:
+        with telemetry.span("topk.mask_put", bytes=exclude.nbytes):
+            base = jax.device_put(np.asarray(exclude, bool))
+    return (_no_boost(n_items) if boost is None else boost,
+            no_exclude_mask(n_items) if base is None else base, rows)
+
+
+def _rank(scores, rules, k: int):
+    """`_ur_rank` of a score row at ``k``'s step of `_NUM_LADDER`."""
+    return _ur_rank(scores, *rules,
+                    k=min(_step_of(_NUM_LADDER, k), scores.shape[0]))
+
+
+def _fetch(out, k: int):
+    """The answer on the host, cut to ``k``. Span ``topk.wait``: the
+    device's queue, the kernel and the readback, as after the ALS scan."""
+    with telemetry.span("topk.wait"):
+        out = jax.device_get(out)
+    return (out[0][:k], out[1][:k]) + tuple(out[2:])
+
+
+def score_rows(resident: ResidentIndicators, history, k: int,
+               exclude=None, boost=None):
+    """The k best items for a history, by the resident indicators:
+    ``history`` {event name: catalog rows} (an event type the indicators
+    lack counts nothing; duplicates count once), ``exclude`` as
+    `_rules_on_device` takes it, ``boost`` a resident ``float32[n_items]``
+    multiplier or None. Returns (scores[k'], items[k'], postings read) as
+    host values, best first; a place beyond the items that scored over 0
+    holds -inf.
+
+    Two dispatches, `_ur_score` (the sums) then `_ur_rank` (the rules and
+    the selection), and one fetch. Spans ``ur.score`` (tags ``rows``,
+    ``step``, ``k``, ``postings``) around ``ur.dispatch`` (both enqueues;
+    a first shape's compile shows here) and ``topk.wait``; counters
+    ``pio_ur_queries_total{path}``, ``pio_ur_history_rows_total``,
+    ``pio_ur_postings_read_total``."""
+    n_items = resident.n_items
+    k = min(int(k), n_items)
+    lists = [np.unique(np.asarray(history.get(name, ()), np.int32))
+             for name in resident.names]
+    n_rows = sum(map(len, lists))
+    step = _step_of(_HISTORY_LADDER, max(map(len, lists)))
+    packed = np.full((len(lists), step), n_items, np.int32)
+    for e, rows_e in enumerate(lists):
+        packed[e, :len(rows_e)] = rows_e
+    with telemetry.span("ur.score", rows=n_rows, step=step, k=k) as sp:
+        rules = _rules_on_device(exclude, boost, n_items)
+        with telemetry.span("ur.dispatch"):
+            acc, postings = _ur_score(
+                resident.post_item, resident.post_score, resident.offsets,
+                packed, n_items=n_items)
+            out = _rank(acc, rules, k)
+        scores, items, postings = _fetch((*out, postings), k)
+        sp.tag(postings=int(postings))
+    _M_UR_QUERIES.labels("history").inc()
+    _M_UR_ROWS.inc(n_rows)
+    _M_UR_POSTINGS.inc(int(postings))
+    return scores, items, int(postings)
+
+
+def popular_rows(resident: ResidentIndicators, k: int, exclude=None,
+                 boost=None):
+    """The k most popular items under the same rules (the backfill of a
+    query without history): (scores[k'], items[k']). Span ``ur.backfill``
+    around ``ur.dispatch`` and ``topk.wait``."""
+    n_items = resident.n_items
+    k = min(int(k), n_items)
+    with telemetry.span("ur.backfill", k=k):
+        rules = _rules_on_device(exclude, boost, n_items)
+        with telemetry.span("ur.dispatch"):
+            out = _rank(resident.popularity, rules, k)
+        scores, items = _fetch(out, k)
+    _M_UR_QUERIES.labels("backfill").inc()
+    return scores, items
+
+
+def compile_ladders(resident: ResidentIndicators, num: int) -> None:
+    """Deploy time (a model's ``warm_up``): every executable a query can
+    meet, compiled by running it once. The sums, a step of
+    `_HISTORY_LADDER` each; the rules and the selection, a step of
+    `_NUM_LADDER` (and ``num``'s own) by a step of the rule rows'
+    `ops/topk.ROW_LADDER` each, which the backfill shares."""
+    def history(step):
+        rows = np.arange(min(step, resident.n_items), dtype=np.int32)
+        return dict.fromkeys(resident.names, rows)
+
+    for step in _HISTORY_LADDER:
+        score_rows(resident, history(step), num)
+    for k in sorted({*_NUM_LADDER, _step_of(_NUM_LADDER, num)}):
+        for rule_rows in ROW_LADDER:
+            # a list of this step's shape: one row over the step below
+            exclude = RowExclude(
+                None, np.zeros(rule_rows // 8 + 1, np.int32), None)
+            score_rows(resident, history(1), k, exclude)
+            if resident.popularity is not None:
+                popular_rows(resident, k, exclude)

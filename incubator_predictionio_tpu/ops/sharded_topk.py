@@ -12,7 +12,7 @@ merges them with a two-key lexicographic sort that reproduces single-device
 ``lax.top_k`` semantics bit-for-bit (ties break toward the lowest global
 index, exactly as ``lax.top_k`` does). That stays true now that the flat
 single-device path no longer calls ``lax.top_k`` on a large row:
-``ops/topk._select_topk`` returns ``lax.top_k``'s values and indices by
+``ops/topk.select_topk`` returns ``lax.top_k``'s values and indices by
 construction (its docstring has the argument) and ends in this same
 two-key sort, so "what ``lax.top_k`` gives on the unsharded row" is still
 the one contract both layouts (flat on one device, this mesh one) meet.

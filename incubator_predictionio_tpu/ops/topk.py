@@ -10,13 +10,20 @@ JSON parsing only.
 `lax.top_k` of a whole score row is a full stable sort of the row (21 of
 28 ms at 9.4M items, PERF.md §5), so on a large catalog `_topk_scores`
 reaches the same values and indices, ties included, through
-`_select_topk`: block maxima, the k best blocks, a sort of their k·L
-candidates. Small catalogs call `lax.top_k` itself (`_select_block_len`).
+`select_topk`: block maxima, the k best blocks, a sort of their k·L
+candidates. Small catalogs call `lax.top_k` itself (`select_block_len`).
 
 What a query suppresses reaches `_topk_scores` as a resident ``bool[n_items]``
 mask, or as that plus ROWS (`RowExclude`): the mask the e-commerce rules
 describe is then composed on the device, inside the same executable, and
 the query ships a few KB of row indices, not a mask of catalog length.
+
+The rules and the selection are shared with `ops/llr` (the Universal
+Recommender's resident index scores into the same row ladder and the same
+selection): `ROW_LADDER`, `row_capacity`, `RowExclude`, `pack_rows`,
+`put_rows`, `suppress_rows`, `no_exclude_mask`, `select_block_len` and
+`select_topk` are this module's public pieces; a change to the packed rows'
+layout or to the tie order is a change to both kernels.
 """
 
 from __future__ import annotations
@@ -43,7 +50,7 @@ _M_SELECT = telemetry.registry().counter(
 _MIN_BLOCK_LEN = 128
 
 
-def _select_block_len(n_items: int, k: int) -> int:
+def select_block_len(n_items: int, k: int) -> int:
     """Block length L of the two-stage selection for a row of ``n_items``
     scores, or 0 where plain ``lax.top_k`` is to run. The two stages sort
     B = ceil(n/L) block maxima and k·L candidates; B + k·L is least at
@@ -60,7 +67,7 @@ def _select_block_len(n_items: int, k: int) -> int:
     return length if 2 * (n_blocks + k * length) < n_items else 0
 
 
-def _select_topk(scores, k: int, block_len: int):
+def select_topk(scores, k: int, block_len: int):
     """``lax.top_k(scores, k)`` of one score row ``[n]``, values and
     indices bit for bit, without sorting the row: pad it with -inf to
     ``[B, block_len]``, take each block's maximum, pick the kb = min(k, B)
@@ -109,15 +116,15 @@ def _select_topk(scores, k: int, block_len: int):
 #: 8 times that, where resolving the ids on the host (a dict lookup each)
 #: already costs more than a dense mask does. Longer lists take the dense
 #: host mask.
-_ROW_LADDER = (4096, 32768)
+ROW_LADDER = (4096, 32768)
 
 
 def row_capacity(deny, allow) -> Optional[int]:
-    """The first step of `_ROW_LADDER` that holds each of the two row
+    """The first step of `ROW_LADDER` that holds each of the two row
     lists (``allow`` may be None), or None where one is over the ladder's
     top."""
     longest = max(len(deny), 0 if allow is None else len(allow))
-    return next((step for step in _ROW_LADDER if longest <= step), None)
+    return next((step for step in ROW_LADDER if longest <= step), None)
 
 
 class RowExclude(NamedTuple):
@@ -132,7 +139,7 @@ class RowExclude(NamedTuple):
     allow: Optional[np.ndarray]
 
 
-def _pack_rows(deny, allow, capacity: int, n_items: int) -> np.ndarray:
+def pack_rows(deny, allow, capacity: int, n_items: int) -> np.ndarray:
     """The two lists as ONE int32 array (a put costs by the array, not by
     the byte, at this size): ``[deny | allow | whether allow was given]``,
     each list SORTED (the scatter is told so and skips its own sort) and
@@ -146,27 +153,27 @@ def _pack_rows(deny, allow, capacity: int, n_items: int) -> np.ndarray:
     return out
 
 
-def _put_rows(exclude: RowExclude, n_items: int):
-    """`_pack_rows`'s array of the two lists, on the device. Span
+def put_rows(exclude: RowExclude, n_items: int):
+    """`pack_rows`'s array of the two lists, on the device. Span
     ``topk.mask_put`` (tag ``bytes``), as for a dense host mask."""
     _base, deny, allow = exclude
     capacity = row_capacity(deny, allow)
     if capacity is None:
         raise ValueError(
             f"{len(deny)} deny / {0 if allow is None else len(allow)} allow "
-            f"rows are over the ladder {_ROW_LADDER}")
+            f"rows are over the ladder {ROW_LADDER}")
     with telemetry.span("topk.mask_put") as sp:
-        rows = _pack_rows(deny, allow, capacity, n_items)
+        rows = pack_rows(deny, allow, capacity, n_items)
         sp.tag(bytes=rows.nbytes)
         return jax.device_put(rows)
 
 
-def _suppress_rows(scores, rows):
+def suppress_rows(scores, rows):
     """-inf at the rows of ``deny`` and, where an ``allow`` list was
     given, at every row outside it: elementwise what the dense mask of
     the same lists gives (models/_filters.py). Whether it was given is a
     traced value, so a query with a whiteList and one without share the
-    executable. ``rows`` is `_pack_rows`'s array."""
+    executable. ``rows`` is `pack_rows`'s array."""
     capacity = rows.shape[0] // 2
     deny, allow, has_allow = rows[:capacity], rows[capacity:-1], rows[-1] != 0
     listed = jnp.zeros(scores.shape, bool).at[allow].set(
@@ -186,15 +193,15 @@ def _topk_scores(user_vec, item_factors, exclude_mask, k: int, rows=None):
     scores = (item_factors * user_vec[None, :]).sum(axis=1)  # [n_items]
     scores = jnp.where(exclude_mask, -jnp.inf, scores)
     if rows is not None:
-        scores = _suppress_rows(scores, rows)
-    block_len = _select_block_len(scores.shape[0], k)
+        scores = suppress_rows(scores, rows)
+    block_len = select_block_len(scores.shape[0], k)
     if block_len:
-        return _select_topk(scores, k, block_len)
+        return select_topk(scores, k, block_len)
     return jax.lax.top_k(scores, k)
 
 
 @functools.lru_cache(maxsize=None)
-def _no_exclude_mask(n_items: int):
+def no_exclude_mask(n_items: int):
     """Device-resident all-False mask, one per catalog size. Building
     `jnp.zeros((n_items,), bool)` per query cost ~0.2 ms of eager
     dispatch + transfer on the CPU-local hot path (ISSUE 17 profile) —
@@ -210,12 +217,12 @@ def top_k_items(user_vec, item_factors, k: int, exclude=None):
     TYPE which path the call takes; the answer is the same on each:
 
     - None, or a ``bool[n_items]`` array resident on the device (a
-      ``jax.Array``: `_no_exclude_mask`, a `CategoryIndex` device mask):
+      ``jax.Array``: `no_exclude_mask`, a `CategoryIndex` device mask):
       nothing crosses to the device but the query vector;
     - a host ``np.ndarray`` ``bool[n_items]`` (the dense mask of
       `models/_filters.build_exclude_mask`): its ``n_items`` bytes are put
       under span ``topk.mask_put``;
-    - a `RowExclude`: its row lists, padded to a step of `_ROW_LADDER`, are
+    - a `RowExclude`: its row lists, padded to a step of `ROW_LADDER`, are
       put under the same span (tag ``bytes``: some KB) and `_topk_scores`
       composes the mask from them and the resident base. Lists over the
       ladder's top are the caller's to turn into a dense mask
@@ -224,9 +231,9 @@ def top_k_items(user_vec, item_factors, k: int, exclude=None):
     n_items = item_factors.shape[0]
     rows = None
     if isinstance(exclude, RowExclude):
-        exclude, rows = exclude.base, _put_rows(exclude, n_items)
+        exclude, rows = exclude.base, put_rows(exclude, n_items)
     if exclude is None:
-        exclude = _no_exclude_mask(n_items)
+        exclude = no_exclude_mask(n_items)
     k = min(int(k), n_items)
     # arguments go to the jitted kernel RAW: jit's C++ dispatch commits
     # them to device far cheaper than eager jnp.asarray per query
@@ -235,13 +242,13 @@ def top_k_items(user_vec, item_factors, k: int, exclude=None):
     # an xla.compile child); topk.wait: the device's queue, the scan and
     # the readback. ``select`` says which selection this (n_items, k)
     # compiled to: the predicate _topk_scores itself traces with.
-    select = "blocks" if _select_block_len(n_items, k) else "direct"
+    select = "blocks" if select_block_len(n_items, k) else "direct"
     _M_SELECT.labels(select).inc()
     if isinstance(exclude, np.ndarray):
         # a dense mask built on the host this query (the business rules of
         # models/_filters.py): its bytes cross here, under a span of their
         # own, so that topk.dispatch stays the enqueue alone. A resident
-        # mask (_no_exclude_mask) takes no put.
+        # mask (no_exclude_mask) takes no put.
         with telemetry.span("topk.mask_put", bytes=exclude.nbytes):
             exclude = jax.device_put(exclude)
     with telemetry.span("topk.dispatch", select=select):

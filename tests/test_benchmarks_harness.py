@@ -60,6 +60,14 @@ RED = {
         "ISSUE 40's cell runs the same train_als front and is appended, "
         "and a model_config PR may edit no file the benchmark has: the "
         "next benchmark PR turns the equality into a subset",
+    ("test_simprod_deployment",
+     "test_the_cell_went_in_by_files_and_appended_entries"):
+        "pins PR 40's cell, configuration and two metrics as the LAST "
+        "entries of the manifest ([-1], [-2:]); ISSUE 42 has its own "
+        "appended after them, and a model_config PR may edit no file the "
+        "benchmark has: the next benchmark PR turns the positions into "
+        "membership (as test_runtime_spans.py and "
+        "test_ur_served_deployment.py check theirs)",
 }
 
 

@@ -143,7 +143,7 @@ def test_recommend_is_the_top_of_the_allowed(shop, case):
     rules = dict(rules)
     if rules.get("black") == "own-top-5":
         rules["black"] = _top(shop, user, 5)
-    assert topk._select_block_len(N_ITEMS, num)          # the block path
+    assert topk.select_block_len(N_ITEMS, num)          # the block path
     got = shop.model.recommend(
         "nobody" if user is None else f"u{user}", num,
         categories=rules.get("categories"), white_list=rules.get("white"),
@@ -201,7 +201,7 @@ def test_spans_and_the_counter_carry_their_tags(shop, monkeypatch):
         "excluded": len(shop.withdrawn) + len(shop.seen[0]) + 1,
         "path": "device"}
     # the rows at the ladder's floor and the whiteList flag, not N_ITEMS
-    assert mine[3].tags == {"bytes": 4 * (2 * topk._ROW_LADDER[0] + 1)}
+    assert mine[3].tags == {"bytes": 4 * (2 * topk.ROW_LADDER[0] + 1)}
     assert all(a.t1_ns <= b.t0_ns for a, b in zip(mine, mine[1:]))
     after = {r: rule(r) for r in before}
     assert {r: after[r] - before[r] for r in before} == {

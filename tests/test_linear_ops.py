@@ -209,57 +209,74 @@ def test_cco_heavy_user_extraction_is_exact():
         np.testing.assert_allclose(got[:min(n, 6)], exp[:min(n, 6)], atol=1e-2)
 
 
-def test_ur_boost_applied_before_topk(memory_storage):
-    """Review fix: bias>0 field boosts must influence selection."""
-    from incubator_predictionio_tpu.ops.llr import Indicators, score_user
-
-    ind = Indicators(
-        idx=np.array([[1], [1], [1]], np.int32),
-        score=np.array([[5.0], [4.0], [3.0]], np.float32),
-    )
-    membership = np.array([0, 1, 0], np.float32)
-    boost = np.array([1.0, 1.0, 10.0], np.float32)
-    scores, idx = score_user([(ind, membership, 1.0)], k=1, item_boost=boost)
-    assert idx[0] == 2  # boosted item wins despite lower raw score
-
-
 import pytest
 
 
-@pytest.mark.parametrize("excluded,boosted",
-                         [(False, False), (True, False), (True, True)])
-def test_ur_score_user_matches_plain_reference(excluded, boosted):
-    """The one scorer a Universal Recommender query runs (URModel calls
-    it on its indicator tables directly): two event types with their
-    own boosts, empty (-1) correlator slots, a business-rule mask and a
-    per-item boost, against the definition written out in float64."""
-    from incubator_predictionio_tpu.ops.llr import Indicators, score_user
+def _ur_case(name):
+    """(indicators, history rows an event type, boost, exclude, k) of one
+    case of the scorer's test below."""
+    from incubator_predictionio_tpu.ops.llr import Indicators
 
+    if name == "boost_before_topk":
+        # Review fix: bias>0 field boosts must influence selection.
+        ind = Indicators(idx=np.array([[1], [1], [1]], np.int32),
+                         score=np.array([[5.0], [4.0], [3.0]], np.float32))
+        return ({"buy": ind}, {"buy": [1]},
+                np.array([1.0, 1.0, 10.0], np.float32), None, 1)
     rng = np.random.default_rng(17)
-    n_items, k = 101, 10
-    inds = [Indicators(
+    n_items = 101
+    inds = {e: Indicators(
         idx=rng.integers(-1, n_items, size=(n_items, kc)).astype(np.int32),
         score=rng.random((n_items, kc)).astype(np.float32))
-        for kc in (6, 3)]
-    membs = [(rng.random(n_items) < 0.3).astype(np.float32) for _ in inds]
-    boosts = (1.0, 2.0)
-    item_boost = (np.where(rng.random(n_items) < 0.1, 2.0, 1.0)
-                  .astype(np.float32) if boosted else None)
-    exclude = (rng.random(n_items) < 0.2) if excluded else None
-    scores, idx = score_user(list(zip(inds, membs, boosts)), k,
-                             exclude=exclude, item_boost=item_boost)
+        for e, kc in (("buy", 6), ("view", 3))}
+    history = {e: np.flatnonzero(rng.random(n_items) < 0.3) for e in inds}
+    boost = (np.where(rng.random(n_items) < 0.1, 2.0, 1.0).astype(np.float32)
+             if "boosted" in name else None)
+    exclude = (rng.random(n_items) < 0.2) if "excluded" in name else None
+    return inds, history, boost, exclude, 10
+
+
+@pytest.mark.parametrize("case", ["boost_before_topk", "plain", "excluded",
+                                  "excluded+boosted", "excluded_as_rows"])
+def test_ur_score_rows_matches_plain_reference(case):
+    """The one scorer a Universal Recommender query runs (URModel calls
+    it on its resident indicators): two event types of different widths,
+    empty (-1) correlator slots, a business-rule mask (dense, or as rows)
+    and a per-item boost applied BEFORE the selection, against the
+    definition written out in float64."""
+    import jax
+
+    from incubator_predictionio_tpu.ops.llr import (
+        place_indicators, score_rows,
+    )
+    from incubator_predictionio_tpu.ops.topk import RowExclude
+
+    inds, history, boost, exclude, k = _ur_case(case)
+    n_items = next(iter(inds.values())).idx.shape[0]
+    given = exclude
+    if case == "excluded_as_rows":
+        given = RowExclude(None, np.flatnonzero(exclude).astype(np.int32),
+                           None)
+    scores, idx, postings = score_rows(
+        place_indicators(inds), history, k, exclude=given,
+        boost=None if boost is None else jax.device_put(boost))
     want = np.zeros(n_items)
-    for ind, m, b in zip(inds, membs, boosts):
-        hit = np.where(ind.idx >= 0, m[np.maximum(ind.idx, 0)], 0.0)
-        want += (ind.score.astype(np.float64) * hit).sum(axis=1) * b
-    if boosted:
-        want *= item_boost
-    if excluded:
+    hits = 0
+    for e, ind in inds.items():
+        member = np.zeros(n_items + 1)
+        member[np.asarray(history[e], int)] = 1.0
+        hit = np.where(ind.idx >= 0, member[ind.idx], 0.0)
+        hits += int(hit.sum())
+        want += (ind.score.astype(np.float64) * hit).sum(axis=1)
+    if boost is not None:
+        want *= boost
+    if exclude is not None:
         want[exclude] = -np.inf
     order = np.argsort(-want, kind="stable")[:k]
+    assert postings == hits
     np.testing.assert_array_equal(np.asarray(idx), order)
     np.testing.assert_allclose(np.asarray(scores), want[order], rtol=1e-5)
-    if excluded:
+    if exclude is not None:
         assert not exclude[np.asarray(idx)].any()
 
 

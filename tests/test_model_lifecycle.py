@@ -511,6 +511,44 @@ def test_swap_validate_failure_under_query_fire(memory_storage, chaos):
     assert codes and set(codes) == {200}, set(codes)
 
 
+def test_reload_whose_warm_up_finds_no_room_stays_on_last_good(
+        memory_storage, monkeypatch):
+    """A model whose resident state cannot be placed beside the live
+    model's (a 7.6 GB index on a 16 GB chip: the Universal Recommender at a
+    shop's catalog size, docs/operations.md) fails inside ``warm_up``; the
+    gate refuses the swap, the last-good model keeps answering, and a
+    restart (a new server, nothing resident) deploys the new instance."""
+    iid1 = _train(memory_storage, "one")
+    server = EngineServer(lifecycle_engine.engine_factory(),
+                          engine_factory_name="lifecycle",
+                          storage=memory_storage)
+    iid2 = _train(memory_storage, "two")
+
+    def no_room(self):
+        raise RuntimeError("RESOURCE_EXHAUSTED: Error allocating device "
+                           "buffer: Attempting to allocate 7.00G")
+
+    monkeypatch.setattr(lifecycle_engine.LifecycleModel, "warm_up", no_room,
+                        raising=False)
+    with ServerThread(server.app) as st:
+        r = requests.get(st.base + "/reload", timeout=60)
+        assert r.status_code == 500
+        assert "warm-up failed" in r.json()["message"]
+        assert "RESOURCE_EXHAUSTED" in r.json()["message"]
+        status = requests.get(st.base + "/status").json()
+        assert status["degraded"] is True
+        assert status["engineInstanceId"] == iid1
+        assert _post(st.base, "u1").json()["tag"] == "one"
+    monkeypatch.undo()
+    restarted = EngineServer(lifecycle_engine.engine_factory(),
+                             engine_factory_name="lifecycle",
+                             storage=memory_storage)
+    with ServerThread(restarted.app) as st:
+        assert requests.get(st.base + "/status").json()[
+            "engineInstanceId"] == iid2
+        assert _post(st.base, "u1").json()["tag"] == "two"
+
+
 def test_nan_model_refused_by_gate_and_pinned_by_refresh(memory_storage):
     """A NaN-poisoned retrain must never go live: the refresh loop's
     validated swap hits the nan_guard, stays on last-good, pins the

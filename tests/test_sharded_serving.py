@@ -425,13 +425,13 @@ def test_sharded_catalog_facade_layout_selection(catalog, mesh):
 def test_two_layouts_bit_identical_across_block_selection(
         mesh8, k, excluded):
     """A catalog large enough that the flat layout selects by blocks
-    (ops/topk._select_topk) instead of ``lax.top_k`` of the whole row:
+    (ops/topk.select_topk) instead of ``lax.top_k`` of the whole row:
     flat and mesh still answer bit for bit alike, through the facade,
     with and without a business-rule mask, on heavy ties too."""
     from incubator_predictionio_tpu.ops import topk
 
     n_items, rank = 4099, 16  # not a multiple of 8, of 128 or of the shards
-    assert topk._select_block_len(n_items, k) == 128
+    assert topk.select_block_len(n_items, k) == 128
     rng = np.random.default_rng(19)
     items = rng.normal(size=(n_items, rank)).astype(np.float32)
     items[rng.integers(0, n_items, 600)] = items[7]  # duplicate rows: ties

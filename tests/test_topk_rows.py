@@ -4,7 +4,7 @@ against the dense host mask of the same rules.
 ``models/_filters.build_exclude`` turns a query's rules into ONE sparse
 description and hands the kernel either its dense form (a fresh
 ``bool[n_items]``, shipped whole) or its row form (int32 rows padded to a
-step of ``topk._ROW_LADDER`` and a category mask resident on the device).
+step of ``topk.ROW_LADDER`` and a category mask resident on the device).
 Both must give ``top_k_items`` the same answer, scores and indices bit for
 bit: at k = 10 on a catalog large enough for the block selection, and at
 k = n_items, where the finite scores ARE the complement of the mask, so
@@ -29,7 +29,7 @@ from incubator_predictionio_tpu.workflow.context import (  # noqa: E402
 
 N_ITEMS, RANK = 40_000, 8
 CATS = ("c0", "c1", "c2", "c3")
-FLOOR, TOP = topk._ROW_LADDER[0], topk._ROW_LADDER[-1]
+FLOOR, TOP = topk.ROW_LADDER[0], topk.ROW_LADDER[-1]
 
 
 def ids(rows) -> list[str]:
@@ -155,7 +155,7 @@ def test_rows_answer_as_the_dense_mask_bit_for_bit(catalog, case):
     np.testing.assert_array_equal(dense, gone)
     rows = _build(catalog, rules, rows=True)
     assert isinstance(rows, topk.RowExclude)               # all fit the ladder
-    assert topk._select_block_len(N_ITEMS, 10)             # the block path
+    assert topk.select_block_len(N_ITEMS, 10)             # the block path
     user = catalog.users[len(case) % len(catalog.users)]
     _assert_same(topk.top_k_items(user, catalog.factors, 10, exclude=rows),
                  topk.top_k_items(user, catalog.factors, 10, exclude=dense))
@@ -275,11 +275,11 @@ def test_no_rows_traces_to_the_call_of_before(catalog):
     """A caller that passes no rows (the recommendation template, the
     sibling cell) lowers to the same program with and without the new
     argument: ``rows`` adds nothing to the jaxpr unless given."""
-    user, mask = catalog.users[0], topk._no_exclude_mask(N_ITEMS)
+    user, mask = catalog.users[0], topk.no_exclude_mask(N_ITEMS)
     without = topk._topk_scores.lower(user, catalog.factors, mask, 10)
     given = topk._topk_scores.lower(user, catalog.factors, mask, 10, None)
     assert without.as_text() == given.as_text()
     assert "scatter" not in without.as_text()
-    rows = topk._pack_rows(np.zeros(0, np.int32), None, FLOOR, N_ITEMS)
+    rows = topk.pack_rows(np.zeros(0, np.int32), None, FLOOR, N_ITEMS)
     assert "scatter" in topk._topk_scores.lower(
         user, catalog.factors, mask, 10, rows).as_text()

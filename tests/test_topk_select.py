@@ -1,8 +1,8 @@
 """The exact two-stage selection of ops/topk.py against ``jax.lax.top_k``.
 
-``_select_topk`` (block maxima, the k best blocks, a sort of their k·L
+``select_topk`` (block maxima, the k best blocks, a sort of their k·L
 candidates) must return what ``lax.top_k`` returns on the same row, values
-AND indices bit for bit, tie order included; ``_select_block_len`` decides
+AND indices bit for bit, tie order included; ``select_block_len`` decides
 from (n_items, k) alone whether ``_topk_scores`` runs it, and
 ``pio_topk_select_total{path}`` says which way each call went.
 """
@@ -15,7 +15,7 @@ jax = pytest.importorskip("jax")
 from incubator_predictionio_tpu.common import telemetry  # noqa: E402
 from incubator_predictionio_tpu.ops import topk  # noqa: E402
 
-_select = jax.jit(topk._select_topk, static_argnames=("k", "block_len"))
+_select = jax.jit(topk.select_topk, static_argnames=("k", "block_len"))
 
 
 def _random(n, seed=0):
@@ -111,7 +111,7 @@ def test_selection_is_lax_top_k_bit_for_bit(case, monkeypatch):
                      jax.lax.top_k(row, k))
         return
     n_items, rank, k, excluded, path = SHAPE_CASES[case]
-    assert ("blocks" if topk._select_block_len(n_items, k) else "direct") \
+    assert ("blocks" if topk.select_block_len(n_items, k) else "direct") \
         == path
     rng = np.random.default_rng(n_items + k)
     items = rng.normal(size=(n_items, rank)).astype(np.float32)
@@ -128,7 +128,7 @@ def test_selection_is_lax_top_k_bit_for_bit(case, monkeypatch):
     assert tagged.tags == {"select": path}
     # the reference: the same jitted scoring with lax.top_k of the whole
     # row, as every catalog had it before the selection existed
-    monkeypatch.setattr(topk, "_select_block_len", lambda n, k: 0)
+    monkeypatch.setattr(topk, "select_block_len", lambda n, k: 0)
     direct = jax.jit(topk._topk_scores.__wrapped__, static_argnames=("k",))
     mask = np.zeros(n_items, bool) if exclude is None else exclude
     _assert_same(got, direct(user, items, mask, k=k))
@@ -147,7 +147,7 @@ def test_selection_is_lax_top_k_bit_for_bit(case, monkeypatch):
     (0, 0, 0),
 ])
 def test_block_len_follows_the_shape(n_items, k, want):
-    got = topk._select_block_len(n_items, k)
+    got = topk.select_block_len(n_items, k)
     assert got == want
     if got:
         def sorted_values(length):
