@@ -105,12 +105,28 @@ class DataMap(Mapping[str, Any]):
         return f"DataMap({self._fields!r})"
 
 
+_EPOCH = _dt.datetime(1970, 1, 1, tzinfo=_dt.timezone.utc)
+
+
+def _as_datetime(t):
+    """``t``, or the ``datetime`` of ``t`` microseconds since the epoch."""
+    if t.__class__ is int:
+        return _EPOCH + _dt.timedelta(microseconds=t)
+    return t
+
+
 class PropertyMap(DataMap):
     """DataMap plus first/last update times — the result of replaying
     $set/$unset/$delete events (reference: data/.../storage/PropertyMap.scala).
+
+    A replay over the columnar scan builds its maps through
+    :meth:`_adopt`: the freshly parsed dict is taken without a copy and
+    the times are kept as microseconds since the epoch, turned into
+    ``datetime`` s when first read. Such a map compares, hashes, pickles
+    and reprs as one built through the constructor.
     """
 
-    __slots__ = ("first_updated", "last_updated")
+    __slots__ = ("_first", "_last")
 
     def __init__(
         self,
@@ -119,8 +135,45 @@ class PropertyMap(DataMap):
         last_updated: _dt.datetime,
     ):
         super().__init__(fields)
-        self.first_updated = first_updated
-        self.last_updated = last_updated
+        self._first = first_updated
+        self._last = last_updated
+
+    @classmethod
+    def _adopt(cls, fields: dict, first, last) -> "PropertyMap":
+        """A map that owns ``fields`` (no copy); ``first`` / ``last`` are
+        microseconds since the epoch or ``datetime`` s."""
+        m = cls.__new__(cls)
+        m._fields = fields
+        m._first = first
+        m._last = last
+        return m
+
+    @property
+    def first_updated(self) -> _dt.datetime:
+        t = self._first = _as_datetime(self._first)
+        return t
+
+    @first_updated.setter
+    def first_updated(self, t: _dt.datetime) -> None:
+        self._first = t
+
+    @property
+    def last_updated(self) -> _dt.datetime:
+        t = self._last = _as_datetime(self._last)
+        return t
+
+    @last_updated.setter
+    def last_updated(self, t: _dt.datetime) -> None:
+        self._last = t
+
+    def __getstate__(self):
+        # the state the class pickled when ``first_updated`` /
+        # ``last_updated`` were its slots (unpickling sets them through the
+        # setters): byte for byte, whichever way the map was built, and
+        # readable by older trees
+        return None, {"first_updated": self.first_updated,
+                      "last_updated": self.last_updated,
+                      "_fields": self._fields}
 
     def __repr__(self) -> str:
         return (

@@ -22,9 +22,10 @@ from __future__ import annotations
 
 import datetime as _dt
 import itertools
+import json as _json
 import os
 import threading
-from typing import Iterable, Iterator, Optional, Sequence
+from typing import Iterable, Iterator, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -52,6 +53,12 @@ _M_AGGREGATE_ENTITIES = telemetry.registry().counter(
     "pio_store_aggregate_entities_total",
     "Entities that a $set/$unset/$delete replay over the columnar scan "
     "left with properties (one count a store.aggregate span)")
+_M_AGGREGATE_EVENTS = telemetry.registry().counter(
+    "pio_store_aggregate_events_total",
+    "Events of the requested entity type that a $set/$unset/$delete "
+    "replay handed to its one parse (parsed) or dropped on the columns "
+    "because they lie at or before their entity's last $delete "
+    "(dropped)", ("step",))
 
 
 _M_PARSE = telemetry.registry().counter(
@@ -458,75 +465,133 @@ def scan_log_file(path: str, start_us: Optional[int] = None,
     return scan, snapshot_bytes, tail_bytes
 
 
-def aggregate_replay(
+class Replayed(NamedTuple):
+    """What :func:`replay_columns` leaves: the surviving entities' ids,
+    their props (fresh dicts the caller owns), the raw microsecond times
+    of each one's first ``$set`` after its last ``$delete`` and of the
+    last ``$set`` / ``$unset`` applied (``_TIME_ABSENT`` = the event
+    carried none), in the order the entities were first ``$set`` after
+    their last ``$delete``; and how the fold engaged: ``parsed`` (spans
+    handed to the one parse), ``dropped`` (events at or before their
+    entity's last ``$delete``, never parsed) and ``folded`` (entities
+    built from more than one surviving event)."""
+
+    ids: Sequence[str]
+    props: Sequence[dict]
+    first: np.ndarray
+    last: np.ndarray
+    parsed: int = 0
+    dropped: int = 0
+    folded: int = 0
+
+
+_NOTHING_REPLAYED = Replayed((), (), np.empty(0, np.int64),
+                             np.empty(0, np.int64))
+
+
+def replay_columns(
     cols: ColumnarEvents, rows: np.ndarray,
     entity_type: Optional[str] = None,
-) -> dict[str, tuple[dict, int, int]]:
-    """$set/$unset/$delete replay over selected columnar rows →
-    ``{entity_id: (props, first_us, last_us)}`` with raw microsecond
-    times (``_TIME_ABSENT`` = the event carried none — callers decide
-    the "now" substitution). THE one replay implementation: the merged
-    read view (:meth:`JSONLEvents.aggregate_columnar`) and the
-    partition feed's per-shard aggregation share it, so the folding
-    semantics cannot drift. ``rows`` must already be filtered to the
-    $set/$unset/$delete selection."""
+) -> Replayed:
+    """$set/$unset/$delete replay over selected columnar rows, folded on
+    the columns. THE one replay implementation: the merged read view
+    (:meth:`JSONLEvents.aggregate_columnar`) and the partition feed's
+    per-shard aggregation (:func:`aggregate_replay`) share it, so the
+    folding semantics cannot drift. ``rows`` must already be filtered to
+    the $set/$unset/$delete selection.
+
+    Rows with an entity id of the requested type are taken in stable
+    time order (an absent time counts as "now": last, in file order) and
+    grouped by entity. What lies at or before an entity's last
+    ``$delete``, and an ``$unset`` before its first ``$set`` after that,
+    changes nothing and is dropped on the columns; the spans that are
+    left go through ONE ``json.loads``. An entity left with one ``$set``
+    takes that object as its props; only one with several events is
+    folded in Python, in time order (a ``$set`` updates keys, an
+    ``$unset`` drops its keys)."""
     if rows.size == 0:
-        return {}
+        return _NOTHING_REPLAYED
     keep = cols.eid[rows] >= 0
     if entity_type is not None:
         et_table = cols.table(ColumnarEvents.TABLE_ETYPE)
         try:
             keep &= cols.etype[rows] == et_table.index(entity_type)
         except ValueError:
-            return {}
+            return _NOTHING_REPLAYED
     rows = rows[keep]
+    n = int(rows.size)
+    if n == 0:
+        return _NOTHING_REPLAYED
     ev_table = cols.table(ColumnarEvents.TABLE_EVENT)
-    codes = {n: ev_table.index(n)
-             for n in ("$set", "$unset", "$delete") if n in ev_table}
+    codes = {name: ev_table.index(name)
+             for name in ("$set", "$unset", "$delete") if name in ev_table}
+    set_c = codes.get("$set", -1)
     # ascending stable time order == sorted(find(), key=event_time),
     # with absent times treated as "now" (sorts last, file order)
     sort_t = cols.time_us[rows]
     sort_t = np.where(sort_t == _TIME_ABSENT,
                       np.iinfo(np.int64).max, sort_t)
     rows = rows[np.argsort(sort_t, kind="stable")]
+    # grouped by entity, time order kept within a group
+    by = np.argsort(cols.eid[rows], kind="stable")
+    g_rows = rows[by]
+    g_eid = cols.eid[g_rows]
+    g_ev = cols.event[g_rows]
+    step = g_eid[1:] != g_eid[:-1]
+    starts = np.flatnonzero(np.r_[True, step])
+    group = np.cumsum(np.r_[False, step])
+    pos = np.arange(n)
+    # a group's last $delete, and its first $set after that
+    cut = np.maximum.reduceat(
+        np.where(g_ev == codes.get("$delete", -3), pos, -1), starts)
+    alive = pos > cut[group]
+    first_set = np.minimum.reduceat(
+        np.where(alive & (g_ev == set_c), pos, n), starts)
+    kept = np.flatnonzero(pos >= first_set[group])
+    dropped = n - int(np.count_nonzero(alive))
+    if kept.size == 0:
+        return _NOTHING_REPLAYED._replace(dropped=dropped)
+    k_group = group[kept]
+    k_starts = np.flatnonzero(np.r_[True, k_group[1:] != k_group[:-1]])
+    k_ends = np.r_[k_starts[1:], kept.size] - 1
+    k_rows = g_rows[kept]
 
-    import json as _json
-
-    loads, raw = _json.loads, cols.raw
-    set_c = codes.get("$set", -1)
-    unset_c = codes.get("$unset", -2)
-    # hot loop over python scalars: tolist() beats per-element
-    # np.int64 indexing, and the props spans are sliced inline
-    ev_l = cols.event[rows].tolist()
-    eid_l = cols.eid[rows].tolist()
-    t_l = cols.time_us[rows].tolist()
-    span_l = cols.props[rows].tolist()
-    # replay keyed on interned entity codes; strings resolved once
-    state: dict[int, tuple[dict, int, int]] = {}
-    for e, c, t, (s0, e0) in zip(ev_l, eid_l, t_l, span_l):
-        if e == set_c:
-            d = loads(raw[s0:e0]) if s0 >= 0 else {}
-            got = state.get(c)
-            if got is not None:
-                props, first, _ = got
-                props.update(d)
-                state[c] = (props, first, t)
-            else:
-                state[c] = (d, t, t)
-        elif e == unset_c:
-            got = state.get(c)
-            if got is not None:
-                props, first, _ = got
-                if s0 >= 0:
-                    for k in loads(raw[s0:e0]):
-                        props.pop(k, None)
-                state[c] = (props, first, t)
-        else:  # $delete
-            state.pop(c, None)
-
+    raw = cols.raw
+    parsed = _json.loads(b"[" + b",".join([
+        raw[s:e] if s >= 0 else b"{}"
+        for s, e in cols.props[k_rows].tolist()]) + b"]")
+    multi = np.flatnonzero(k_ends > k_starts)
+    if multi.size:
+        is_set = (g_ev[kept] == set_c).tolist()
+        for s, e in zip(k_starts[multi].tolist(), k_ends[multi].tolist()):
+            props = parsed[s]
+            for i in range(s + 1, e + 1):
+                if is_set[i]:
+                    props.update(parsed[i])
+                else:
+                    for key in parsed[i]:
+                        props.pop(key, None)
+    # the order each entity was first $set after its last $delete
+    order = np.argsort(by[kept[k_starts]])
+    k_starts, k_ends = k_starts[order], k_ends[order]
+    t = cols.time_us[k_rows]
     # the survivors' ids alone are made strings, not the whole table
-    return dict(zip(cols.strings(ColumnarEvents.TABLE_EID, list(state)),
-                    state.values()))
+    return Replayed(
+        cols.strings(ColumnarEvents.TABLE_EID, g_eid[kept[k_starts]]),
+        [parsed[s] for s in k_starts.tolist()], t[k_starts], t[k_ends],
+        parsed=int(kept.size), dropped=dropped, folded=int(multi.size))
+
+
+def aggregate_replay(
+    cols: ColumnarEvents, rows: np.ndarray,
+    entity_type: Optional[str] = None,
+) -> dict[str, tuple[dict, int, int]]:
+    """:func:`replay_columns` as ``{entity_id: (props, first_us,
+    last_us)}`` with raw microsecond times (``_TIME_ABSENT`` = the event
+    carried none — callers decide the "now" substitution): the partition
+    feed's per-shard form."""
+    r = replay_columns(cols, rows, entity_type)
+    return dict(zip(r.ids, zip(r.props, r.first.tolist(), r.last.tolist())))
 
 
 def _fsync_enabled() -> bool:
@@ -1234,30 +1299,33 @@ class JSONLEvents(base.LEvents):
 
         Span ``store.aggregate``: tags ``events`` (the $set, $unset and
         $delete events replayed, of every entity type), ``entities`` (what
-        the replay left, as ``pio_store_aggregate_entities_total``) and
+        the replay left, as ``pio_store_aggregate_entities_total``),
         ``source`` (the ``store.scan`` inside it: a train that has read
-        its ratings first says ``cached``).
+        its ratings first says ``cached``) and how the fold engaged
+        (:class:`Replayed`): ``parsed``, ``dropped`` (both also
+        ``pio_store_aggregate_events_total{step}``) and ``folded``.
         """
         with telemetry.span("store.aggregate") as sp:
             scan, cols, rows = self._select_columnar(
                 app_id, channel_id, ["$set", "$unset", "$delete"],
                 start_time, until_time)
-            state = aggregate_replay(cols, rows, entity_type)
-            sp.tag(events=int(rows.size), entities=len(state),
-                   source=scan.source)
-            _M_AGGREGATE_ENTITIES.labels().inc(len(state))
+            r = replay_columns(cols, rows, entity_type)
+            sp.tag(events=int(rows.size), entities=len(r.ids),
+                   source=scan.source, parsed=r.parsed, dropped=r.dropped,
+                   folded=r.folded)
+            _M_AGGREGATE_ENTITIES.labels().inc(len(r.ids))
+            _M_AGGREGATE_EVENTS.labels("parsed").inc(r.parsed)
+            _M_AGGREGATE_EVENTS.labels("dropped").inc(r.dropped)
 
+            # an event without a time reports the scan's "now"
             now = _dt.datetime.now(_dt.timezone.utc)
-
-            def us_dt(us: int) -> _dt.datetime:
-                if us == _TIME_ABSENT:
-                    return now
-                return _EPOCH + _dt.timedelta(microseconds=us)
-
-            out = {
-                eid: PropertyMap(props, us_dt(first), us_dt(last))
-                for eid, (props, first, last) in state.items()
-            }
+            first, last = r.first.tolist(), r.last.tolist()
+            for times, us in ((first, r.first), (last, r.last)):
+                for i in np.flatnonzero(us == _TIME_ABSENT).tolist():
+                    times[i] = now
+            adopt = PropertyMap._adopt
+            out = {eid: adopt(props, f, la) for eid, props, f, la
+                   in zip(r.ids, r.props, first, last)}
             if required:
                 req = set(required)
                 out = {k: v for k, v in out.items()

@@ -15,6 +15,7 @@ from incubator_predictionio_tpu.data.storage import (
     EvaluationInstance,
     Event,
     Model,
+    PropertyMap,
     Storage,
 )
 
@@ -550,10 +551,10 @@ def test_fast_aggregate_matches_generic(tmp_path, backend):
                 "HOSTS": "127.0.0.1", "PORTS": str(srv.port)}))
             stack.callback(client.close)
             le = client.l_events()
-        _fuzz_aggregate_identity(le)
+        _fuzz_aggregate_identity(le, raw_lines=backend == "jsonl")
 
 
-def _fuzz_aggregate_identity(le):
+def _fuzz_aggregate_identity(le, raw_lines=False):
     import random
 
     from incubator_predictionio_tpu.data.storage.base import (
@@ -603,6 +604,195 @@ def _fuzz_aggregate_identity(le):
             assert g[k].to_dict() == c[k].to_dict(), k
             assert g[k].first_updated == c[k].first_updated, k
             assert g[k].last_updated == c[k].last_updated, k
+    if raw_lines:
+        _fuzz_aggregate_raw_lines(le, base_t)
+
+
+def _raw_event(event, entity_type, entity_id, t=None, props=None):
+    """One canonical line as an external writer may leave it: the
+    ``eventTime`` and the ``properties`` only where given."""
+    import json
+
+    rec = {"event": event, "entityType": entity_type, "entityId": entity_id}
+    if t is not None:
+        rec["eventTime"] = t.isoformat(timespec="milliseconds").replace(
+            "+00:00", "Z")
+    if props is not None:
+        rec["properties"] = props
+    return json.dumps(rec).encode() + b"\n"
+
+
+def _fuzz_aggregate_raw_lines(le, base_t):
+    """The JSONL replay on lines the Event path cannot write: an
+    ``$unset`` before any ``$set``, an ``$unset`` and a ``$set`` after a
+    ``$delete``, a ``$delete`` and a ``$set`` at one time (both orders), an
+    entity whose last event is a ``$delete``, a ``$set`` without
+    ``properties``, events without an ``eventTime`` (they come last, in file
+    order, at the read's "now"), and ids shared by two entity types —
+    written out, then fuzzed; each read equals the generic replay."""
+    import random
+
+    from incubator_predictionio_tpu.data.storage.base import (
+        aggregate_property_events,
+    )
+
+    def t(s):
+        return base_t + dt.timedelta(seconds=s)
+
+    lines = [
+        _raw_event("$unset", "item", "unset-first", t(1), {"a": None}),
+        _raw_event("$set", "item", "unset-first", t(2), {"a": 1, "b": 2}),
+        _raw_event("$set", "item", "unset-first", t(3), {"c": 3}),
+        _raw_event("$set", "item", "after-delete", t(1), {"a": 1}),
+        _raw_event("$delete", "item", "after-delete", t(2)),
+        _raw_event("$unset", "item", "after-delete", t(3), {"a": None}),
+        _raw_event("$set", "item", "after-delete", t(4), {"b": 2}),
+        _raw_event("$unset", "item", "after-delete", t(5), {"b": None}),
+        _raw_event("$set", "item", "after-delete", t(6), {"c": 1}),
+        _raw_event("$set", "item", "tie-gone", t(5), {"a": 1}),
+        _raw_event("$delete", "item", "tie-gone", t(5)),
+        _raw_event("$set", "item", "tie-back", t(1), {"z": 9}),
+        _raw_event("$delete", "item", "tie-back", t(5)),
+        _raw_event("$set", "item", "tie-back", t(5), {"a": 1}),
+        _raw_event("$set", "item", "ends-deleted", t(1), {"a": 1}),
+        _raw_event("$set", "item", "ends-deleted", t(2), {"b": 1}),
+        _raw_event("$delete", "item", "ends-deleted", t(3)),
+        _raw_event("$set", "item", "bare", t(1)),
+        _raw_event("$set", "item", "bare-later", t(1), {"a": 1}),
+        _raw_event("$set", "item", "bare-later", t(2)),
+        _raw_event("$set", "item", "no-time", None, {"a": 1}),
+        _raw_event("$set", "item", "mixed-time", t(1), {"a": 1}),
+        _raw_event("$unset", "item", "mixed-time", None, {"a": None}),
+        _raw_event("$set", "item", "mixed-time", None, {"b": 2}),
+        _raw_event("$set", "user", "shared", t(1), {"u": 1}),
+        _raw_event("$set", "item", "shared", t(2), {"i": 1}),
+        _raw_event("$delete", "item", "shared", t(3)),
+        _raw_event("$set", "user", "shared-both", t(1), {"u": 1}),
+        _raw_event("$set", "item", "shared-both", t(1), {"i": 2}),
+    ]
+    le.insert_canonical_lines(b"".join(lines), 3)
+    before = dt.datetime.now(dt.timezone.utc)
+    got = le.aggregate_properties(3, "item")
+    after = dt.datetime.now(dt.timezone.utc)
+    want = {
+        "unset-first": ({"a": 1, "b": 2, "c": 3}, t(2), t(3)),
+        "after-delete": ({"c": 1}, t(4), t(6)),
+        "tie-back": ({"a": 1}, t(5), t(5)),
+        "bare": ({}, t(1), t(1)),
+        "bare-later": ({"a": 1}, t(1), t(2)),
+        "shared-both": ({"i": 2}, t(1), t(1)),
+    }
+    assert set(got) == set(want) | {"no-time", "mixed-time"}
+    for k, (props, first, last) in want.items():
+        assert got[k] == props, k
+        assert (got[k].first_updated, got[k].last_updated) == (first, last)
+    assert got["no-time"] == {"a": 1}
+    assert before <= got["no-time"].first_updated <= after
+    assert got["no-time"].first_updated == got["no-time"].last_updated
+    assert got["mixed-time"] == {"b": 2}
+    assert got["mixed-time"].first_updated == t(1)
+    assert before <= got["mixed-time"].last_updated <= after
+    assert dict(le.aggregate_properties(3, "user")) == {
+        "shared": {"u": 1}, "shared-both": {"u": 1}}
+
+    # the same forms at random: few ids of two types, many ties
+    rng = random.Random(43)
+    lines = []
+    for _ in range(2000):
+        kind = rng.choices(["$set", "$unset", "$delete"], [0.55, 0.3, 0.15])[0]
+        when = None if rng.random() < 0.05 else t(rng.randrange(60))
+        props = None  # a $set without properties 1 in 10 times
+        if kind == "$unset" or kind == "$set" and rng.random() < 0.9:
+            props = {f"a{rng.randrange(4)}": rng.randrange(9)
+                     for _ in range(rng.randrange(kind == "$unset", 3))}
+        lines.append(_raw_event(kind, rng.choice(["user", "item"]),
+                                str(rng.randrange(50)), when, props))
+    le.insert_canonical_lines(b"".join(lines), 4)
+    for et, req in (("user", None), ("item", None), ("item", ["a1"])):
+        before = dt.datetime.now(dt.timezone.utc)
+        g = aggregate_property_events(
+            le.find(4, None, None, None, et, None,
+                    ["$set", "$unset", "$delete"]), required=req)
+        c = le.aggregate_properties(4, et, required=req)
+        assert set(g) == set(c), et
+        for k in g:
+            assert g[k].to_dict() == c[k].to_dict(), k
+            for a, b in ((g[k].first_updated, c[k].first_updated),
+                         (g[k].last_updated, c[k].last_updated)):
+                # a time read as "now" by each: after ``before``
+                assert a == b or (a >= before and b >= before), k
+
+
+#: ``PropertyMap({"categories": ["Books"]}, T, T + 1 s)`` pickled (protocol 4)
+#: by the tree before the replay's maps kept their times as microseconds
+_PROPERTY_MAP_PICKLE = (
+    b"\x80\x04\x95\xfd\x00\x00\x00\x00\x00\x00\x00\x8c/incubator_predictionio"
+    b"_tpu.data.storage.datamap\x94\x8c\x0bPropertyMap\x94\x93\x94)\x81\x94N}"
+    b"\x94(\x8c\rfirst_updated\x94\x8c\x08datetime\x94\x8c\x08datetime\x94"
+    b"\x93\x94C\n\x07\xde\x07\x01\x00\x00\x00\x00\x00\x00\x94h\x06\x8c\x08"
+    b"timezone\x94\x93\x94h\x06\x8c\ttimedelta\x94\x93\x94K\x00K\x00K\x00\x87"
+    b"\x94R\x94\x85\x94R\x94\x86\x94R\x94\x8c\x0clast_updated\x94h\x08C\n\x07"
+    b"\xde\x07\x01\x00\x00\x01\x00\x00\x00\x94h\x11\x86\x94R\x94\x8c\x07"
+    b"_fields\x94}\x94\x8c\ncategories\x94]\x94\x8c\x05Books\x94asu\x86\x94b.")
+
+
+def test_a_replayed_property_map_is_one_built_the_public_way(tmp_path):
+    """The JSONL replay's maps own their parsed dict and keep their times
+    as microseconds until read; each still equals, hashes, reprs and
+    pickles (byte for byte) as ``PropertyMap(dict(m), first, last)``, goes
+    through the storage server's encoder as one, and reads a pickle of
+    the class as it was."""
+    import json
+    import pickle
+
+    from incubator_predictionio_tpu.data.api import storage_server
+    from incubator_predictionio_tpu.data.storage import http_backend
+    from incubator_predictionio_tpu.data.storage.jsonl import JSONLEvents
+
+    le = JSONLEvents(str(tmp_path))
+    t0 = dt.datetime(2014, 7, 1, tzinfo=dt.timezone.utc)
+    le.insert_batch([
+        Event("$set", "item", "one", properties=DataMap(
+            {"categories": ["Books"], "price": 9.5}), event_time=t0),
+        Event("$set", "item", "two", properties=DataMap({"a": 1}),
+              event_time=t0),
+        Event("$set", "item", "two", properties=DataMap({"b": [1, 2]}),
+              event_time=t0 + dt.timedelta(milliseconds=1)),
+        Event("$unset", "item", "two", properties=DataMap({"a": None}),
+              event_time=t0 + dt.timedelta(days=400, milliseconds=7)),
+    ], 1)
+    got = le.aggregate_properties(1, "item")
+    assert set(got) == {"one", "two"}
+    blobs = {k: pickle.dumps(m, protocol=4) for k, m in got.items()}
+    for k, m in got.items():
+        public = PropertyMap(dict(m), m.first_updated, m.last_updated)
+        assert type(m) is PropertyMap
+        assert m == public and hash(m) == hash(public)
+        assert repr(m) == repr(public)
+        assert blobs[k] == pickle.dumps(public, protocol=4)
+        back = pickle.loads(blobs[k])
+        assert back == public and repr(back) == repr(public)
+        assert (back.first_updated, back.last_updated) == (
+            public.first_updated, public.last_updated)
+    assert got["two"] == {"b": [1, 2]}
+    assert got["two"].first_updated == t0
+    assert got["two"].last_updated == t0 + dt.timedelta(days=400,
+                                                        milliseconds=7)
+
+    wire = json.loads(json.dumps(
+        storage_server._encode_result("l_events", got)))
+    assert wire == {k: http_backend.property_map_to_json(
+        PropertyMap(dict(m), m.first_updated, m.last_updated))
+        for k, m in got.items()}
+    for k, m in got.items():
+        back = http_backend.property_map_from_json(wire[k])
+        assert repr(back) == repr(m)
+
+    old = pickle.loads(_PROPERTY_MAP_PICKLE)
+    assert old == {"categories": ["Books"]}
+    assert (old.first_updated, old.last_updated) == (
+        t0, t0 + dt.timedelta(seconds=1))
+    assert pickle.dumps(old, protocol=4) == _PROPERTY_MAP_PICKLE
 
 
 def test_hbase_filter_pushdown_only_transfers_matches(tmp_path):

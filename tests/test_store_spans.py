@@ -467,3 +467,51 @@ def test_the_reads_maps_through_the_artifact_and_warm_up(shop, form):
         items.inverse(int(j)) for j in want]
     assert algo.predict(model, {"user": "nobody", "num": 3}) == {
         "itemScores": []}
+
+
+# -- the property replay ------------------------------------------------------
+
+
+def test_the_replay_says_what_it_parsed_dropped_and_folded(shop):
+    """``store.aggregate`` on a small log: ``parsed`` counts the spans of
+    the one parse, ``dropped`` the events at or before an entity's last
+    ``$delete``, ``folded`` the entities of more than one surviving event;
+    an ``$unset`` before the first ``$set`` is neither, another entity
+    type's events count in ``events`` alone;
+    ``pio_store_aggregate_events_total{step}`` moves by the two counts."""
+    storage, store, app_id, _path = shop
+
+    def prop(k: int, event: str, entity: str, props=None, kind="item"):
+        return Event(event=event, entity_type=kind, entity_id=entity,
+                     properties=DataMap(props or {}),
+                     event_time=T0 + datetime.timedelta(seconds=k))
+
+    store.insert_batch([
+        prop(0, "$set", "i1", {"a": 1}),      # i1: three parsed, folded
+        prop(1, "$set", "i1", {"b": 2}),
+        prop(2, "$unset", "i1", {"a": 0}),
+        prop(0, "$set", "i2", {"a": 1}),      # i2: one parsed
+        prop(0, "$set", "i3", {"a": 1}),      # i3: two dropped, one parsed
+        prop(1, "$delete", "i3"),
+        prop(2, "$set", "i3", {"c": 3}),
+        prop(0, "$set", "i4", {"a": 1}),      # i4: two dropped, gone
+        prop(1, "$delete", "i4"),
+        prop(0, "$unset", "i5", {"a": 0}),    # i5: ignored, one parsed
+        prop(1, "$set", "i5", {"e": 5}),
+        prop(0, "$set", "u1", {"u": 1}, kind="user"),
+    ], app_id)
+    fam = {f.name: f for f in telemetry.registry().collect()}[
+        "pio_store_aggregate_events_total"]
+    before = {s: fam.labels(s).value() for s in ("parsed", "dropped")}
+    t0 = time.perf_counter_ns()
+    got = PEventStore.aggregate_properties(APP, "item", storage=storage)
+    spans = [s for s in telemetry.spans_snapshot()
+             if s.t0_ns >= t0 and s.name == "store.aggregate"]
+    assert {k: dict(v) for k, v in got.items()} == {
+        "i1": {"b": 2}, "i2": {"a": 1}, "i3": {"c": 3}, "i5": {"e": 5}}
+    assert len(spans) == 1
+    assert spans[0].tags == {
+        "events": 12, "entities": 4, "source": "parse",
+        "parsed": 6, "dropped": 4, "folded": 1}
+    assert {s: fam.labels(s).value() - before[s]
+            for s in ("parsed", "dropped")} == {"parsed": 6, "dropped": 4}
