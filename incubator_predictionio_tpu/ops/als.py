@@ -26,7 +26,7 @@ recipe (PAPERS.md: arxiv 2112.02194):
   so the ALX layout composes with any data-axis layout (including
   multi-host sharded ingest) with no extra machinery.
 - One half-step solves the regularized normal equations
-  (YᵀY + λ·c·I) x = Yᵀr per row with a batched Pallas Gauss-Jordan
+  (YᵀY + λ·c·I) x = Yᵀr per row with a batched Pallas elimination
   solve (ops/pallas_kernels.py).
 - The whole iteration loop runs inside one jit under shard_map; the only
   cross-device traffic is the counterpart replication (1-D) or, on 2-D
@@ -56,7 +56,7 @@ from ..common import telemetry
 from ..common.faultinject import fault_point
 from ..parallel import supervisor as gang
 
-from .pallas_kernels import batched_spd_solve
+from .pallas_kernels import batched_spd_solve, solve_path
 from .rowblocks import (
     BucketArrays, LayoutPlan, fill_buckets, ladder_growth, plan_and_fill_both,
     plan_layout,
@@ -231,7 +231,7 @@ def _ridge_solve(a, b, lam, yty, *, implicit, model_sharded, platform, k):
     if implicit:
         a = a + yty[None, :, :]  # shared YᵀY term (all items)
     a = a + lam[:, None, None] * jnp.eye(k, dtype=jnp.float32)
-    # Pallas VMEM Gauss-Jordan on TPU, XLA Cholesky elsewhere. platform
+    # Pallas VMEM elimination on TPU, XLA Cholesky elsewhere. platform
     # is the MESH's device platform, threaded from the caller —
     # jax.default_backend() is wrong here: the driver dry-runs a CPU mesh
     # while a TPU stays the process default backend (and vice versa in
@@ -1041,8 +1041,9 @@ def train_als(
                     for b, sh in zip(flat, in_shardings[3:]))
     chunk = checkpoint_hook.every_n if checkpoint_hook is not None and checkpoint_hook.enabled else 0
     # which device path the dispatches run: Hu-Koren-Volinsky or explicit,
-    # with the value slabs or without them
-    loop_tags = {"implicit": bool(params.implicit_prefs), "binary": binary}
+    # with the value slabs or without them, through which solve
+    loop_tags = {"implicit": bool(params.implicit_prefs), "binary": binary,
+                 "solve": solve_path(k, mesh.devices.flat[0].platform)}
     if nan_guard:
         # Sanitizer tier: one dispatch per iteration + a device-side
         # finite reduction (ONE scalar fetched per iteration, not the

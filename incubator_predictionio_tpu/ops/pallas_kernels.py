@@ -3,17 +3,21 @@
 Profiling the ml20m half-step on a v5e chip (see bench.py) shows XLA's
 batched ``cholesky`` + ``cho_solve`` of the [n_rows, k, k] normal equations
 dominating the iteration (~575 ms for 138k rank-32 systems — the solver
-lowering is latency-bound on small matrices). The MXU/VPU-friendly
-replacement here solves all systems with one VMEM-resident Gauss-Jordan
-sweep:
+lowering is latency-bound on small matrices). The VPU-friendly replacement
+here solves all systems in VMEM by Gaussian elimination and a
+back-substitution (``_gauss_solve``):
 
 - The batch lives on the *lane* dimension: matrices are transposed to
   [k, k, N] so every elimination step is a [k, C]-shaped vector op across
   C systems at full lane width (C a multiple of 128).
 - Each grid step copies a C-wide slab into VMEM scratch and runs the
-  k-step elimination entirely on-chip — HBM traffic is exactly one read
-  of A/b and one write of x (the XLA formulation re-streams the whole
-  [N, k, k] array every elimination step).
+  elimination entirely on-chip — HBM traffic is exactly one read of A/b
+  and one write of x (the XLA formulation re-streams the whole [N, k, k]
+  array every elimination step).
+- The elimination touches only the trailing block, in static blocks of
+  8 pivots: about 0.37 k³ element updates a system at k = 128, against
+  the k³ of a Gauss-Jordan sweep that also clears the rows above each
+  pivot. The back-substitution adds k².
 - No pivoting: every system is SPD by construction (normal equations
   plus a λ·I ridge — ops/als.py adds 1e-6 even for empty rows).
 
@@ -34,56 +38,68 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _gj_eliminate(a_s, b_s, *, k: int):
-    """Run the elimination on VMEM scratch [k, k, C] / [k, C]; return x.
+#: pivots per elimination block: the f32 sublane tile, so every block's
+#: trailing slice starts on a tile boundary and is static
+_BLOCK = 8
 
-    Normalization-free Gauss-Jordan: pivot rows are never scaled in place
-    (row j's elimination factor is masked to zero, so row j survives
-    verbatim); after k elimination steps A is diagonal and one division
-    by the diagonal recovers x. This halves the VPU traffic of the naive
-    formulation, whose per-step masked full-block `where` store of the
-    normalized pivot row cost as much as the elimination FMA itself.
+
+def _gauss_solve(a_s, b_s, *, k: int):
+    """Solve on VMEM scratch [k, k, C] / [k, C] (k a multiple of 8); return x.
+
+    Forward elimination of the trailing block, then a back-substitution.
+    The pivots go in static blocks of 8: block [b0, b0 + 8) updates only
+    rows ≥ b0 (the leading dim) and columns ≥ b0 (the sublane dim), so
+    both slices of the scratch are static and tile-aligned. Within a
+    block, a row's factor is masked to zero at rows ≤ the pivot, so the
+    pivot row survives verbatim and A ends upper triangular in the rows
+    and columns that matter. That is Σ_{m=1..k/8} 8·(8m)² element updates
+    a system (0.37 k³ at k = 128, where a full Gauss-Jordan makes k³).
+    The back-substitution reads row j of U as one [k, C] slab, k² work.
     """
     from jax.experimental import pallas as pl
 
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)  # [k, 1]
-
-    def step(j, _):
+    for b0 in range(0, k, _BLOCK):
         # Dynamic slicing happens on the refs (Mosaic lowers pl.ds ref
         # indexing; dynamic_slice on values is not implemented).
-        rowj = a_s[pl.ds(j, 1), :, :][0]                    # [k, C] (raw)
-        piv = a_s[pl.ds(j, 1), pl.ds(j, 1), :][0]           # [1, C] a[j,j]
-        inv = 1.0 / piv                                     # [1, C]
-        bj = b_s[pl.ds(j, 1), :]                            # [1, C] (raw)
+        row_ids = jax.lax.broadcasted_iota(jnp.int32, (k - b0, 1), 0) + b0
 
-        f = a_s[:, pl.ds(j, 1), :][:, 0, :] * inv           # [k, C] col j
-        # Row j eliminates every row but itself (it is finished as-is).
-        f = jnp.where(row_ids == j, 0.0, f)
+        def step(j, _, b0=b0, row_ids=row_ids):
+            rowj = a_s[pl.ds(j, 1), b0:, :][0]              # [k-b0, C]
+            inv = 1.0 / a_s[pl.ds(j, 1), pl.ds(j, 1), :][0]  # [1, C] 1/a[j,j]
+            bj = b_s[pl.ds(j, 1), :]                        # [1, C]
+            f = a_s[b0:, pl.ds(j, 1), :][:, 0, :] * inv     # [k-b0, C] col j
+            f = jnp.where(row_ids <= j, 0.0, f)
+            a_s[b0:, b0:, :] = a_s[b0:, b0:, :] - f[:, None, :] * rowj[None]
+            b_s[b0:, :] = b_s[b0:, :] - f * bj
+            return 0
 
-        a_s[...] = a_s[...] - f[:, None, :] * rowj[None, :, :]
-        b_s[...] = b_s[...] - f * bj
-        return 0
+        jax.lax.fori_loop(b0, b0 + _BLOCK, step, 0)
 
-    jax.lax.fori_loop(0, k, step, 0)
-    # A is now diagonal; extract it with an iota mask (no dynamic loads;
-    # i1 vectors cannot grow a minor dim under Mosaic, so mask in f32).
-    col_ids = jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
-    eye_mask = (row_ids == col_ids).astype(jnp.float32)     # [k, k]
-    diag = jnp.sum(a_s[...] * eye_mask[:, :, None], axis=1)  # [k, C]
-    return b_s[...] / diag
+    # U[j, c] for c > j meets x[c] already solved; x[c ≤ j] is still 0,
+    # so the whole-row product needs no mask.
+    all_ids = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
+
+    def back(t, x):
+        j = k - 1 - t
+        u = a_s[pl.ds(j, 1), :, :][0]                       # [k, C] row j
+        s = jnp.sum(u * x, axis=0, keepdims=True)           # [1, C]
+        xj = (b_s[pl.ds(j, 1), :] - s) / a_s[pl.ds(j, 1), pl.ds(j, 1), :][0]
+        return jnp.where(all_ids == j, xj, x)
+
+    return jax.lax.fori_loop(0, k, back, jnp.zeros(b_s.shape, jnp.float32))
 
 
-def _gauss_jordan_kernel(a_ref, b_ref, x_ref, a_s, b_s, *, k: int):
+def _solve_kernel(a_ref, b_ref, x_ref, a_s, b_s, *, k: int):
     """Solve C systems: a_ref [k, k, C], b_ref [k, C] → x_ref [k, C].
 
     a_s/b_s are VMEM scratch copies mutated in place by the elimination.
     """
     a_s[...] = a_ref[...]
     b_s[...] = b_ref[...]
-    x_ref[...] = _gj_eliminate(a_s, b_s, k=k)
+    x_ref[...] = _gauss_solve(a_s, b_s, k=k)
 
 
-def _gauss_jordan_kernel_wide(a_hbm, b_hbm, x_hbm, a_s, b_s, sems, *, k: int):
+def _solve_kernel_wide(a_hbm, b_hbm, x_hbm, a_s, b_s, sems, *, k: int):
     """Wide-rank slab (96 < k ≤ 128): a_hbm [G, k, k, C], C = 128.
 
     At k=128 the f32 [k, k, C] slab is 8 MB, so the pipelined kernel's
@@ -91,8 +107,8 @@ def _gauss_jordan_kernel_wide(a_hbm, b_hbm, x_hbm, a_s, b_s, sems, *, k: int):
     (and Mosaic rejects lane blocks narrower than 128). Slabs therefore
     stay in HBM (ANY space) and each grid step DMAs ONE slab into a
     single VMEM scratch — no double buffering. The elimination is
-    compute-bound (k⁴·C/k ≈ 0.5 GFLOP/slab against 8 MB of traffic), so
-    the lost DMA/compute overlap is noise.
+    compute-bound (≈ 0.2 GFLOP/slab at k = 128 against 8 MB of traffic),
+    so the lost DMA/compute overlap is noise.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -104,7 +120,7 @@ def _gauss_jordan_kernel_wide(a_hbm, b_hbm, x_hbm, a_s, b_s, sems, *, k: int):
     cp_b.start()
     cp_a.wait()
     cp_b.wait()
-    b_s[...] = _gj_eliminate(a_s, b_s, k=k)
+    b_s[...] = _gauss_solve(a_s, b_s, k=k)
     cp_x = pltpu.make_async_copy(b_s, x_hbm.at[i], sems.at[2])
     cp_x.start()
     cp_x.wait()
@@ -133,7 +149,7 @@ def _solve_lanes(a_t, b_t, *, interpret: bool = False, vma=None):
     c = min(c, n)
     grid = (n // c,)
 
-    kernel = functools.partial(_gauss_jordan_kernel, k=k)
+    kernel = functools.partial(_solve_kernel, k=k)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -159,7 +175,7 @@ def _solve_slabs_wide(a_g, b_g, *, interpret: bool = False, vma=None):
 
     Slab-major layout: the caller pre-transposes so each grid step's slab
     is one contiguous [k, k, 128] block — the kernel's manual DMA is a
-    single contiguous transfer (see _gauss_jordan_kernel_wide).
+    single contiguous transfer (see _solve_kernel_wide).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -169,7 +185,7 @@ def _solve_slabs_wide(a_g, b_g, *, interpret: bool = False, vma=None):
         out_shape = jax.ShapeDtypeStruct((g, k, c), jnp.float32, vma=vma)
     else:
         out_shape = jax.ShapeDtypeStruct((g, k, c), jnp.float32)
-    kernel = functools.partial(_gauss_jordan_kernel_wide, k=k)
+    kernel = functools.partial(_solve_kernel_wide, k=k)
     return pl.pallas_call(
         kernel,
         grid=(g,),
@@ -194,6 +210,13 @@ def _solve_reference(a, b):
     return jax.scipy.linalg.cho_solve((chol, True), b[..., None])[..., 0]
 
 
+def solve_path(k: int, platform: str) -> str:
+    """The solve ``batched_spd_solve`` auto-selects for rank ``k`` on
+    ``platform``: ``pallas`` (the VMEM elimination, k ≤ 128 on a TPU) or
+    ``cholesky`` (XLA's)."""
+    return "pallas" if platform == "tpu" and k <= 128 else "cholesky"
+
+
 def batched_spd_solve(a, b, *, use_pallas: bool | None = None,
                       platform: str | None = None,
                       interpret: bool = False, vma=None):
@@ -215,7 +238,7 @@ def batched_spd_solve(a, b, *, use_pallas: bool | None = None,
     if use_pallas is None:
         if platform is None:
             platform = jax.default_backend()
-        use_pallas = platform == "tpu" and k <= 128
+        use_pallas = solve_path(k, platform) == "pallas"
     if not use_pallas:
         return _solve_reference(a, b)
 

@@ -416,6 +416,10 @@ class Supervisor:
         # parent-death contract, not a shorter-lived caller's
         self._membership_lock = threading.Lock()
         self._membership_cmds: list[tuple[str, int]] = []
+        # slots accepted but not yet spawned (launch-time workers before
+        # the supervision thread starts them, added ones before the next
+        # sweep): such a slot is starting, not dead
+        self._unspawned: set[int] = set(range(self.config.num_workers))
         # idx -> monotonic SIGKILL deadline; a retiring worker is
         # EXPECTED to exit, so the any-exit-is-failure service rule and
         # the stall detector both skip it
@@ -466,6 +470,12 @@ class Supervisor:
             return None
         return w.proc.pid
 
+    def worker_starting(self, idx: int) -> bool:
+        """True while slot ``idx`` is accepted but not yet spawned, so
+        ``worker_pid`` reads None for a worker that is coming up."""
+        with self._membership_lock:
+            return idx in self._unspawned
+
     def live_worker_indices(self) -> list[int]:
         """Indices on the books and not mid-retirement."""
         return sorted(w.idx for w in self._workers
@@ -497,6 +507,7 @@ class Supervisor:
             elif idx in taken:
                 raise ValueError(f"worker {idx} is already on the books")
             self._membership_cmds.append(("add", int(idx)))
+            self._unspawned.add(int(idx))
         return int(idx)
 
     def retire_worker(self, idx: int) -> None:
@@ -523,6 +534,8 @@ class Supervisor:
                     self.worker_restarts.append(0)
                 self._workers.append(
                     self._spawn_worker(idx, None, resume=False, attempt=0))
+                with self._membership_lock:
+                    self._unspawned.discard(idx)
                 self._event("workerAdded", worker=idx)
                 log.info("service worker %d added (now %d on the books)",
                          idx, len(self._workers))
@@ -612,6 +625,8 @@ class Supervisor:
             self._spawn_worker(i, port, resume, self._attempt)
             for i in range(cfg.num_workers)
         ]
+        with self._membership_lock:
+            self._unspawned.difference_update(range(cfg.num_workers))
         self._event("gangStart", attempt=self._attempt, resume=resume,
                     port=port,
                     pids=[w.proc.pid for w in self._workers])

@@ -625,8 +625,12 @@ def run_fleet(worker_argv: Sequence[str], replicas: int, host: str,
             samples = []
             for i, doc in zip(idxs, docs):
                 drng = front.is_draining(i)
+                # a slot the supervisor has not spawned yet is coming
+                # up, not dead: counted dead, the first tick after launch
+                # reads the fleet below its floor and spawns a spare
                 s = ReplicaSample(
-                    slot=i, alive=sup.worker_pid(i) is not None,
+                    slot=i, alive=(sup.worker_pid(i) is not None
+                                   or sup.worker_starting(i)),
                     ready=front.is_ready(i) and not drng, draining=drng)
                 if isinstance(doc, dict):
                     ov = doc.get("overload") or {}

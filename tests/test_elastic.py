@@ -326,6 +326,30 @@ class TestDynamicMembership:
             t.join(timeout=30)
         assert sup.state == "drained"
 
+    def test_unspawned_slots_read_as_starting(self, tmp_path):
+        sup = _service_sup(tmp_path)
+        sup.add_worker(1)
+        # before run(): the launch slot and the queued add are coming
+        # up (no pid yet), an unclaimed slot is not
+        assert sup.worker_starting(0) and sup.worker_pid(0) is None
+        assert sup.worker_starting(1) and not sup.worker_starting(2)
+        t = threading.Thread(target=sup.run, daemon=True)
+        t.start()
+        try:
+            _sup_poll(lambda: sup.worker_pid(0) and sup.worker_pid(1),
+                      msg="both workers spawned")
+            assert not sup.worker_starting(0)
+            assert not sup.worker_starting(1)
+            # a spawned worker that dies reads dead, not starting
+            pid = sup.worker_pid(1)
+            os.kill(pid, signal.SIGKILL)
+            _sup_poll(lambda: sup.worker_pid(1) != pid,
+                      msg="worker 1 seen dead or relaunched")
+            assert not sup.worker_starting(1)
+        finally:
+            sup.request_stop()
+            t.join(timeout=30)
+
     def test_added_worker_has_restart_budget(self, tmp_path):
         sup = _service_sup(tmp_path, max_restarts=1)
         t = threading.Thread(target=sup.run, daemon=True)
@@ -627,7 +651,7 @@ def test_eventserver_scale_rebalances_leases_exactly_once(tmp_path):
     event is present exactly once through every transition, and the
     orphaned shard stays readable while parked."""
     from test_event_log import (_ev, _make_mw_env, _prepare_metadata,
-                                _wait_ready)
+                                _wait_partitions, _wait_ready)
 
     env = _make_mw_env(tmp_path,
                        PIO_FS_BASEDIR=str(tmp_path / "pio_store"))
@@ -678,6 +702,7 @@ def test_eventserver_scale_rebalances_leases_exactly_once(tmp_path):
     try:
         _wait_ready(proc, base)
         wait_info(lambda d: d["workers"] == [0, 1], "front info")
+        _wait_partitions(proc, base, (0, 1))
         acked = []
         # two pinned sessions land on both workers: both shards take
         # writes before the first rebalance

@@ -522,6 +522,33 @@ def _wait_ready(proc, base, timeout=90):
     raise AssertionError("front not ready in time")
 
 
+def _wait_partitions(proc, base, partitions, timeout=90):
+    """Wait until every worker in ``partitions`` answers through the
+    front. The front answers as soon as ONE worker listens, and skips a
+    worker that still refuses connects, so traffic sent before the rest
+    bind would all land on the first one. Each ``GET /`` here opens a
+    fresh connection, which the front hands to the next worker in turn."""
+    deadline = time.monotonic() + timeout
+    seen: set = set()
+    while time.monotonic() < deadline:
+        if proc.poll() is not None:
+            out = proc.stdout.read().decode(errors="replace")
+            raise AssertionError(
+                f"front died before workers {sorted(partitions)} "
+                f"answered (rc={proc.returncode}):\n{out[-3000:]}")
+        try:
+            seen.add(requests.get(base + "/", timeout=2).json()
+                     .get("partition"))
+        except (requests.RequestException, ValueError):
+            pass
+        if set(partitions) <= seen:
+            return
+        time.sleep(0.05)
+    proc.kill()
+    raise AssertionError(f"workers answered {sorted(seen, key=str)}, "
+                         f"expected {sorted(partitions)}")
+
+
 def _supervisor_doc(tmp_path, front_pid):
     path = os.path.join(str(tmp_path), "pio_store", "gang",
                         f"pid{front_pid}", "supervisor.json")
@@ -549,6 +576,7 @@ def test_multiworker_smoke_disjoint_partitions_and_merged_reads(tmp_path):
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
     try:
         _wait_ready(proc, base)
+        _wait_partitions(proc, base, (0, 1))
         acked = []
         # sessions pin a connection → a backend; two sessions land on
         # different workers (round-robin), proving disjoint ownership

@@ -94,7 +94,8 @@ def test_implicit_als_is_the_references_on_counted_pairs(repeats, n_dev):
     mesh = mesh_from_devices(devices=jax.devices()[:n_dev])
     got = train_als(cu, ci, cr, N_USERS, N_ITEMS, params, mesh=mesh)
     loop = [s for s in telemetry.spans_snapshot() if s.name == "als.loop"][-1]
-    assert loop.tags == {"implicit": True, "binary": not repeats}
+    assert loop.tags == {"implicit": True, "binary": not repeats,
+                         "solve": "cholesky"}
     wu, wi, wc = reference_implicit.pair_counts(u, i, N_ITEMS)
     want = reference_implicit.implicit_als_reference(
         wu, wi, wc, N_USERS, N_ITEMS, RANK, 0.01, 1.0, 3, SWEEPS, "bfloat16")
