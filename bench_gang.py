@@ -1,7 +1,7 @@
 """Gang-restart recovery bench: how fast does supervised multi-worker
 training detect a dead/hung worker and resume from checkpoint?
 
-Runs a REAL 2-process sharded-ALS gang (tests/gang_als_worker.py) under
+Runs a REAL 2-process merged-feed ALS gang (tests/gang_als_worker.py) under
 parallel/supervisor.Supervisor and measures, with wall-clock brackets:
 
 - kill bracket: SIGKILL one worker mid-training →
@@ -453,7 +453,7 @@ def main() -> int:
         log(f"[gang-bench] could not persist to BASELINE.json: {e}")
     with open(os.path.join(HERE, "MULTICHIP_gang.json"), "w") as f:
         json.dump({"metric": "gang supervised recovery (2 workers, "
-                             "sharded ALS, CPU gloo) + partition-local "
+                             "merged-feed ALS, CPU gloo) + partition-local "
                              "training feeds (1/2/4-worker bracket, "
                              "feed-path A/B, ceiling control)",
                    **results,

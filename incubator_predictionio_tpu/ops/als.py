@@ -24,7 +24,7 @@ recipe (PAPERS.md: arxiv 2112.02194):
   storage per device is n_rows·k·4/m bytes, so catalog capacity scales
   linearly with the model axis. Ownership windows are windows of SLOTS,
   so the ALX layout composes with any data-axis layout (including
-  multi-host sharded ingest) with no extra machinery.
+  a mesh that spans processes) with no extra machinery.
 - One half-step solves the regularized normal equations
   (YᵀY + λ·c·I) x = Yᵀr per row with a batched Pallas elimination
   solve (ops/pallas_kernels.py).
@@ -58,8 +58,8 @@ from ..parallel import supervisor as gang
 
 from .pallas_kernels import batched_spd_solve, solve_path
 from .rowblocks import (
-    BucketArrays, LayoutPlan, fill_buckets, ladder_growth, plan_and_fill_both,
-    plan_layout,
+    BucketArrays, LayoutPlan,
+    plan_and_fill_both,
 )
 from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, default_mesh, fast_put
 
@@ -1150,276 +1150,6 @@ def train_phase_seconds(since_ns: int) -> dict[str, float]:
     }
 
 
-def process_row_ranges(n_rows: int, mesh: Optional[Mesh] = None
-                       ) -> tuple[int, int]:
-    """[row0, row1) of entity rows THIS process owns on the mesh data axis.
-
-    The contract for sharded multi-host ingest: each training process
-    range-reads only the events whose solved-side row falls in its range
-    (one range per side), instead of every host scanning the full store.
-    Deterministic from (n_rows, mesh) alone — no coordination needed.
-    Ranges are in LOGICAL row ids (the layout's internal slot padding
-    never changes ownership); row1 may exceed n_rows on the last process.
-    """
-    mesh = mesh or default_mesh()
-    d_size, _ = _mesh_dims(mesh)
-    rpl = -(-n_rows // d_size)
-    n_proc = jax.process_count()
-    if d_size % n_proc:
-        # Same contract train_als_process_sharded enforces; failing here
-        # keeps callers from range-reading wrong slices before train raises.
-        raise ValueError(
-            f"data axis size {d_size} is not divisible by "
-            f"{n_proc} processes")
-    shards_per_proc = d_size // n_proc
-    p = jax.process_index()
-    return p * shards_per_proc * rpl, (p + 1) * shards_per_proc * rpl
-
-
-def train_als_process_sharded(
-    user_slice: tuple[np.ndarray, np.ndarray, np.ndarray],
-    item_slice: tuple[np.ndarray, np.ndarray, np.ndarray],
-    n_users: int,
-    n_items: int,
-    params: ALSParams,
-    mesh: Optional[Mesh] = None,
-    checkpoint_hook=None,
-    resume: bool = False,
-) -> ALSFactors:
-    """Multi-controller ALS where each process ingests ONLY its shard.
-
-    ``user_slice`` = (user_idx, item_idx, rating) holding exactly the
-    events whose USER row this process owns (``process_row_ranges(
-    n_users)``); ``item_slice`` = the same tuple order, holding the
-    events whose ITEM row this process owns. In a deployment
-    these are two range-reads against the shared event store — no host
-    ever materializes the full dataset (the Spark-side analog is
-    partitioned RDD ingest, SURVEY.md §2.10).
-
-    The layout is a pure function of the per-row nnz counts, so ONE
-    allgather of each side's local counts gives every process the
-    identical global plan; each then fills only its own shards and the
-    arrays are assembled with ``jax.make_array_from_process_local_data``.
-    Factors match the single-process run bit-for-bit. Works on 1-D data
-    meshes AND 2-D (d, m) ALX meshes — ownership windows are windows of
-    layout slots, independent of which process filled them.
-
-    ``checkpoint_hook``/``resume``: same contract as train_als; every
-    process drives the (multihost-coordinated) orbax hook — the primary
-    writes, the rest participate in its barriers. Factors are replicated
-    across processes in multi-controller runs, so the snapshots are
-    identical regardless of which process persists them.
-    """
-    mesh = mesh or default_mesh()
-    d_size, m_size = _mesh_dims(mesh)
-    n_proc = jax.process_count()
-    if d_size % n_proc:
-        raise ValueError(f"{d_size} devices do not divide {n_proc} processes")
-    n_local = d_size // n_proc
-    p = jax.process_index()
-    shard0 = p * n_local
-
-    from jax.experimental import multihost_utils
-
-    def _global_counts(rows, n_rows):
-        """Allgather per-process local counts into the full count vector
-        (each process counts only rows it owns; ranges are disjoint)."""
-        rpl = -(-n_rows // d_size)
-        seg = n_local * rpl
-        local = np.zeros(seg, np.int64)
-        rows = np.asarray(rows, np.int64)
-        row0 = p * seg
-        if rows.size:
-            if rows.min() < row0 or rows.max() >= row0 + seg:
-                raise ValueError(
-                    "sharded ingest: got rows outside this process's range "
-                    f"[{row0}, {row0 + seg}) — the caller must range-read "
-                    "only owned rows (process_row_ranges); got rows in "
-                    f"[{rows.min()}, {rows.max()}] (n_rows={n_rows}, "
-                    f"p={p}, n_local={n_local}, d={d_size})")
-            local = np.bincount(rows - row0, minlength=seg)[:seg]
-        gathered = np.asarray(
-            multihost_utils.process_allgather(local)).reshape(-1)
-        return gathered[:n_rows]
-
-    # The ladder growth shapes the GLOBAL layout plan, so every process
-    # must agree on it before planning — a silent cross-host env mismatch
-    # would yield divergent plans whose shape-mismatched collectives hang
-    # or corrupt. Allgather-verify like binary_ratings below.
-    growth = ladder_growth()
-    # Gather the float64 BIT PATTERN as two int32s: device_put silently
-    # canonicalizes float64→float32 and int64→int32 (x64 mode is never
-    # on), which would corrupt either wider representation; int32 is the
-    # one dtype the gather leaves untouched (binary_ratings below relies
-    # on the same fact).
-    growth_bits = np.frombuffer(np.float64(growth).tobytes(), np.int32)
-    all_growth = np.asarray(multihost_utils.process_allgather(
-        growth_bits)).reshape(-1, 2)
-    if not np.all(all_growth == growth_bits[None, :]):
-        seen = sorted(set(
-            float(np.frombuffer(np.asarray(row, np.int32).tobytes(),
-                                np.float64)[0])
-            for row in all_growth))
-        raise ValueError(
-            "PIO_ALS_LADDER_GROWTH disagrees across processes: "
-            f"{seen} — every host must set the same value (it shapes "
-            "the global factor layout)")
-
-    # Both slices use (user_idx, item_idx, rating) tuple order; the
-    # solved-side ROW array is user_slice[0] resp. item_slice[1].
-    counts_u = _global_counts(user_slice[0], n_users)
-    counts_i = _global_counts(item_slice[1], n_items)
-    plan_u = plan_layout(counts_u, d_size, m_div=m_size)
-    plan_i = plan_layout(counts_i, d_size, m_div=m_size)
-
-    if params.binary_ratings is None:
-        # Every process must pick the SAME jit signature: AND the local
-        # all-ones verdicts (a process's slice can be all-ones while
-        # another's is not).
-        local_bin = np.array([
-            np.all(np.asarray(user_slice[2]) == 1.0)
-            and np.all(np.asarray(item_slice[2]) == 1.0)], np.int32)
-        agreed = np.asarray(
-            multihost_utils.process_allgather(local_bin)).all()
-        params = dataclasses.replace(params, binary_ratings=bool(agreed))
-    binary = bool(params.binary_ratings)
-
-    arrs_u = fill_buckets(plan_u, user_slice[0], user_slice[1], user_slice[2],
-                          col_slot_map=plan_i.slot_of_row,
-                          sentinel=plan_i.total_slots,
-                          shard0=shard0, n_local_shards=n_local,
-                          fill_vals=not binary)
-    arrs_i = fill_buckets(plan_i, item_slice[1], item_slice[0], item_slice[2],
-                          col_slot_map=plan_u.slot_of_row,
-                          sentinel=plan_u.total_slots,
-                          shard0=shard0, n_local_shards=n_local,
-                          fill_vals=not binary)
-
-    fn, in_shardings = _cached_train_fn(mesh, params, plan_u, plan_i)
-    flat_local = (
-        _side_flat(arrs_u, plan_u, _host_lam(plan_u, params), binary,
-                   col_sentinel=plan_i.total_slots)
-        + _side_flat(arrs_i, plan_i, _host_lam(plan_i, params), binary,
-                     col_sentinel=plan_u.total_slots))
-
-    def _to_global(local, sharding):
-        # Every per-side device arg is row-sharded over the data axis;
-        # this process supplies its own shards' slice.
-        local = np.asarray(local)
-        global_rows = local.shape[0] * n_proc
-        return jax.make_array_from_process_local_data(
-            sharding, local, (global_rows,) + local.shape[1:])
-
-    # lam and v_parent are global per-slot vectors in _side_flat; slice
-    # them to this process's shards before assembly.
-    def _slice_side(flat, plan):
-        out = list(flat)
-        rps = plan.rows_per_shard
-        out[-1] = out[-1][shard0 * rps:(shard0 + n_local) * rps]
-        if plan.v_rows_per_shard > 0:
-            rv = plan.v_rows_per_shard
-            out[-2] = out[-2][shard0 * rv:(shard0 + n_local) * rv]
-        return out
-
-    per_bucket = 1 if binary else 2
-    n_u_args = (per_bucket * len(plan_u.lengths)
-                + ((per_bucket + 1) if plan_u.v_rows_per_shard else 0) + 1)
-    u_flat = _slice_side(flat_local[:n_u_args], plan_u)
-    i_flat = _slice_side(flat_local[n_u_args:], plan_i)
-    flat = tuple(
-        _to_global(b, s)
-        for b, s in zip(u_flat + i_flat, in_shardings[3:])
-    )
-
-    x_shape = (plan_u.total_slots, params.rank)
-    x0, y0 = _fresh_init(params, plan_u, plan_i, n_users, n_items,
-                         keep_users=params.num_iterations < 1)
-
-    fingerprint = None
-    if checkpoint_hook is not None:
-        import zlib
-
-        # Process-invariant fingerprint: every process sees only its own
-        # slice, so hash the local slice and allgather the per-process
-        # digests — combined in process order, the result is identical
-        # everywhere (and still covers the full global triple).
-        layout_fp = zlib.crc32(
-            plan_i.slot_of_row.tobytes(),
-            zlib.crc32(plan_u.slot_of_row.tobytes(), _LAYOUT_TAG))
-        local_fp = zlib.crc32(
-            np.asarray(user_slice[2], np.float32).tobytes(),
-            zlib.crc32(np.asarray(user_slice[1], np.int64).tobytes(),
-                       zlib.crc32(np.asarray(user_slice[0], np.int64)
-                                  .tobytes(), layout_fp)))
-        all_fp = np.asarray(multihost_utils.process_allgather(
-            np.int64(local_fp))).reshape(-1)
-        fingerprint = zlib.crc32(
-            all_fp.tobytes(),
-            zlib.crc32(np.asarray(counts_u).tobytes(),
-                       zlib.crc32(np.asarray(counts_i).tobytes(),
-                                  layout_fp)))
-
-    start_iter = 0
-    if checkpoint_hook is not None and resume:
-        from ..workflow.checkpoint import CheckpointIncompatibleError
-
-        step = checkpoint_hook.latest_step()
-        if step is not None and step < params.num_iterations:
-            start_iter, tree = checkpoint_hook.restore(step)
-            rx = np.asarray(tree["user_factors"])
-            ry = np.asarray(tree["item_factors"])
-            if rx.shape != x_shape or ry.shape != y0.shape or \
-                    int(np.asarray(tree.get("fingerprint", -1))) != fingerprint:
-                raise CheckpointIncompatibleError(
-                    "checkpoint does not match the current sharded layout/"
-                    "data — retrain from scratch")
-            x0, y0 = rx, ry
-
-    gx0 = (_zeros_on_device(x_shape, in_shardings[1]) if x0 is None
-           else jax.make_array_from_callback(
-               x_shape, in_shardings[1], lambda idx: x0[idx]))
-    gy0 = jax.make_array_from_callback(
-        y0.shape, in_shardings[2], lambda idx: y0[idx])
-
-    chunk = (checkpoint_hook.every_n
-             if checkpoint_hook is not None and checkpoint_hook.enabled else 0)
-    if chunk and params.num_iterations - start_iter > chunk:
-        x, y = gx0, gy0
-        it = start_iter
-        while it < params.num_iterations:
-            fault_point("train.sweep")
-            n = min(chunk, params.num_iterations - it)
-            x, y = fn(np.int32(n), x, y, *flat)
-            gang.beat()  # after the dispatch: sweep 1 includes compile
-            it += n
-            if it < params.num_iterations:
-                # EVERY process calls save: orbax's CheckpointManager is
-                # multihost-coordinated (its own barriers; the primary
-                # process writes, the rest participate). Factors are
-                # replicated in multi-controller runs, so the pytrees
-                # are identical across processes.
-                checkpoint_hook.save(
-                    it, {"user_factors": np.asarray(jax.device_get(x)),
-                         "item_factors": np.asarray(jax.device_get(y)),
-                         "fingerprint": np.int64(fingerprint)})
-                gang.beat()  # a save (manager init, barriers) can be slow
-                # Collective drain check (allgathered): every process
-                # takes this branch at the SAME boundary or none does.
-                if gang.drain_requested_global():
-                    raise gang.GangDrainRequested(it)
-    else:
-        x, y = fn(np.int32(params.num_iterations - start_iter), gx0, gy0,
-                  *flat)
-        gang.beat()
-    x, y = jax.device_get((x, y))
-    return ALSFactors(
-        user_factors=np.asarray(x)[plan_u.slot_of_row],
-        item_factors=np.asarray(y)[plan_i.slot_of_row],
-        n_users=n_users,
-        n_items=n_items,
-    )
-
-
 #: Cap on one fused gather→gram chunk's [CH, k, k] f32 outer-product
 #: slab in the partition-local trainer (the analog of _FUSED_SLAB_BYTES
 #: for the event-COO layout).
@@ -1468,7 +1198,7 @@ def _make_dp_train_fn(mesh: Mesh, params: ALSParams, n_u_pad: int,
         raise ValueError(
             "the partition-local feed trainer shards factor blocks over "
             "the data axis only; 2-D (d, m) ALX meshes need the slab "
-            "trainer (train_als / train_als_process_sharded)")
+            "trainer (train_als)")
     d_size = mesh.shape[DATA_AXIS]
     k = params.rank
     rps_u = n_u_pad // d_size
@@ -1624,9 +1354,8 @@ def train_als_partition_local(
     """ALS over PARTITION-LOCAL events: each gang process passes only
     the (user, item, rating) triple its event-log partitions hold —
     any rows, any order, already mapped to GLOBAL indices via the
-    allgathered id vocabularies (workflow/train_feed.py). Unlike
-    :func:`train_als_process_sharded` there is no row-ownership
-    contract on the input: per-row normal equations are linear in
+    allgathered id vocabularies (workflow/train_feed.py). No process
+    needs another's events: per-row normal equations are linear in
     per-event contributions, so partition partials all-reduce to the
     exact full-data equations (see :func:`_make_dp_train_fn`).
 

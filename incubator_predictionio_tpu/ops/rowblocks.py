@@ -8,7 +8,7 @@ slot, and any segment-reduction after the gather is pure overhead. This
 layout minimizes both:
 
 - Each row's entries live in ONE dense slab row [C_b] whose capacity C_b
-  comes from a geometric ladder of 8-multiples (~1.15 steps), so padding
+  comes from a geometric ladder of 8-multiples (~1.05 steps), so padding
   is ~5% instead of the ~11%+ of uniform tiling — and the per-row normal
   equations fall straight out of a [R_b, C_b, k] einsum with NO tile→row
   segment reduction at all (the reduction IS the einsum contraction).
@@ -24,9 +24,8 @@ axis (ALX factor sharding) — MODEL_AXIS ownership windows are windows of
 slots, so the two compose with no extra machinery. Column indices are
 pre-mapped into the counterpart's π space on the host.
 
-The layout is a pure function of the per-row nnz counts (``plan_layout``),
-so multi-host processes agree on the full plan from one tiny allgather of
-counts — then each fills only its own shards (``fill_buckets``).
+The layout is a pure function of the per-row nnz counts (``plan_layout``);
+``fill_buckets`` then scatters the entries into the planned slabs.
 
 The reference has no analog: its ALS data layout is MLlib's in/out-block
 RDD partitioning inside Spark (SURVEY.md §2.9 model-parallel row).
@@ -43,56 +42,26 @@ import numpy as np
 #: largest einsum slab.
 OVERFLOW_LEN = 2048
 
-#: Default geometric growth of the capacity ladder past 64. Every padded
-#: slot is a wasted gather (the ALS wall), so tighter is faster until the
-#: bucket count (= separate einsum programs inside the one jit) hurts
-#: compile time. Measured at ML-20M shape: 1.15 → mean padding 1.100
-#: (5+15 buckets), 1.05 → 1.052 (12+37 buckets) — ~4.6% fewer gathered
-#: rows.  The r4 driver-verified A/B on the real chip ran 1.05 at
-#: 18.67M ev/s vs 1.15 at 17.56M (+6.3% end-to-end, compile time
-#: within noise), so 1.05 is the shipped default.
-DEFAULT_LADDER_GROWTH = 1.05
+#: Geometric growth of the capacity ladder past 64. Every padded slot is
+#: a wasted gather (the ALS wall), so tighter is faster until the bucket
+#: count (= separate einsum programs inside the one jit) hurts compile
+#: time. Measured at ML-20M shape: 1.15 → mean padding 1.100 (5+15
+#: buckets), 1.05 → 1.052 (12+37 buckets) — ~4.6% fewer gathered rows.
+#: The r4 driver-verified A/B on the real chip ran 1.05 at 18.67M ev/s
+#: vs 1.15 at 17.56M (+6.3% end-to-end, compile time within noise), so
+#: 1.05 is the value. A constant: it shapes the global plan, which every
+#: process of a multi-process train must agree on.
+LADDER_GROWTH = 1.05
 
 
-def ladder_growth() -> float:
-    """Effective ladder growth: PIO_ALS_LADDER_GROWTH env or the default.
-
-    Parsed lazily so a malformed value degrades to the default with a
-    warning instead of raising at import time in every entry point.
-    Values outside (1.0, 4.0] also fall back to the default with a
-    warning (≤1.0 never terminates the ladder; >4.0 is effectively a
-    two-bucket ladder, certainly a typo).
-    The value shapes the GLOBAL layout plan, so multi-host runs fold it
-    into the layout fingerprint and allgather-verify agreement (see
-    ops/als.py) — a cross-host mismatch fails fast instead of hanging in
-    shape-mismatched collectives.
-    """
-    import warnings
-
-    from ..common import envknobs
-
-    g = envknobs.env_float("PIO_ALS_LADDER_GROWTH", DEFAULT_LADDER_GROWTH,
-                           warn=True)
-    if g == DEFAULT_LADDER_GROWTH:
-        return DEFAULT_LADDER_GROWTH
-    if not 1.0 < g <= 4.0:
-        warnings.warn(
-            f"PIO_ALS_LADDER_GROWTH={g} outside (1.0, 4.0]; using "
-            f"{DEFAULT_LADDER_GROWTH}", stacklevel=2)
-        return DEFAULT_LADDER_GROWTH
-    return g
-
-
-def length_ladder(max_len: int, overflow_len: int = OVERFLOW_LEN,
-                  growth: float | None = None) -> np.ndarray:
-    """Row-capacity ladder: multiples of 8 up to 64, then ~×growth steps
-    (rounded up to a multiple of 8), capped at ``overflow_len``.
+def length_ladder(max_len: int,
+                  overflow_len: int = OVERFLOW_LEN) -> np.ndarray:
+    """Row-capacity ladder: multiples of 8 up to 64, then ~×LADDER_GROWTH
+    steps (rounded up to a multiple of 8), capped at ``overflow_len``.
 
     Geometric steps bound per-row padding waste while keeping the bucket
-    count (= separate einsum programs) in the tens. All hosts of a
-    multi-host run must agree on ``growth`` (it shapes the global plan).
+    count (= separate einsum programs) in the tens.
     """
-    g = ladder_growth() if growth is None else float(growth)
     target = max(8, min(int(max_len), overflow_len))
     caps = []
     v = 0
@@ -100,7 +69,8 @@ def length_ladder(max_len: int, overflow_len: int = OVERFLOW_LEN,
         if v < 64:
             v += 8
         else:
-            v = min(max(-(-int(v * g) // 8) * 8, v + 8), overflow_len)
+            v = min(max(-(-int(v * LADDER_GROWTH) // 8) * 8, v + 8),
+                    overflow_len)
         caps.append(v)
     return np.asarray(caps, dtype=np.int64)
 
@@ -146,8 +116,7 @@ def plan_layout(counts: np.ndarray, n_shards: int, m_div: int = 1,
     """Plan the bucket layout for one side from its per-row nnz counts.
 
     Rows are owned by shards in contiguous logical ranges of
-    ``ceil(n_rows / n_shards)`` (the multi-host range-read contract,
-    ops.als.process_row_ranges). ``m_div``: rows_per_shard is rounded up
+    ``ceil(n_rows / n_shards)``. ``m_div``: rows_per_shard is rounded up
     so the total padded row count divides the model axis.
     """
     counts = np.asarray(counts, dtype=np.int64)
@@ -353,9 +322,8 @@ def fill_buckets(plan: LayoutPlan, row: np.ndarray, col: np.ndarray,
                  fill_vals: bool = True) -> BucketArrays:
     """Scatter entries into the planned slabs for shards
     [shard0, shard0+n_local_shards). ``row`` must contain ONLY rows owned
-    by those shards (the multi-host range-read contract); ``col`` is
-    global counterpart row ids, mapped through ``col_slot_map`` into the
-    counterpart's π space.
+    by those shards; ``col`` is global counterpart row ids, mapped through
+    ``col_slot_map`` into the counterpart's π space.
 
     ``use_native``: None = auto (the C++ single-pass scatter when the
     toolchain is available — it replaces the numpy path's stable argsort,
@@ -412,7 +380,7 @@ def fill_buckets(plan: LayoutPlan, row: np.ndarray, col: np.ndarray,
         if s_lo < shard0 or s_hi >= shard0 + S_loc:
             raise ValueError(
                 "fill_buckets: entries reference rows outside shards "
-                f"[{shard0}, {shard0 + S_loc}) — range-read only owned rows")
+                f"[{shard0}, {shard0 + S_loc})")
         col64 = np.asarray(col, np.int64)
         if len(col64) and (col64.min() < 0
                            or col64.max() >= len(col_slot_map)):

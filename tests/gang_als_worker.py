@@ -1,5 +1,7 @@
 """One supervised gang worker for tests/test_gang_supervisor.py (and
-bench_gang.py): sharded-ingest ALS under parallel/supervisor.py.
+bench_gang.py): merged-feed ALS under parallel/supervisor.py — every
+worker holds the whole dataset and runs `train_als` on a mesh spanning
+the gang, as `pio train --num-workers N --feed merged` does.
 
 The supervisor provides all the wiring via environment
 (PIO_COORDINATOR_ADDRESS / PIO_NUM_PROCESSES / PIO_PROCESS_ID /
@@ -47,8 +49,7 @@ import numpy as np  # noqa: E402
 
 from incubator_predictionio_tpu.ops.als import (  # noqa: E402
     ALSParams,
-    process_row_ranges,
-    train_als_process_sharded,
+    train_als,
 )
 from incubator_predictionio_tpu.parallel.mesh import (  # noqa: E402
     mesh_from_devices,
@@ -77,17 +78,10 @@ def main() -> int:
     params = ALSParams(rank=4, num_iterations=n_iters, seed=5)
     mesh = mesh_from_devices(devices=jax.devices())
 
-    u0, u1 = process_row_ranges(n_users, mesh)
-    i0, i1 = process_row_ranges(n_items, mesh)
-    usel = (u >= u0) & (u < u1)
-    isel = (i >= i0) & (i < i1)
-
     hook = CheckpointHook(ckpt_dir, every_n=1)
     try:
-        out = train_als_process_sharded(
-            (u[usel], i[usel], r[usel]), (u[isel], i[isel], r[isel]),
-            n_users, n_items, params, mesh=mesh,
-            checkpoint_hook=hook, resume=resume)
+        out = train_als(u, i, r, n_users, n_items, params, mesh=mesh,
+                        checkpoint_hook=hook, resume=resume)
     except GangDrainRequested as e:
         print(f"[worker] drained at step {e.step}", flush=True)
         hook.close()

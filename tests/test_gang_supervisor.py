@@ -1,6 +1,6 @@
 """Gang-supervised multi-host training (ISSUE 7 acceptance).
 
-The headline chaos test runs a REAL 2-worker gang training sharded ALS
+The headline chaos test runs a REAL 2-worker gang training merged-feed ALS
 under parallel/supervisor.Supervisor, SIGKILLs one worker mid-sweep
 (deterministic `train.sweep:crash` fault), then SIGSTOPs a worker in the
 relaunched gang to simulate a hang (heartbeat stall) — and asserts the
@@ -303,7 +303,7 @@ def _run_supervisor_in_thread(sup):
 @pytest.mark.gang
 @pytest.mark.chaos
 def test_gang_survives_sigkill_and_sigstop(tmp_path):
-    """The headline acceptance: a 2-worker sharded-ALS gang loses one
+    """The headline acceptance: a 2-worker merged-feed ALS gang loses one
     worker to SIGKILL mid-sweep (attempt 0), gang-restarts from the
     checkpoint, loses another to SIGSTOP (attempt 1, detected as a
     heartbeat stall), gang-restarts again, and FINISHES with factors

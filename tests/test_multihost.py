@@ -28,7 +28,7 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _launch_workers(out_path, mode, extra_args=(), per_pid_env=None):
+def _launch_workers(out_path, mode, extra_args=()):
     """Start the 2-process jax.distributed worker pair; returns procs."""
     worker = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "mh_als_worker.py")
@@ -42,8 +42,7 @@ def _launch_workers(out_path, mode, extra_args=(), per_pid_env=None):
     }
     procs = []
     for pid in range(2):
-        env = {**env_base, "PIO_PROCESS_ID": str(pid),
-               **((per_pid_env or {}).get(pid, {}))}
+        env = {**env_base, "PIO_PROCESS_ID": str(pid)}
         procs.append(subprocess.Popen(
             [sys.executable, worker, out_path, mode, *map(str, extra_args)],
             env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
@@ -84,21 +83,13 @@ def _mh_data():
     return u, i, r, n_users, n_items
 
 
-@pytest.mark.parametrize("mode", [
-    # "full" (every process holds the whole dataset — the merged-feed
-    # gang path) is slow-marked for the tier-1 wall budget (PR 15): the
-    # sharded variants keep the 2-process parity contract tier-1, and
-    # the partition-feed gang e2e (tests/test_partition_feed.py) now
-    # covers multi-process training through the product read path.
-    pytest.param("full", marks=pytest.mark.slow),
-    "sharded", "sharded-ones"])
+@pytest.mark.parametrize("mode", ["full", "full-ones"])
 def test_two_process_training_matches_single_process(tmp_path, mode):
-    """mode="full": every worker holds the whole dataset (shared-store
-    reads). mode="sharded": each worker ingests ONLY the event ranges it
-    owns (ops.als.train_als_process_sharded) — the partitioned-ingest
-    story; factors must still match the single-process run.
-    mode="sharded-ones": all-ones ratings — both processes must
-    allgather-agree on the binary (value-slab-elided) signature."""
+    """Every worker holds the whole dataset (shared-store reads, the
+    merged feed of `pio train --num-workers N`) and runs `train_als` on
+    the mesh spanning both processes; factors must match the
+    single-process run. mode="full-ones": all-ones ratings — both
+    processes must pick the binary (value-slab-elided) signature."""
     # No pytest-timeout in this image; the communicate(timeout=240) below
     # is the hang guard.
     out_path = str(tmp_path / "mh_factors.npz")
@@ -117,7 +108,7 @@ def test_two_process_training_matches_single_process(tmp_path, mode):
     import jax
 
     u, i, r, n_users, n_items = _mh_data()
-    if mode == "sharded-ones":
+    if mode == "full-ones":
         r = np.ones_like(r)
     mesh = mesh_from_devices(devices=jax.devices()[:4])
     ref = train_als(u, i, r, n_users, n_items,
@@ -128,14 +119,13 @@ def test_two_process_training_matches_single_process(tmp_path, mode):
     np.testing.assert_allclose(mh["item"], ref.item_factors, rtol=2e-4, atol=2e-5)
 
 
-def test_two_process_2d_mesh_sharded_ingest(tmp_path):
-    """MODEL_AXIS × multi-host composition (VERDICT r2 weak #3 / next #3):
-    a (d, m) = (2, 2) mesh SPANNING two processes with sharded ingest —
-    factor matrices row-sharded over the model axis while each process
-    range-reads only its own events. Must match a single-process run on
-    the same mesh shape."""
+def test_two_process_2d_mesh_matches_single_process(tmp_path):
+    """MODEL_AXIS × multi-process composition: a (d, m) = (2, 2) mesh
+    SPANNING two processes — factor matrices row-sharded over the model
+    axis, each process holding the whole dataset. Must match a
+    single-process run on the same mesh shape."""
     out_path = str(tmp_path / "mh2d_factors.npz")
-    procs = _launch_workers(out_path, "sharded2d")
+    procs = _launch_workers(out_path, "full2d")
     outs = _join_workers(procs)
     for p, out in zip(procs, outs):
         assert p.returncode == 0, f"worker failed:\n{out[-3000:]}"
@@ -158,30 +148,8 @@ def test_two_process_2d_mesh_sharded_ingest(tmp_path):
     np.testing.assert_allclose(mh["item"], ref.item_factors, rtol=2e-4, atol=2e-5)
 
 
-def test_ladder_growth_mismatch_fails_fast(tmp_path):
-    """A cross-host PIO_ALS_LADDER_GROWTH mismatch must fail fast with a
-    clear error — NOT hang in shape-mismatched collectives (the plan it
-    shapes is global). ADVICE r3 rowblocks finding."""
-    out_path = str(tmp_path / "mh_factors.npz")
-    procs = _launch_workers(
-        out_path, "sharded",
-        per_pid_env={0: {"PIO_ALS_LADDER_GROWTH": "1.15"},
-                     1: {"PIO_ALS_LADDER_GROWTH": "1.05"}})
-    # Generous deadline (VERDICT r4 weak #6): "fail fast" here means
-    # "error instead of deadlocking in shape-mismatched collectives",
-    # not "exit within N wall seconds on a saturated 1-core host" —
-    # under full-suite load the jax.distributed init + gloo teardown of
-    # the surviving peer alone can exceed a tight cap. A true hang still
-    # trips this: a deadlocked collective never exits at all.
-    outs = _join_workers(procs, timeout=420)
-    assert any(p.returncode not in (0, None) for p in procs)
-    combined = "\n".join(outs)
-    assert "PIO_ALS_LADDER_GROWTH disagrees across processes" in combined
-    assert "<timed out>" not in combined
-
-
-def test_two_process_sharded_kill_and_resume(tmp_path):
-    """Kill both sharded-ingest trainers mid-run, then resume from the
+def test_two_process_kill_and_resume(tmp_path):
+    """Kill both merged-feed trainers mid-run, then resume from the
     last orbax snapshot: the resumed run must finish and match an
     uninterrupted single-process reference (chunked resume is
     bitwise-identical math through the same traced executable)."""
@@ -192,7 +160,7 @@ def test_two_process_sharded_kill_and_resume(tmp_path):
     n_iters = 6
 
     # Phase 1: train with per-iteration snapshots, kill once one exists.
-    procs = _launch_workers(str(tmp_path / "phase1.npz"), "sharded-ckpt",
+    procs = _launch_workers(str(tmp_path / "phase1.npz"), "full-ckpt",
                             (ckpt_dir, n_iters, 0))
     try:
         deadline = time.time() + 180
@@ -221,7 +189,7 @@ def test_two_process_sharded_kill_and_resume(tmp_path):
         _join_workers(procs, timeout=30)
 
     # Phase 2: fresh coordinator, resume from the snapshot, run to end.
-    procs = _launch_workers(out_path, "sharded-ckpt",
+    procs = _launch_workers(out_path, "full-ckpt",
                             (ckpt_dir, n_iters, 1))
     outs = _join_workers(procs)
     for p, out in zip(procs, outs):
